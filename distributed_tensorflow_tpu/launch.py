@@ -420,7 +420,11 @@ def run(
     """End-to-end entry: parse flags, bootstrap, train. Returns the final
     metrics dict (or None for a ps no-op process)."""
     from distributed_tensorflow_tpu.cluster import bootstrap_from_argv
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
 
+    configure_compile_cache()
     # Env overrides (pod-scheduler surface): heartbeat/elastic knobs ride
     # DTF_* like the resilience knobs; bootstrap_from_argv then threads the
     # cluster-level heartbeat settings into bootstrap, so the documented

@@ -30,40 +30,19 @@ def _ring_perm(n: int) -> list[tuple[int, int]]:
     return [(j, (j + 1) % n) for j in range(n)]
 
 
-def _vma_of(a):
-    """The varying manual axes of ``a``'s abstract value, or the empty set
-    on JAX versions without ``jax.typeof`` / vma typing (the same vintage
-    the ``lax.pvary`` fallback below targets — there every axis is
-    cast-able and double-casting is accepted)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return frozenset(getattr(typeof(a), "vma", ()))
-
-
 def to_varying(a, axis_name):
     """Cast a value to varying over ``axis_name`` (vma typing under
     ``shard_map``; accepts one axis or a tuple). Idempotent: axes the
     value ALREADY varies over are skipped — ``pcast(to='varying')``
     rejects them, and callers like the ring-attention carry inits derive
     their zeros from inputs whose vma depends on the enclosing mesh (1-D
-    sp vs 2-D dp×sp). ``pcast`` is the current API; ``pvary`` its
-    predecessor — routing every varying-cast through this one helper
-    keeps the whole framework working on JAX versions that only have one
-    of them."""
+    sp vs 2-D dp×sp)."""
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    have = _vma_of(a)
+    have = jax.typeof(a).vma
     axes = tuple(ax for ax in axes if ax not in have)
     if not axes:
         return a
-    if hasattr(lax, "pcast"):
-        return lax.pcast(a, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(a, axes)
-    # Pre-vma vintage (no pcast, no pvary): every value is implicitly
-    # varying under shard_map and there is no rep/vma checker to satisfy —
-    # the cast is an identity.
-    return a
+    return lax.pcast(a, axes, to="varying")
 
 
 def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:

@@ -60,6 +60,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
+
 _NEG_INF = -1e30
 
 # Measured dense/flash crossover (tools/attention_bench.py, two-point
@@ -945,8 +947,7 @@ def flash_attention_with_lse(
             raise ValueError("offset requires causal=True")
         if offset < 0:
             raise ValueError(f"offset must be >= 0, got {offset}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b, l, h, d = q.shape
     hkv = k.shape[2]
     if kv_lens is not None and kv_lens.shape != (b,):

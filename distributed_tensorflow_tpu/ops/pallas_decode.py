@@ -1,101 +1,82 @@
-"""Pallas TPU kernel: fused single-token decode step for one GPT block.
+"""Pallas TPU kernel: the fused decode step of a GPT block stack.
 
 The reference has no generative path at all (its one "inference" is the
 in-loop accuracy fetch, reference tfsingle.py:94); serving decode is this
 framework's hottest un-kerneled path. At L=1 each transformer block of
 ``models/gpt.py`` lowers to ~20 small XLA ops (the ``decode_step``
 docstring), so per-token time is dominated by per-op dispatch overhead
-and KV-cache HBM traffic, not FLOPs — the round-5 unroll fix
-(939→306 µs/token) showed decode gaps track cache-traffic ratios. This
-module collapses one block's whole single-token step into ONE Pallas
-launch per layer:
+and KV-cache HBM traffic, not FLOPs. This module collapses a block's
+whole few-token step
 
     layernorm₁ → QKV projection → RoPE → quantize-on-write of the fresh
-    K/V row → online-softmax attention over the resident cache →
+    K/V rows → online-softmax attention over the resident cache →
     output projection → residual → layernorm₂ → dense FFN → residual
 
-with the block's weights and the token's activations VMEM-resident
-across the launch, and the KV cache read block-by-block straight from
-the slab rows or the paged pool (block tables ride as scalar-prefetch
-arguments, so the pool gather is grid index-map arithmetic — no XLA
-gather materializes a contiguous view). Quantized caches (round 15)
-dequantize int8/fp8 payload blocks *inside* the kernel — the launch
-reads 1-byte elements plus the per-row f32 scales and upcasts in VMEM,
-which is where the 2× HBM-bytes claim becomes a latency claim. Per the
-round-15 rule, dequantization targets the COMPUTE dtype, never f32
-storage (the f32 view exists only as the transient dot operand).
+into ONE kernel body (:func:`_decode_kernel`) behind five entry points:
 
-Grid: ``(S, Hkv·nc + 1)`` — per serving slot, one step per
-(KV head, cache block) pair plus one finalize step. TPU grids run
+- :func:`decode_block_slab` / :func:`decode_block_paged` —
+  ``decode_engine="pallas-layer"``: one launch per LAYER; the fresh K/V
+  rows come back as outputs and ``models/gpt.py`` commits them with the
+  XLA engine's own scatter (``_commit_slot_rows`` /
+  ``_commit_paged_rows``), so the two engines' caches agree by
+  construction.
+- :func:`decode_token_slab` / :func:`decode_token_paged` —
+  ``decode_engine="pallas"``, the megakernel: one launch per TOKEN. The
+  layer loop is the outermost grid dimension, per-layer weights are
+  STREAMED through layer-indexed block maps (only the current layer —
+  and the next one being prefetched — is VMEM-resident), the residual
+  rows live in a VMEM scratch across the whole sequential grid, and the
+  fresh rows are committed IN the kernel.
+- :func:`verify_tokens_paged` — the small-L speculation verify
+  (``spec_draft > 0`` under ``"pallas"``): the same body at L rows per
+  slot, the suffix's causal block folded into the online-softmax init.
+
+**Grid and blocks.** ``(n_layers, S, nc + 1)``: per layer and serving
+slot, one step per cache block plus one finalize step. TPU grids run
 sequentially with the minor dimension fastest, so VMEM scratch carries
-the layernormed token row, the current head's online-softmax state
-(m/l/acc as [g, 1]/[g, Dh] 2-D tiles — 1-D vectors trip Mosaic relayout
-bugs, CLAUDE.md), and the per-head attention outputs across the slot's
-steps. Weight refs use constant index maps, so Mosaic fetches them once
-per launch and re-uses the resident copy every step.
+the per-head online-softmax state (m / l / acc, one ``[L, ·]`` tile per
+query head) across a slot's steps. Every block's last two dimensions
+equal the array's — the (8, 128) tiling rule the chip's compiler
+enforces and the interpreter does not: activations ride as ``[S, L, d]``
+with ``(1, L, d)`` blocks, a cache block is ``(bc, Hkv, Dh)`` — ALL KV
+heads of ``bc`` positions — and heads are picked inside the kernel by
+static index (a strided sublane load), never by a per-head block map.
+Weights use whole-layer blocks, so the projections are one
+``[L, d]·[d, H·Dh]`` matmul each and heads are static lane slices of
+the result.
 
-The fresh K/V row is folded into the attention ONLINE-SOFTMAX INIT
-(m = s_fresh, l = 1, acc = v_fresh — exactly one unmasked entry) after
-a round-trip through the cache's storage dtype, so the kernel attends
-precisely the values the cache will hold — the round-15 uniform rule
-("a quantized cache attends stored values EVERYWHERE") that keeps the
-fused engine token-compatible with the XLA engine. The cache blocks
-themselves are attended with the fresh position masked OUT
-(``idx != slot`` / ``idx < length``): the kernel reads the PRE-write
-cache, so the write's slot must come from registers, not memory.
+**Fresh rows.** The fresh K/V rows are folded into the attention
+ONLINE-SOFTMAX INIT after a round-trip through the cache's storage
+dtype, so the kernel attends precisely the values the cache will hold —
+the round-15 uniform rule ("a quantized cache attends stored values
+EVERYWHERE") that keeps the fused engines token-compatible with the XLA
+engine. The cache blocks themselves are attended with the fresh
+positions masked OUT (``idx != slot`` / ``idx < prefix_len``): the
+kernel reads the PRE-write cache, so the write's rows must come from
+registers, not memory. Quantized caches (round 15) dequantize int8/fp8
+payload blocks inside the kernel, to the COMPUTE dtype (never f32
+storage; the f32 view is only the transient dot operand).
 
-In the PER-LAYER kernel the one-row cache COMMIT stays outside the
-launch (models/gpt.py applies the same ``.at[rows, slot].set`` /
-``scatter_token_kv`` index math as the XLA engine): TPU output blocks
-may only be revisited on consecutive grid steps, so an in-kernel
-scatter would either copy the whole cache through an aliased output
-(doubling the HBM traffic this kernel exists to remove) or need a
-manual-DMA HBM path. Same division of labor as the fused flash
-backward's dq-partial sum (ops/pallas_attention.py).
+**In-kernel commit** (token and verify entry points). The cache arrays
+ride the launch TWICE — once as BlockSpec-pipelined read operands and
+once as ``memory_space=ANY`` operands aliased input→output
+(``input_output_aliases``), written by small manual DMAs at each
+layer's finalize step: one ``[Hkv, Dh]`` row group per committed
+position. That sidesteps the output-revisit rule (the commit is a DMA,
+not a pipelined output block) without copying the cache. Rows that must
+not commit (inactive slots, ``li >= suffix_len``) SKIP the DMA — exactly
+the XLA scatter's drop-at-sentinel / write-old-value-back no-op, so the
+committed bytes match the XLA index math bit-for-bit on the storage
+dtype (scale side tensors included). Writes are disjoint from every
+read by construction: the kernel attends the PRE-write cache and active
+slots never share writable blocks (the serve_pool allocator invariant —
+COW prefixes are read-only).
 
-Round 20 grows the per-layer kernel into a per-TOKEN tier
-(:func:`decode_token_slab` / :func:`decode_token_paged` /
-:func:`verify_tokens_paged` — ``decode_engine="pallas"``; the per-layer
-kernel stays as ``"pallas-layer"``, the escape hatch + parity oracle,
-the round-13 fused-vs-split pattern):
-
-- **Multi-layer megakernel**: the layer loop joins the grid as the
-  OUTERMOST dimension ``(n_layers, S, Hkv·nc + 1)`` and per-layer
-  weights are STREAMED through layer-indexed block maps instead of
-  held constant-index-map resident — one launch per token amortizes
-  the per-layer launch overhead, and the VMEM weight budget becomes a
-  per-LAYER cap (only the current layer's blocks are resident). The
-  residual rows live in an [S, d] f32 VMEM scratch across the whole
-  sequential grid.
-- **In-kernel cache commit**: the cache arrays ride the launch TWICE —
-  once as BlockSpec-pipelined read operands (unchanged structure) and
-  once as ``memory_space=ANY`` operands aliased input→output
-  (``input_output_aliases``), written by small manual DMAs at each
-  layer's finalize step. That sidesteps the output-revisit rule (the
-  commit is a DMA, not a pipelined output block) without copying the
-  cache. Inactive rows SKIP the DMA — exactly the XLA scatter's
-  drop-at-sentinel / write-old-value-back no-op, so the committed
-  bytes match the XLA index math bit-for-bit on the storage dtype
-  (scale side tensors included). Writes are disjoint from every read
-  by construction: the kernel attends the PRE-write cache (write slot
-  masked out / ``idx < length`` strict), and active slots never share
-  writable blocks (the serve_pool allocator invariant — COW prefixes
-  are read-only).
-- **Fused speculation-verify**: a small-L (L ≤ spec_draft+1) paged
-  verify kernel — the ragged ``extend_paged`` math with the suffix
-  causal block folded into the online-softmax init, fresh rows
-  round-tripped through the storage dtype (round-15 uniform rule),
-  strict ``idx < prefix_len`` cache validity, and per-position commit
-  DMAs gated on ``li < suffix_len`` — the greedy-exact acceptance
-  contract ("a bad draft never changes a token") rides on the same
-  quantize-on-write parity as the decode kernels.
-
-``interpret=None`` auto-selects the Pallas interpreter off-TPU and the
-Mosaic compiler on TPU (the ops/pallas_attention.py convention); parity
-vs the XLA engine is pinned in tests/test_pallas_decode.py (interpreter)
-and recorded on-chip by ``tools/attention_parity.py --write-docs``
-(``decode-fused-vs-xla:*`` per-layer rows; round 20 adds
-``decode-mega-vs-xla:*`` and ``verify-fused-vs-xla:*``).
+``interpret=None`` resolves through ``ops/pallas_mode`` (interpreter
+off-TPU, Mosaic on TPU); parity vs the XLA engine is pinned in
+tests/test_pallas_decode.py (interpreter), the compile for the chip in
+tests/test_chip_compile.py, and the on-chip token match by
+``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -109,21 +90,27 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
+
 _NEG_INF = -1e30
 _EPS = 1e-12
 # qmax per quantized KV dtype — MUST match ops/quantized._QMAX (the
 # kernel re-derives the same symmetric per-row scales the XLA engine
 # commits, so both engines attend identical stored values).
 _QMAX = {"int8": 127.0, "fp8": 448.0}
-_STORAGE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+
+_WEIGHT_NAMES = (
+    "wq", "wk", "wv", "wo", "ln1_scale", "ln1_bias", "ln2_scale",
+    "ln2_bias", "w_up", "b_up", "w_down", "b_down",
+)
+_MATRICES = ("wq", "wk", "wv", "wo", "w_up", "w_down")
 
 
 def _pick_cache_block(c: int, requested: int | None) -> int:
     """Largest power-of-two divisor of the cache length ≤ 512 (one score
-    tile is [g, bc] — tiny; the cap bounds the resident KV block at
-    bc·Dh elements), or ``c`` itself for short/odd caches (Mosaic pads
-    non-tile-multiple shapes; serving caches are small enough that a
-    single whole-cache block is fine)."""
+    tile is [L, bc] — tiny; the cap bounds the resident KV block at
+    bc·Hkv·Dh elements), or ``c`` itself for short/odd caches (a single
+    whole-cache block)."""
     if requested is not None:
         if c % requested:
             raise ValueError(f"block {requested} must divide cache {c}")
@@ -134,24 +121,22 @@ def _pick_cache_block(c: int, requested: int | None) -> int:
     return c
 
 
-def _ln_row(x, scale_ref, bias_ref):
-    """f32 layernorm on a [1, d] row — the models/base.layernorm
+def _ln_rows(x, scale, bias):
+    """f32 layernorm on [L, d] rows — the models/base.layernorm
     arithmetic verbatim (eps included), so the fused block cannot drift
     numerically from the XLA block."""
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = ((x32 - mu) ** 2).mean(-1, keepdims=True)
-    return ((x32 - mu) * lax.rsqrt(var + 1e-5)) * scale_ref[:] + bias_ref[:]
+    return ((x32 - mu) * lax.rsqrt(var + 1e-5)) * scale + bias
 
 
 def _rope_rows(x, pos_f, dh: int, base: float):
-    """Rotary embedding on [rows, Dh] — the models/gpt._rope pair
-    rotation in f32. ``pos_f`` is a scalar (all rows at the slot's own
-    position — the decode step) or a [rows, 1] f32 column (per-row
-    positions — the verify kernel's suffix rows); both broadcast
-    against the [1, half] frequency row identically."""
+    """Rotary embedding on [L, Dh] — the models/gpt._rope pair rotation
+    in f32. ``pos_f`` is a [L, 1] f32 column of per-row positions,
+    broadcast against the [1, half] frequency row."""
     half = dh // 2
-    io = lax.broadcasted_iota(jnp.float32, (1, half), 1)
+    io = lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(jnp.float32)
     # base ** (-i/half) in the models/gpt._rope evaluation order (the
     # exp(-ln·i/half) refactoring differs in the last ulp, which the
     # parity tests would otherwise have to budget for).
@@ -162,15 +147,15 @@ def _rope_rows(x, pos_f, dh: int, base: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _quant_row(x, kv_q: str):
-    """Symmetric per-row quantization of [rows, Dh] — the
+def _quant_rows(x, kv_q: str):
+    """Symmetric per-row quantization of [L, Dh] — the
     ops/quantized.quantize_kv recipe (amax over the lane dim, eps floor,
     int8 round-and-clip / fp8 cast) re-derived in-kernel so the fused
-    engine commits bit-identical rows to the XLA engine."""
+    engines commit bit-identical rows to the XLA engine."""
     qmax = _QMAX[kv_q]
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, _EPS) / qmax
-    xs = x.astype(jnp.float32) / scale
+    xs = x / scale
     if kv_q == "int8":
         q = jnp.clip(jnp.round(xs), -qmax, qmax).astype(jnp.int8)
     else:
@@ -178,347 +163,491 @@ def _quant_row(x, kv_q: str):
     return q, scale
 
 
-def _fused_decode_kernel(
+def _dot_nt(a, b):
+    """[M, K] · [N, K]ᵀ → [M, N] f32 (contract the minor dims; no
+    materialized transpose)."""
+    return lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dma(src, dst, sem):
+    """One synchronous manual copy (start + wait) — the in-kernel cache
+    commit's write primitive. Serialized on one DMA semaphore: commits
+    are a few rows per layer, latency-insignificant next to the cache
+    read stream."""
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _decode_kernel(
     *refs,
-    nc: int, hkv_n: int, g: int, dh: int, bc: int, cache_len: int,
-    window: int | None, rolling: bool, kv_q: str | None, cd,
-    rope: bool, rope_base: float, n_prefetch: int,
+    n_layers: int, nc: int, hq_n: int, hkv_n: int, dh: int, L: int,
+    bc: int, cache_len: int, window: int | None, rolling: bool,
+    paged: bool, commit: bool, kv_q: str | None, cd, rope: bool,
+    rope_base: float,
 ):
-    lens_ref = refs[0]
-    i = n_prefetch  # tables (paged) are consumed by index maps only
+    """The one kernel body; see the module docstring. Ref order: scalar
+    prefetch (prefix lens, suffix lens, active[, tables]); h; the twelve
+    weights; cache K, V[, K/V scales]; [the ANY alias sources]; outputs;
+    scratch."""
+    plen_ref, slen_ref, act_ref = refs[:3]
+    tab_ref = refs[3] if paged else None
+    i = 4 if paged else 3
     (h_ref, wq_ref, wk_ref, wv_ref, wo_ref, ln1s_ref, ln1b_ref,
      ln2s_ref, ln2b_ref, wup_ref, bup_ref, wdn_ref, bdn_ref,
      ck_ref, cv_ref) = refs[i:i + 15]
     i += 15
+    ks_ref = vs_ref = None
     if kv_q is not None:
         ks_ref, vs_ref = refs[i:i + 2]
         i += 2
-        ho_ref, kq_ref, vq_ref, ksc_ref, vsc_ref = refs[i:i + 5]
-        i += 5
+    if commit:
+        i += 2  # ANY alias sources: donated, never read
+        ho_any, cko, cvo = refs[i:i + 3]
     else:
-        ho_ref, kq_ref, vq_ref = refs[i:i + 3]
-        i += 3
-    hn_scr, q_scr, m_scr, l_scr, acc_scr, attn_scr = refs[i:i + 6]
+        ho_ref, kf_dst, vf_dst = refs[i:i + 3]
+    i += 3
+    if kv_q is not None:
+        ksc_out, vsc_out = refs[i:i + 2]
+        i += 2
+    h_scr, q_scr, m_scr, l_scr, acc_scr = refs[i:i + 5]
+    i += 5
+    if commit:
+        kf_dst, vf_dst, out_scr, sem = refs[i:i + 4]
 
-    s_i = pl.program_id(0)
-    j = pl.program_id(1)
-    t_att = hkv_n * nc
-    jc = jnp.minimum(j, t_att - 1)
-    hkv = jc // nc
-    ic = jc % nc
-    length = lens_ref[s_i]
+    l_i = pl.program_id(0)
+    s_i = pl.program_id(1)
+    j = pl.program_id(2)
+    ic = jnp.minimum(j, nc - 1)
+    g = hq_n // hkv_n
+    plen = plen_ref[s_i]
+    slen = slen_ref[s_i]
     scale = 1.0 / math.sqrt(dh)
+    li_col = lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+
+    @pl.when((l_i == 0) & (j == 0))
+    def _seed_residual():
+        h_scr[s_i] = h_ref[0]
+
+    h_rows = h_scr[s_i]  # [L, d] f32
 
     @pl.when(j == 0)
-    def _ln1():
-        hn_scr[:] = _ln_row(h_ref[:], ln1s_ref, ln1b_ref)
-
-    @pl.when((j < t_att) & (ic == 0))
-    def _head_start():
-        # This KV head's projections: hn @ per-head weight columns, in
-        # the compute dtype with f32 accumulation (GPTLM._dot). The g
-        # query rows are produced one static slice at a time — a
-        # [1, g·Dh] → [g, Dh] reshape would cross the lane/sublane
-        # boundary, the relayout class CLAUDE.md warns about.
-        hn = hn_scr[:].astype(cd)
-        for gi in range(g):
-            q_scr[gi:gi + 1, :] = jnp.dot(
-                hn, wq_ref[:, gi * dh:(gi + 1) * dh],
-                preferred_element_type=jnp.float32,
-            )
-        kf = jnp.dot(hn, wk_ref[:], preferred_element_type=jnp.float32)
-        vf = jnp.dot(hn, wv_ref[:], preferred_element_type=jnp.float32)
-        if rope:
-            pos_f = length.astype(jnp.float32)
-            q_scr[:] = _rope_rows(q_scr[:], pos_f, dh, rope_base)
-            kf = _rope_rows(kf, pos_f, dh, rope_base)
-        # Quantize-on-write, then attend the ROUND-TRIPPED values — the
-        # round-15 uniform rule: position `length` must score exactly as
-        # a later decode re-reading it from the cache will.
-        if kv_q is None:
-            kq_row = kf.astype(kq_ref.dtype)
-            vq_row = vf.astype(vq_ref.dtype)
-            kf_att = kq_row.astype(jnp.float32)
-            vf_att = vq_row.astype(jnp.float32)
-        else:
-            kq_row, k_sc = _quant_row(kf, kv_q)
-            vq_row, v_sc = _quant_row(vf, kv_q)
-            kf_att = (kq_row.astype(jnp.float32) * k_sc).astype(cd).astype(
-                jnp.float32
-            )
-            vf_att = (vq_row.astype(jnp.float32) * v_sc).astype(cd).astype(
-                jnp.float32
-            )
-            ksc_ref[0, 0] = k_sc[0, 0]
-            vsc_ref[0, 0] = v_sc[0, 0]
-        kq_ref[:] = kq_row
-        vq_ref[:] = vq_row
-        # Online-softmax INIT from the fresh row: exactly one unmasked
-        # entry, so m = its score, l = exp(0) = 1, acc = its value.
-        sf = jnp.sum(q_scr[:] * kf_att, axis=-1, keepdims=True) * scale
-        m_scr[:] = sf
-        l_scr[:] = jnp.ones_like(l_scr)
-        acc_scr[:] = jnp.broadcast_to(vf_att, acc_scr.shape)
+    def _start():
+        # All heads' projections as three matmuls in the compute dtype
+        # with f32 accumulation (GPTLM._dot); heads are static lane
+        # slices of the results.
+        hn = _ln_rows(h_rows, ln1s_ref[0], ln1b_ref[0]).astype(cd)
+        q_all = jnp.dot(hn, wq_ref[0], preferred_element_type=jnp.float32)
+        k_all = jnp.dot(hn, wk_ref[0], preferred_element_type=jnp.float32)
+        v_all = jnp.dot(hn, wv_ref[0], preferred_element_type=jnp.float32)
+        pos_f = (plen + li_col).astype(jnp.float32)
+        lj = lax.broadcasted_iota(jnp.int32, (L, L), 1)
+        valid = (lj <= li_col) & (lj < slen)
+        if window is not None:
+            valid &= lj > li_col - window
+        hcol = lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1)
+        k_scales = jnp.zeros((L, hkv_n), jnp.float32)
+        v_scales = jnp.zeros((L, hkv_n), jnp.float32)
+        for hk in range(hkv_n):
+            kf = k_all[:, hk * dh:(hk + 1) * dh]
+            vf = v_all[:, hk * dh:(hk + 1) * dh]
+            if rope:
+                kf = _rope_rows(kf, pos_f, dh, rope_base)
+            # Quantize-on-write, then attend the ROUND-TRIPPED values —
+            # the round-15 uniform rule: a fresh position must score
+            # exactly as a later decode re-reading it from the cache.
+            if kv_q is None:
+                kq_rows = kf.astype(kf_dst.dtype)
+                vq_rows = vf.astype(vf_dst.dtype)
+                kf_att = kq_rows.astype(jnp.float32)
+                vf_att = vq_rows.astype(jnp.float32)
+            else:
+                kq_rows, k_sc = _quant_rows(kf, kv_q)  # [L, Dh], [L, 1]
+                vq_rows, v_sc = _quant_rows(vf, kv_q)
+                kf_att = (kq_rows.astype(jnp.float32) * k_sc).astype(
+                    cd
+                ).astype(jnp.float32)
+                vf_att = (vq_rows.astype(jnp.float32) * v_sc).astype(
+                    cd
+                ).astype(jnp.float32)
+                k_scales = jnp.where(hcol == hk, k_sc, k_scales)
+                v_scales = jnp.where(hcol == hk, v_sc, v_scales)
+            # Output blocks carry a leading slot axis the scratch lacks.
+            where = (slice(None), hk) if commit else (0, slice(None), hk)
+            kf_dst[where] = kq_rows
+            vf_dst[where] = vq_rows
+            for gi in range(g):
+                hq = hk * g + gi
+                qh = q_all[:, hq * dh:(hq + 1) * dh]
+                if rope:
+                    qh = _rope_rows(qh, pos_f, dh, rope_base)
+                q_scr[hq] = qh
+                # Softmax INIT from the fresh causal block: query row li
+                # attends suffix keys lj ≤ li (within the real suffix;
+                # windowed models also bound the band). Dead rows (no
+                # valid key) are guarded — m is _NEG_INF and l stays 0.
+                sf = jnp.where(valid, _dot_nt(qh, kf_att) * scale, _NEG_INF)
+                m0 = jnp.max(sf, axis=-1, keepdims=True)
+                m_safe = jnp.where(m0 > _NEG_INF * 0.5, m0, 0.0)
+                p = jnp.where(valid, jnp.exp(sf - m_safe), 0.0)
+                m_scr[hq] = m0
+                l_scr[hq] = jnp.sum(p, axis=-1, keepdims=True)
+                acc_scr[hq] = jnp.dot(
+                    p, vf_att, preferred_element_type=jnp.float32
+                )
+        if kv_q is not None:
+            ksc_out[0, 0] = k_scales
+            vsc_out[0, 0] = v_scales
 
     def _attend():
-        kblk = ck_ref[0, :, 0, :]  # [bc, Dh]
-        vblk = cv_ref[0, :, 0, :]
-        if kv_q is None:
-            kb = kblk.astype(jnp.float32)
-            vb = vblk.astype(jnp.float32)
-        else:
-            # Per-block scales arrive as [bc, Hkv] (all heads — a 2-D
-            # tile); this head's column is selected by an iota mask, the
-            # lane-dynamic-index-free idiom.
-            hsel = (
-                lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1) == hkv
-            ).astype(jnp.float32)
-            ksc = jnp.sum(ks_ref[0] * hsel, axis=-1, keepdims=True)
-            vsc = jnp.sum(vs_ref[0] * hsel, axis=-1, keepdims=True)
-            # Dequantize to the COMPUTE dtype (round-15 rule); the f32
-            # upcast after is the transient dot operand, matching the
-            # XLA engine's f32-promoted score einsum.
-            kb = (kblk.astype(jnp.float32) * ksc).astype(cd).astype(
-                jnp.float32
-            )
-            vb = (vblk.astype(jnp.float32) * vsc).astype(cd).astype(
-                jnp.float32
-            )
-        sblk = jnp.dot(
-            q_scr[:], kb.T, preferred_element_type=jnp.float32
-        ) * scale  # [g, bc]
-        idx = ic * bc + lax.broadcasted_iota(jnp.int32, (g, bc), 1)
+        idx = ic * bc + lax.broadcasted_iota(jnp.int32, (L, bc), 1)
         if rolling:
-            # Rolling slab (windowed models): slot i holds absolute
-            # position length − ((slot − i) mod C) — the
+            # Rolling slab (windowed models, one row per slot): slot i
+            # holds absolute position plen − ((slot − i) mod C) — the
             # models/gpt._decode_block identity — minus the write slot
-            # itself (handled exactly at init; the cache block read here
+            # itself (handled exactly at init; the block read here
             # predates the write).
-            slot = length % cache_len
-            slot_pos = length - jnp.mod(slot - idx, cache_len)
+            slot = plen % cache_len
+            slot_pos = plen - jnp.mod(slot - idx, cache_len)
             valid = (slot_pos >= 0) & (idx != slot)
         else:
-            valid = idx < length
+            valid = idx < plen  # STRICT: the kernel reads the PRE-write cache
             if window is not None:
-                valid &= idx > length - window
-        sblk = jnp.where(valid, sblk, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=-1, keepdims=True))
-        # m is always finite (the fresh-row init), so exp underflows
-        # masked entries to exact zeros; the where is belt-and-braces.
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(sblk - m_new), 0.0)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32
-        )
-        m_scr[:] = m_new
+                valid &= idx > plen + li_col - window
+        for hk in range(hkv_n):
+            kblk = ck_ref[0, 0, :, hk, :]  # [bc, Dh]
+            vblk = cv_ref[0, 0, :, hk, :]
+            if kv_q is None:
+                kb = kblk.astype(jnp.float32)
+                vb = vblk.astype(jnp.float32)
+            else:
+                # Dequantize to the COMPUTE dtype (round-15 rule); the
+                # f32 upcast after is the transient dot operand, matching
+                # the XLA engine's f32-promoted score einsum.
+                ksc = ks_ref[0, 0][:, hk:hk + 1]  # [bc, 1]
+                vsc = vs_ref[0, 0][:, hk:hk + 1]
+                kb = (kblk.astype(jnp.float32) * ksc).astype(cd).astype(
+                    jnp.float32
+                )
+                vb = (vblk.astype(jnp.float32) * vsc).astype(cd).astype(
+                    jnp.float32
+                )
+            for gi in range(g):
+                hq = hk * g + gi
+                sblk = jnp.where(
+                    valid, _dot_nt(q_scr[hq], kb) * scale, _NEG_INF
+                )  # [L, bc]
+                m_prev = m_scr[hq]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(sblk, axis=-1, keepdims=True)
+                )
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.where(valid, jnp.exp(sblk - m_new), 0.0)
+                l_scr[hq] = l_scr[hq] * corr + jnp.sum(
+                    p, axis=-1, keepdims=True
+                )
+                acc_scr[hq] = acc_scr[hq] * corr + jnp.dot(
+                    p, vb, preferred_element_type=jnp.float32
+                )
+                m_scr[hq] = m_new
 
     # Skip cache blocks that cannot hold a valid position (absolute
-    # layouts: written positions are 0..length-1, windowed also
-    # > length-W). Rolling slabs interleave positions across blocks, so
-    # every block is live there.
-    if rolling:
-        live = j < t_att
-    else:
-        live = (j < t_att) & (ic * bc < length)
+    # layouts: written positions are 0..plen-1, windowed also above the
+    # lowest row's band edge plen − W). Rolling slabs interleave
+    # positions across blocks, so every block is live there.
+    live = j < nc
+    if not rolling:
+        live &= ic * bc < plen
         if window is not None:
-            live &= (ic + 1) * bc - 1 > length - window
+            live &= (ic + 1) * bc - 1 > plen - window
     pl.when(live)(_attend)
 
-    @pl.when((j < t_att) & (ic == nc - 1))
-    def _head_end():
-        out_h = acc_scr[:] / l_scr[:]  # l >= exp(m_f - m) > 0 always
-        pl.store(attn_scr, (pl.ds(hkv * g, g), slice(None)), out_h)
-
-    @pl.when(j == t_att)
+    @pl.when(j == nc)
     def _final():
-        attn = attn_scr[:].astype(cd)  # [Hq, Dh]
-        d = wo_ref.shape[1]
-        out = jnp.zeros((1, d), jnp.float32)
-        # attn·wo as a static per-head sum of [1, Dh]·[Dh, d] dots — the
-        # [Hq, Dh] → [1, Hq·Dh] flatten it avoids is a cross-tile
+        wo = wo_ref[0]
+        out = jnp.zeros((L, wo.shape[1]), jnp.float32)
+        # attn·wo as a static per-head sum of [L, Dh]·[Dh, d] dots — the
+        # [Hq, L, Dh] → [L, Hq·Dh] flatten it avoids is a cross-tile
         # relayout.
-        for h in range(hkv_n * g):
+        for hq in range(hq_n):
+            l_h = l_scr[hq]
+            out_h = jnp.where(l_h > 0, acc_scr[hq] / l_h, 0.0)
             out = out + jnp.dot(
-                attn[h:h + 1, :], wo_ref[h * dh:(h + 1) * dh, :],
+                out_h.astype(cd), wo[hq * dh:(hq + 1) * dh, :],
                 preferred_element_type=jnp.float32,
             )
-        h1 = h_ref[:].astype(jnp.float32) + out
-        hn2 = _ln_row(h1, ln2s_ref, ln2b_ref)
+        h1 = h_rows + out
+        hn2 = _ln_rows(h1, ln2s_ref[0], ln2b_ref[0])
         up = jnp.dot(
-            hn2.astype(cd), wup_ref[:], preferred_element_type=jnp.float32
-        ) + bup_ref[:]
+            hn2.astype(cd), wup_ref[0], preferred_element_type=jnp.float32
+        ) + bup_ref[0]
         dn = jnp.dot(
-            jax.nn.gelu(up).astype(cd), wdn_ref[:],
+            jax.nn.gelu(up).astype(cd), wdn_ref[0],
             preferred_element_type=jnp.float32,
-        ) + bdn_ref[:]
-        ho_ref[:] = (h1 + dn).astype(ho_ref.dtype)
+        ) + bdn_ref[0]
+        h_new = h1 + dn
+        h_scr[s_i] = h_new
+        if not commit:
+            ho_ref[0] = h_new
+            return
+
+        # In-kernel commit: the XLA engines' exact scatter index math
+        # (slot = pos % C rolling / pos absolute; paged through the
+        # block table), one [Hkv, Dh] row group per position, as manual
+        # DMAs into the aliased cache outputs. Invalid positions issue
+        # NO DMA — the scatter's drop / write-old-back no-op.
+        is_act = act_ref[s_i] != 0
+        for li in range(L):
+            @pl.when(is_act & (li < slen))
+            def _commit(li=li):
+                pos = plen + li
+                if paged:
+                    row, off = tab_ref[s_i, pos // bc], pos % bc
+                else:
+                    row = s_i
+                    off = pos % cache_len if rolling else pos
+                _dma(kf_dst.at[li], cko.at[l_i, row, off], sem)
+                _dma(vf_dst.at[li], cvo.at[l_i, row, off], sem)
+
+        @pl.when(l_i == n_layers - 1)
+        def _emit():
+            out_scr[...] = h_new
+            _dma(out_scr, ho_any.at[s_i], sem)
 
 
 def _weight_inputs(w: dict, cd):
-    """Order + cast the block weights for the kernel call: projections
-    and FFN weights to the compute dtype (GPTLM._dot's operand cast),
-    layernorm params and biases f32 as [1, n] rows."""
-    row = lambda a: a.astype(jnp.float32).reshape(1, -1)  # noqa: E731
+    """Order + cast the layer-stacked block weights for the launch:
+    projections and FFN matrices to the compute dtype (GPTLM._dot's
+    operand cast), layernorm params and biases f32 as [n_layers, 1, n]
+    rows."""
+    n = w["wq"].shape[0]
     return [
-        w["wq"].astype(cd), w["wk"].astype(cd), w["wv"].astype(cd),
-        w["wo"].astype(cd),
-        row(w["ln1_scale"]), row(w["ln1_bias"]),
-        row(w["ln2_scale"]), row(w["ln2_bias"]),
-        w["w_up"].astype(cd), row(w["b_up"]),
-        w["w_down"].astype(cd), row(w["b_down"]),
+        w[nm].astype(cd) if nm in _MATRICES
+        else w[nm].astype(jnp.float32).reshape(n, 1, -1)
+        for nm in _WEIGHT_NAMES
     ]
 
 
-def _fused_call(
-    h, w, ck, cv, k_scale, v_scale, lengths, tables,
-    *, num_heads, window, rolling, kv_dtype, compute_dtype,
-    rope, rope_base, block_c, cache_len, interpret,
+def _vmem_limit(blocks, scratch_bytes: int) -> int:
+    """Fast-memory budget for the launch: every blocked operand is
+    double-buffered by the pipeline (the next layer's weights stream in
+    while the current one computes), plus the scratch, doubled again as
+    headroom for tile padding and the compiler's own temporaries.
+    Clamped to a v5e core's range (the compiler's default scoped limit
+    is 16 MiB of 128 MiB physical)."""
+    blocked = sum(math.prod(shape) * itemsize for shape, itemsize in blocks)
+    need = 2 * blocked + scratch_bytes
+    return int(min(max(2 * need, 32 << 20), 100 << 20))
+
+
+def _call(
+    h, w, ck, cv, k_scale, v_scale, prefix_lens, suffix_lens, active,
+    tables, *, num_heads, window, rolling, commit, kv_dtype,
+    compute_dtype, rope, rope_base, block_c, interpret,
 ):
-    """Shared launch builder for both cache layouts. ``tables`` is None
-    for the slab (cache indexed [S, C, ...] by slot) or [S, nc] int32
-    for the paged pool (cache indexed [NB, bs, ...] through the
-    scalar-prefetched tables)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    s, d = h.shape
+    """The one launch builder. ``h`` [S, L, d]; ``w`` the layer-STACKED
+    weight dict; ``ck``/``cv`` [n_layers, S, C, Hkv, Dh] (slab) or
+    [n_layers, NB, bs, Hkv, Dh] with ``tables`` [S, max_blocks] (paged;
+    the tables ride as scalar prefetch and the pool gather is index-map
+    arithmetic). ``commit`` selects in-kernel commit through aliased
+    ANY-space cache operands (returns the committed caches) against
+    fresh rows returned as outputs."""
+    interpret = resolve_interpret(interpret)
+    rows = h.shape[1]
+    if rows > 1 and rows % 8:
+        # The chip's compiler slices [L, d] row groups out of tiled
+        # scratch only at the f32 sublane tile (8) or a single row; pad
+        # the verify rows up to it. Padded rows sit past every
+        # ``suffix_len``: no real row attends them and they never commit.
+        h = jnp.pad(h, ((0, 0), (0, -rows % 8), (0, 0)))
+    s, L, d = h.shape
+    n_layers = ck.shape[0]
     hkv_n, dh = ck.shape[-2], ck.shape[-1]
-    g = num_heads // hkv_n
     kv_q = None if kv_dtype == "bf16" else kv_dtype
     paged = tables is not None
     if paged:
-        bc = ck.shape[1]  # pool block size
+        bc = ck.shape[2]  # pool block size
         nc = tables.shape[1]
     else:
-        bc = _pick_cache_block(ck.shape[1], block_c)
-        nc = ck.shape[1] // bc
-    t_total = hkv_n * nc + 1
-    t_att = hkv_n * nc
+        bc = _pick_cache_block(ck.shape[2], block_c)
+        nc = ck.shape[2] // bc
 
-    def _hkv_ic(j):
-        jc = jnp.minimum(j, t_att - 1)
-        return jc // nc, jc % nc
-
-    n_prefetch = 2 if paged else 1
+    def _ic(j):
+        return jnp.minimum(j, nc - 1)
 
     if paged:
-        def cmap(s_i, j, lens, tab):
-            hkv, ic = _hkv_ic(j)
-            return (tab[s_i, ic], 0, hkv, 0)
+        def cmap(l_i, s_i, j, plens, slens, act, tab):
+            return (l_i, tab[s_i, _ic(j)], 0, 0, 0)
 
-        def smap(s_i, j, lens, tab):
-            _, ic = _hkv_ic(j)
-            return (tab[s_i, ic], 0, 0)
+        def smap(l_i, s_i, j, plens, slens, act, tab):
+            return (l_i, tab[s_i, _ic(j)], 0, 0)
     else:
-        def cmap(s_i, j, lens):
-            hkv, ic = _hkv_ic(j)
-            return (s_i, ic, hkv, 0)
+        def cmap(l_i, s_i, j, *pref):
+            return (l_i, s_i, _ic(j), 0, 0)
 
-        def smap(s_i, j, lens):
-            _, ic = _hkv_ic(j)
-            return (s_i, ic, 0)
+        def smap(l_i, s_i, j, *pref):
+            return (l_i, s_i, _ic(j), 0)
 
-    def hmap(s_i, j, *pref):
-        return (s_i, 0)
+    def hmap(l_i, s_i, j, *pref):
+        return (s_i, 0, 0)
 
-    def headmap(s_i, j, *pref):
-        return (0, _hkv_ic(j)[0])
+    def lmap(l_i, s_i, j, *pref):
+        return (l_i, 0, 0)
 
-    def const(s_i, j, *pref):
-        return (0, 0)
-
-    def freshmap(s_i, j, *pref):
-        return (s_i * hkv_n + _hkv_ic(j)[0], 0)
-
-    in_specs = [
-        pl.BlockSpec((1, d), hmap),
-        pl.BlockSpec((d, g * dh), headmap),   # wq columns of this head group
-        pl.BlockSpec((d, dh), headmap),       # wk column
-        pl.BlockSpec((d, dh), headmap),       # wv column
-        pl.BlockSpec((d, d), const),          # wo
-        pl.BlockSpec((1, d), const),          # ln1 scale
-        pl.BlockSpec((1, d), const),          # ln1 bias
-        pl.BlockSpec((1, d), const),          # ln2 scale
-        pl.BlockSpec((1, d), const),          # ln2 bias
-        pl.BlockSpec((d, w["w_up"].shape[-1]), const),
-        pl.BlockSpec((1, w["w_up"].shape[-1]), const),
-        pl.BlockSpec((w["w_down"].shape[-2], d), const),
-        pl.BlockSpec((1, d), const),          # b_down
-        pl.BlockSpec((1, bc, 1, dh), cmap),   # cache K block
-        pl.BlockSpec((1, bc, 1, dh), cmap),   # cache V block
-    ]
-    inputs = [h.astype(jnp.float32)] + _weight_inputs(w, compute_dtype) + [
-        ck, cv,
-    ]
+    weights = _weight_inputs(w, compute_dtype)
+    inputs = [h.astype(jnp.float32)] + weights + [ck, cv]
+    in_specs = [pl.BlockSpec((1, L, d), hmap)]
+    in_specs += [pl.BlockSpec((1,) + a.shape[1:], lmap) for a in weights]
+    in_specs += [pl.BlockSpec((1, 1, bc, hkv_n, dh), cmap)] * 2
     if kv_q is not None:
-        in_specs += [
-            pl.BlockSpec((1, bc, hkv_n), smap),
-            pl.BlockSpec((1, bc, hkv_n), smap),
-        ]
         inputs += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, bc, hkv_n), smap)] * 2
+    blocks = [
+        (spec.block_shape, a.dtype.itemsize)
+        for a, spec in zip(inputs, in_specs, strict=True)
+    ]
+    n_prefetch = 4 if paged else 3
 
-    out_specs = [
-        pl.BlockSpec((1, d), hmap),
-        pl.BlockSpec((1, dh), freshmap),
-        pl.BlockSpec((1, dh), freshmap),
-    ]
     storage = ck.dtype
-    out_shape = [
-        jax.ShapeDtypeStruct((s, d), jnp.float32),
-        jax.ShapeDtypeStruct((s * hkv_n, dh), storage),
-        jax.ShapeDtypeStruct((s * hkv_n, dh), storage),
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((s, L, d), f32),           # residual rows
+        pltpu.VMEM((num_heads, L, dh), f32),  # q, per head
+        pltpu.VMEM((num_heads, L, 1), f32),   # m
+        pltpu.VMEM((num_heads, L, 1), f32),   # l
+        pltpu.VMEM((num_heads, L, dh), f32),  # acc
     ]
+    scratch_bytes = 4 * (s * L * d + 2 * num_heads * L * (dh + 1))
+    if commit:
+        # The alias sources: K and V again, whole-buffer ANY operands
+        # donated into the outputs (alias indices count the
+        # scalar-prefetch operands).
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
+        aliases = {n_prefetch + len(inputs) + i: 1 + i for i in range(2)}
+        in_specs += [any_spec] * 2
+        inputs += [ck, cv]
+        out_specs = [any_spec] * 3
+        out_shape = [
+            jax.ShapeDtypeStruct((s, L, d), f32),
+            jax.ShapeDtypeStruct(ck.shape, storage),
+            jax.ShapeDtypeStruct(cv.shape, storage),
+        ]
+        scratch += [
+            pltpu.VMEM((L, hkv_n, dh), storage),  # fresh K rows (commit src)
+            pltpu.VMEM((L, hkv_n, dh), storage),  # fresh V rows
+            pltpu.VMEM((L, d), f32),              # h_out DMA staging
+            pltpu.SemaphoreType.DMA,
+        ]
+        scratch_bytes += 4 * L * d + 2 * L * hkv_n * dh * storage.itemsize
+    else:
+        aliases = {}
+
+        def omap(l_i, s_i, j, *pref):
+            return (s_i, 0, 0, 0)
+
+        out_specs = [
+            pl.BlockSpec((1, L, d), hmap),
+            pl.BlockSpec((1, L, hkv_n, dh), omap),
+            pl.BlockSpec((1, L, hkv_n, dh), omap),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct((s, L, d), f32),
+            jax.ShapeDtypeStruct((s, L, hkv_n, dh), storage),
+            jax.ShapeDtypeStruct((s, L, hkv_n, dh), storage),
+        ]
     if kv_q is not None:
-        out_specs += [
-            pl.BlockSpec((1, 1), freshmap),
-            pl.BlockSpec((1, 1), freshmap),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((s * hkv_n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((s * hkv_n, 1), jnp.float32),
-        ]
+        # Fresh per-row scales, [n_layers, S, L, Hkv]: a [·, Hkv] row is
+        # narrower than a lane tile, which no manual DMA may slice, so
+        # the scale side tensors are committed after the launch
+        # (_commit_index).
+        def scmap(l_i, s_i, j, *pref):
+            return (l_i, s_i, 0, 0)
+
+        out_specs += [pl.BlockSpec((1, 1, L, hkv_n), scmap)] * 2
+        out_shape += [jax.ShapeDtypeStruct((n_layers, s, L, hkv_n), f32)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
-        grid=(s, t_total),
+        grid=(n_layers, s, nc + 1),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),        # hn (post-LN1 row)
-            pltpu.VMEM((g, dh), jnp.float32),       # q of the current head
-            pltpu.VMEM((g, 1), jnp.float32),        # m
-            pltpu.VMEM((g, 1), jnp.float32),        # l
-            pltpu.VMEM((g, dh), jnp.float32),       # acc
-            pltpu.VMEM((num_heads, dh), jnp.float32),  # per-head attn out
-        ],
+        scratch_shapes=scratch,
     )
     kern = partial(
-        _fused_decode_kernel,
-        nc=nc, hkv_n=hkv_n, g=g, dh=dh, bc=bc, cache_len=cache_len,
-        window=window, rolling=rolling, kv_q=kv_q, cd=compute_dtype,
-        rope=rope, rope_base=rope_base, n_prefetch=n_prefetch,
+        _decode_kernel,
+        n_layers=n_layers, nc=nc, hq_n=num_heads, hkv_n=hkv_n, dh=dh, L=L,
+        bc=bc, cache_len=ck.shape[2], window=window, rolling=rolling,
+        paged=paged, commit=commit, kv_q=kv_q, cd=compute_dtype,
+        rope=rope, rope_base=rope_base,
     )
-    prefetch = (lengths.astype(jnp.int32),)
+    prefetch = [
+        p.astype(jnp.int32) for p in (prefix_lens, suffix_lens, active)
+    ]
     if paged:
-        prefetch += (tables.astype(jnp.int32),)
+        prefetch.append(tables.astype(jnp.int32))
     outs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=tuple(out_shape),
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(blocks, scratch_bytes),
+        ),
         interpret=interpret,
     )(*prefetch, *inputs)
-    if kv_q is not None:
-        ho, kq, vq, ksc, vsc = outs
-        return (
-            ho,
-            kq.reshape(s, hkv_n, dh),
-            vq.reshape(s, hkv_n, dh),
-            ksc.reshape(s, hkv_n),
-            vsc.reshape(s, hkv_n),
-        )
-    ho, kq, vq = outs
-    return ho, kq.reshape(s, hkv_n, dh), vq.reshape(s, hkv_n, dh), None, None
+    ho, nk, nv = outs[0][:, :rows], outs[1], outs[2]
+    if kv_q is None:
+        return ho, nk, nv, None, None
+    if not commit:
+        return ho, nk, nv, outs[3][0], outs[4][0]
+    where = _commit_index(
+        prefix_lens, suffix_lens, active, tables, L, k_scale.shape[1],
+        k_scale.shape[2], rolling,
+    )
+    return (
+        ho, nk, nv,
+        k_scale.at[where].set(outs[3], mode="drop"),
+        v_scale.at[where].set(outs[4], mode="drop"),
+    )
+
+
+def _commit_index(
+    prefix_lens, suffix_lens, active, tables, L, n_rows, c, rolling
+):
+    """Index of the committed positions in a [n_layers, rows, C, ·]
+    cache side tensor — the kernel's own commit rule (row li of slot s
+    lands at position ``prefix + li``; slab slot ``pos % C`` when
+    rolling, else ``pos``; paged through the block table) with every
+    position that must NOT commit (inactive slot, ``li >= suffix_len``)
+    sent past the row axis, where a ``mode="drop"`` scatter discards
+    it."""
+    li = jnp.arange(L)[None]
+    pos = prefix_lens[:, None] + li  # [S, L]
+    valid = (active[:, None] != 0) & (li < suffix_lens[:, None])
+    if tables is None:
+        row = jnp.broadcast_to(jnp.arange(pos.shape[0])[:, None], pos.shape)
+        off = pos % c if rolling else pos
+    else:
+        blk = jnp.minimum(pos // c, tables.shape[1] - 1)
+        row = jnp.take_along_axis(tables, blk, axis=1)
+        off = pos % c
+    return (slice(None), jnp.where(valid, row, n_rows), off)
+
+
+def _block_call(h, weights, ck, cv, k_scale, v_scale, lengths, tables, **kw):
+    """One LAYER, fresh rows returned: the token call's shapes with a
+    leading layer axis of one (free reshapes) and L = 1."""
+    lead = lambda a: None if a is None else a[None]  # noqa: E731
+    ones = jnp.ones_like(lengths)
+    ho, kq, vq, ksc, vsc = _call(
+        h[:, None], jax.tree.map(lead, weights), ck[None], cv[None],
+        lead(k_scale), lead(v_scale), lengths, ones, ones, tables,
+        commit=False, **kw,
+    )
+    squeeze = lambda a: None if a is None else a[:, 0]  # noqa: E731
+    return ho[:, 0], kq[:, 0], vq[:, 0], squeeze(ksc), squeeze(vsc)
 
 
 def decode_block_slab(
@@ -554,12 +683,11 @@ def decode_block_slab(
     caller commits the fresh row with the SAME scatter index math as the
     XLA engine (``models/gpt.py``), which is what keeps the two engines
     attending identical caches."""
-    return _fused_call(
+    return _block_call(
         h, weights, ck, cv, k_scale, v_scale, lengths, None,
         num_heads=num_heads, window=window, rolling=window is not None,
         kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
-        rope_base=rope_base, block_c=block_c, cache_len=ck.shape[1],
-        interpret=interpret,
+        rope_base=rope_base, block_c=block_c, interpret=interpret,
     )
 
 
@@ -593,415 +721,12 @@ def decode_block_paged(
     entries gather garbage blocks the mask keeps out of the softmax.
     Return contract matches :func:`decode_block_slab` (the caller
     commits via ``ops/paged_attention.scatter_token_kv``)."""
-    return _fused_call(
+    return _block_call(
         h, weights, pool_k, pool_v, k_scale, v_scale, lengths, tables,
         num_heads=num_heads, window=window, rolling=False,
         kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
-        rope_base=rope_base, block_c=None, cache_len=pool_k.shape[1],
-        interpret=interpret,
+        rope_base=rope_base, block_c=None, interpret=interpret,
     )
-
-
-# -- round 20: the per-token megakernel tier -------------------------------
-
-
-def _dma(src, dst, sem):
-    """One synchronous manual copy (start + wait) — the in-kernel cache
-    commit's write primitive. Serialized on one DMA semaphore: commits
-    are a few rows per layer, latency-insignificant next to the cache
-    read stream."""
-    cp = pltpu.make_async_copy(src, dst, sem)
-    cp.start()
-    cp.wait()
-
-
-def _mega_decode_kernel(
-    *refs,
-    n_layers: int, nc: int, hkv_n: int, g: int, dh: int, bc: int,
-    cache_len: int, window: int | None, rolling: bool, paged: bool,
-    bs: int, kv_q: str | None, cd, rope: bool, rope_base: float,
-    n_prefetch: int,
-):
-    lens_ref, act_ref = refs[0], refs[1]
-    tab_ref = refs[2] if paged else None
-    i = n_prefetch
-    (h_ref, wq_ref, wk_ref, wv_ref, wo_ref, ln1s_ref, ln1b_ref,
-     ln2s_ref, ln2b_ref, wup_ref, bup_ref, wdn_ref, bdn_ref,
-     ck_ref, cv_ref) = refs[i:i + 15]
-    i += 15
-    if kv_q is not None:
-        ks_ref, vs_ref = refs[i:i + 2]
-        i += 2
-    # ANY-space alias sources: unused in the body (their whole purpose
-    # is donating the cache buffers into the outputs).
-    i += 2 if kv_q is None else 4
-    if kv_q is not None:
-        ho_any, cko, cvo, kso, vso = refs[i:i + 5]
-        i += 5
-    else:
-        ho_any, cko, cvo = refs[i:i + 3]
-        kso = vso = None
-        i += 3
-    (h_scr, hn_scr, q_scr, m_scr, l_scr, acc_scr, attn_scr,
-     kf_scr, vf_scr) = refs[i:i + 9]
-    i += 9
-    if kv_q is not None:
-        ksc_scr, vsc_scr = refs[i:i + 2]
-        i += 2
-    else:
-        ksc_scr = vsc_scr = None
-    out_scr, sem = refs[i], refs[i + 1]
-
-    l_i = pl.program_id(0)
-    s_i = pl.program_id(1)
-    j = pl.program_id(2)
-    t_att = hkv_n * nc
-    jc = jnp.minimum(j, t_att - 1)
-    hkv = jc // nc
-    ic = jc % nc
-    length = lens_ref[s_i]
-    is_act = act_ref[s_i] != 0
-    scale = 1.0 / math.sqrt(dh)
-
-    @pl.when((l_i == 0) & (j == 0))
-    def _seed_residual():
-        pl.store(h_scr, (pl.ds(s_i, 1), slice(None)), h_ref[:])
-
-    h_row = pl.load(h_scr, (pl.ds(s_i, 1), slice(None)))  # [1, d] f32
-
-    @pl.when(j == 0)
-    def _ln1():
-        hn_scr[:] = _ln_row(h_row, ln1s_ref[0], ln1b_ref[0])
-
-    @pl.when((j < t_att) & (ic == 0))
-    def _head_start():
-        # Identical math to _fused_decode_kernel's head start, with the
-        # weight blocks carrying a leading streamed-layer axis and the
-        # fresh quantized rows landing in scratch for the commit DMA.
-        hn = hn_scr[:].astype(cd)
-        wq = wq_ref[0]
-        for gi in range(g):
-            q_scr[gi:gi + 1, :] = jnp.dot(
-                hn, wq[:, gi * dh:(gi + 1) * dh],
-                preferred_element_type=jnp.float32,
-            )
-        kf = jnp.dot(hn, wk_ref[0], preferred_element_type=jnp.float32)
-        vf = jnp.dot(hn, wv_ref[0], preferred_element_type=jnp.float32)
-        if rope:
-            pos_f = length.astype(jnp.float32)
-            q_scr[:] = _rope_rows(q_scr[:], pos_f, dh, rope_base)
-            kf = _rope_rows(kf, pos_f, dh, rope_base)
-        if kv_q is None:
-            kq_row = kf.astype(kf_scr.dtype)
-            vq_row = vf.astype(vf_scr.dtype)
-            kf_att = kq_row.astype(jnp.float32)
-            vf_att = vq_row.astype(jnp.float32)
-        else:
-            kq_row, k_sc = _quant_row(kf, kv_q)
-            vq_row, v_sc = _quant_row(vf, kv_q)
-            kf_att = (kq_row.astype(jnp.float32) * k_sc).astype(cd).astype(
-                jnp.float32
-            )
-            vf_att = (vq_row.astype(jnp.float32) * v_sc).astype(cd).astype(
-                jnp.float32
-            )
-            # Head column selected by iota mask — scale scratch is a
-            # [1, Hkv] row, no lane-dynamic store.
-            col = lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1) == hkv
-            ksc_scr[:] = jnp.where(col, k_sc[0, 0], ksc_scr[:])
-            vsc_scr[:] = jnp.where(col, v_sc[0, 0], vsc_scr[:])
-        pl.store(kf_scr, (pl.ds(hkv, 1), slice(None)), kq_row)
-        pl.store(vf_scr, (pl.ds(hkv, 1), slice(None)), vq_row)
-        sf = jnp.sum(q_scr[:] * kf_att, axis=-1, keepdims=True) * scale
-        m_scr[:] = sf
-        l_scr[:] = jnp.ones_like(l_scr)
-        acc_scr[:] = jnp.broadcast_to(vf_att, acc_scr.shape)
-
-    def _attend():
-        kblk = ck_ref[0, 0, :, 0, :]  # [bc, Dh]
-        vblk = cv_ref[0, 0, :, 0, :]
-        if kv_q is None:
-            kb = kblk.astype(jnp.float32)
-            vb = vblk.astype(jnp.float32)
-        else:
-            hsel = (
-                lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1) == hkv
-            ).astype(jnp.float32)
-            ksc = jnp.sum(ks_ref[0, 0] * hsel, axis=-1, keepdims=True)
-            vsc = jnp.sum(vs_ref[0, 0] * hsel, axis=-1, keepdims=True)
-            kb = (kblk.astype(jnp.float32) * ksc).astype(cd).astype(
-                jnp.float32
-            )
-            vb = (vblk.astype(jnp.float32) * vsc).astype(cd).astype(
-                jnp.float32
-            )
-        sblk = jnp.dot(
-            q_scr[:], kb.T, preferred_element_type=jnp.float32
-        ) * scale  # [g, bc]
-        idx = ic * bc + lax.broadcasted_iota(jnp.int32, (g, bc), 1)
-        if rolling:
-            slot = length % cache_len
-            slot_pos = length - jnp.mod(slot - idx, cache_len)
-            valid = (slot_pos >= 0) & (idx != slot)
-        else:
-            valid = idx < length
-            if window is not None:
-                valid &= idx > length - window
-        sblk = jnp.where(valid, sblk, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(sblk - m_new), 0.0)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32
-        )
-        m_scr[:] = m_new
-
-    if rolling:
-        live = j < t_att
-    else:
-        live = (j < t_att) & (ic * bc < length)
-        if window is not None:
-            live &= (ic + 1) * bc - 1 > length - window
-    pl.when(live)(_attend)
-
-    @pl.when((j < t_att) & (ic == nc - 1))
-    def _head_end():
-        out_h = acc_scr[:] / l_scr[:]  # l >= exp(m_f - m) > 0 always
-        pl.store(attn_scr, (pl.ds(hkv * g, g), slice(None)), out_h)
-
-    @pl.when(j == t_att)
-    def _final():
-        attn = attn_scr[:].astype(cd)  # [Hq, Dh]
-        wo = wo_ref[0]
-        d = wo.shape[1]
-        out = jnp.zeros((1, d), jnp.float32)
-        for h in range(hkv_n * g):
-            out = out + jnp.dot(
-                attn[h:h + 1, :], wo[h * dh:(h + 1) * dh, :],
-                preferred_element_type=jnp.float32,
-            )
-        h1 = h_row + out
-        hn2 = _ln_row(h1, ln2s_ref[0], ln2b_ref[0])
-        up = jnp.dot(
-            hn2.astype(cd), wup_ref[0], preferred_element_type=jnp.float32
-        ) + bup_ref[0]
-        dn = jnp.dot(
-            jax.nn.gelu(up).astype(cd), wdn_ref[0],
-            preferred_element_type=jnp.float32,
-        ) + bdn_ref[0]
-        h_new = h1 + dn
-        pl.store(h_scr, (pl.ds(s_i, 1), slice(None)), h_new)
-
-        # In-kernel commit: the XLA engines' exact scatter index math
-        # (slot = length % C rolling / length absolute; paged through
-        # the block table at position length), as manual DMAs into the
-        # aliased cache outputs. Inactive rows SKIP — the scatter's
-        # drop / write-old-back no-op, bit-for-bit.
-        @pl.when(is_act)
-        def _commit():
-            if paged:
-                blk_i = tab_ref[s_i, length // bs]
-                off = length % bs
-                _dma(kf_scr, cko.at[l_i, blk_i, off], sem)
-                _dma(vf_scr, cvo.at[l_i, blk_i, off], sem)
-                if kv_q is not None:
-                    _dma(ksc_scr, kso.at[l_i, blk_i, pl.ds(off, 1)], sem)
-                    _dma(vsc_scr, vso.at[l_i, blk_i, pl.ds(off, 1)], sem)
-            else:
-                slot = length % cache_len if rolling else length
-                _dma(kf_scr, cko.at[l_i, s_i, slot], sem)
-                _dma(vf_scr, cvo.at[l_i, s_i, slot], sem)
-                if kv_q is not None:
-                    _dma(ksc_scr, kso.at[l_i, s_i, pl.ds(slot, 1)], sem)
-                    _dma(vsc_scr, vso.at[l_i, s_i, pl.ds(slot, 1)], sem)
-
-        @pl.when(l_i == n_layers - 1)
-        def _emit():
-            out_scr[:] = h_new
-            _dma(out_scr, ho_any.at[pl.ds(s_i, 1)], sem)
-
-
-def _stacked_weight_inputs(w: dict, cd):
-    """Layer-stacked counterpart of :func:`_weight_inputs`: every leaf
-    keeps its leading [n_layers] axis (the streamed dimension);
-    projections cast to the compute dtype, layernorm/bias rows f32 as
-    [n_layers, 1, n]."""
-    n = w["wq"].shape[0]
-    row = lambda a: a.astype(jnp.float32).reshape(n, 1, -1)  # noqa: E731
-    return [
-        w["wq"].astype(cd), w["wk"].astype(cd), w["wv"].astype(cd),
-        w["wo"].astype(cd),
-        row(w["ln1_scale"]), row(w["ln1_bias"]),
-        row(w["ln2_scale"]), row(w["ln2_bias"]),
-        w["w_up"].astype(cd), row(w["b_up"]),
-        w["w_down"].astype(cd), row(w["b_down"]),
-    ]
-
-
-def _stacked_weight_specs(w, d, g, dh, headmap, lconst):
-    """BlockSpecs streaming ONE layer's weights per grid step: every
-    map leads with the layer coordinate, so Mosaic double-buffers the
-    next layer's blocks while the current one computes — the VMEM
-    budget is per-layer, not per-model."""
-    return [
-        pl.BlockSpec((1, d, g * dh), headmap),  # wq columns of the head group
-        pl.BlockSpec((1, d, dh), headmap),      # wk column
-        pl.BlockSpec((1, d, dh), headmap),      # wv column
-        pl.BlockSpec((1, d, d), lconst),        # wo
-        pl.BlockSpec((1, 1, d), lconst),        # ln1 scale
-        pl.BlockSpec((1, 1, d), lconst),        # ln1 bias
-        pl.BlockSpec((1, 1, d), lconst),        # ln2 scale
-        pl.BlockSpec((1, 1, d), lconst),        # ln2 bias
-        pl.BlockSpec((1, d, w["w_up"].shape[-1]), lconst),
-        pl.BlockSpec((1, 1, w["w_up"].shape[-1]), lconst),
-        pl.BlockSpec((1, w["w_down"].shape[-2], d), lconst),
-        pl.BlockSpec((1, 1, d), lconst),        # b_down
-    ]
-
-
-def _mega_call(
-    h, w, ck, cv, k_scale, v_scale, lengths, active, tables,
-    *, num_heads, window, rolling, kv_dtype, compute_dtype,
-    rope, rope_base, block_c, cache_len, interpret,
-):
-    """Launch builder for the multi-layer megakernel: ONE launch per
-    token over grid ``(n_layers, S, Hkv·nc + 1)``. ``ck``/``cv`` (and
-    scales) are the FULL layer-stacked cache arrays; they enter the
-    call twice — blocked read operands plus ANY-space operands aliased
-    onto the outputs (``input_output_aliases``; alias indices count the
-    scalar-prefetch operands) — and come back committed."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    s, d = h.shape
-    n_layers = ck.shape[0]
-    hkv_n, dh = ck.shape[-2], ck.shape[-1]
-    g = num_heads // hkv_n
-    kv_q = None if kv_dtype == "bf16" else kv_dtype
-    paged = tables is not None
-    if paged:
-        bc = ck.shape[2]  # pool block size
-        nc = tables.shape[1]
-    else:
-        bc = _pick_cache_block(ck.shape[2], block_c)
-        nc = ck.shape[2] // bc
-    t_total = hkv_n * nc + 1
-    t_att = hkv_n * nc
-
-    def _hkv_ic(j):
-        jc = jnp.minimum(j, t_att - 1)
-        return jc // nc, jc % nc
-
-    n_prefetch = 3 if paged else 2
-
-    if paged:
-        def cmap(l_i, s_i, j, lens, act, tab):
-            hkv, ic = _hkv_ic(j)
-            return (l_i, tab[s_i, ic], 0, hkv, 0)
-
-        def smap(l_i, s_i, j, lens, act, tab):
-            _, ic = _hkv_ic(j)
-            return (l_i, tab[s_i, ic], 0, 0)
-    else:
-        def cmap(l_i, s_i, j, lens, act):
-            hkv, ic = _hkv_ic(j)
-            return (l_i, s_i, ic, hkv, 0)
-
-        def smap(l_i, s_i, j, lens, act):
-            _, ic = _hkv_ic(j)
-            return (l_i, s_i, ic, 0)
-
-    def hmap(l_i, s_i, j, *pref):
-        return (s_i, 0)
-
-    def headmap(l_i, s_i, j, *pref):
-        return (l_i, 0, _hkv_ic(j)[0])
-
-    def lconst(l_i, s_i, j, *pref):
-        return (l_i, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, d), hmap)]
-    in_specs += _stacked_weight_specs(w, d, g, dh, headmap, lconst)
-    in_specs += [
-        pl.BlockSpec((1, 1, bc, 1, dh), cmap),  # cache K block
-        pl.BlockSpec((1, 1, bc, 1, dh), cmap),  # cache V block
-    ]
-    inputs = [h.astype(jnp.float32)]
-    inputs += _stacked_weight_inputs(w, compute_dtype)
-    inputs += [ck, cv]
-    if kv_q is not None:
-        in_specs += [
-            pl.BlockSpec((1, 1, bc, hkv_n), smap),
-            pl.BlockSpec((1, 1, bc, hkv_n), smap),
-        ]
-        inputs += [k_scale, v_scale]
-    # The alias sources: same arrays again, whole-buffer ANY operands.
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    n_alias = 2 if kv_q is None else 4
-    alias_base = n_prefetch + len(inputs)
-    in_specs += [any_spec] * n_alias
-    inputs += [ck, cv] if kv_q is None else [ck, cv, k_scale, v_scale]
-
-    out_specs = [any_spec] * (1 + n_alias)
-    out_shape = [jax.ShapeDtypeStruct((s, d), jnp.float32)]
-    out_shape += [
-        jax.ShapeDtypeStruct(a.shape, a.dtype)
-        for a in ([ck, cv] if kv_q is None else [ck, cv, k_scale, v_scale])
-    ]
-    aliases = {alias_base + i: 1 + i for i in range(n_alias)}
-
-    storage = ck.dtype
-    scratch = [
-        pltpu.VMEM((s, d), jnp.float32),           # residual rows
-        pltpu.VMEM((1, d), jnp.float32),           # hn (post-LN1 row)
-        pltpu.VMEM((g, dh), jnp.float32),          # q of the current head
-        pltpu.VMEM((g, 1), jnp.float32),           # m
-        pltpu.VMEM((g, 1), jnp.float32),           # l
-        pltpu.VMEM((g, dh), jnp.float32),          # acc
-        pltpu.VMEM((num_heads, dh), jnp.float32),  # per-head attn out
-        pltpu.VMEM((hkv_n, dh), storage),          # fresh K rows (commit src)
-        pltpu.VMEM((hkv_n, dh), storage),          # fresh V rows
-    ]
-    if kv_q is not None:
-        scratch += [
-            pltpu.VMEM((1, hkv_n), jnp.float32),   # fresh K scales
-            pltpu.VMEM((1, hkv_n), jnp.float32),   # fresh V scales
-        ]
-    scratch += [
-        pltpu.VMEM((1, d), jnp.float32),           # h_out DMA staging
-        pltpu.SemaphoreType.DMA,
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(n_layers, s, t_total),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    kern = partial(
-        _mega_decode_kernel,
-        n_layers=n_layers, nc=nc, hkv_n=hkv_n, g=g, dh=dh, bc=bc,
-        cache_len=cache_len, window=window, rolling=rolling, paged=paged,
-        bs=bc if paged else 0, kv_q=kv_q, cd=compute_dtype,
-        rope=rope, rope_base=rope_base, n_prefetch=n_prefetch,
-    )
-    prefetch = (lengths.astype(jnp.int32), active.astype(jnp.int32))
-    if paged:
-        prefetch += (tables.astype(jnp.int32),)
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=tuple(out_shape),
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*prefetch, *inputs)
-    if kv_q is not None:
-        ho, nk, nv, nks, nvs = outs
-        return ho, nk, nv, nks, nvs
-    ho, nk, nv = outs
-    return ho, nk, nv, None, None
 
 
 def decode_token_slab(
@@ -1034,13 +759,15 @@ def decode_token_slab(
     commit — the scatter no-op, in-kernel). Returns
     ``(h_out [S, d] f32, ck', cv', k_scale', v_scale')`` with the fresh
     rows ALREADY committed at the XLA engine's exact indices."""
-    return _mega_call(
-        h, weights, ck, cv, k_scale, v_scale, lengths, active, None,
+    ho, *caches = _call(
+        h[:, None], weights, ck, cv, k_scale, v_scale, lengths,
+        jnp.ones_like(lengths), active, None,
         num_heads=num_heads, window=window, rolling=window is not None,
-        kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
-        rope_base=rope_base, block_c=block_c, cache_len=ck.shape[2],
+        commit=True, kv_dtype=kv_dtype, compute_dtype=compute_dtype,
+        rope=rope, rope_base=rope_base, block_c=block_c,
         interpret=interpret,
     )
+    return (ho[:, 0], *caches)
 
 
 def decode_token_paged(
@@ -1070,236 +797,14 @@ def decode_token_paged(
     sentinel itself never materializes: no DMA is issued at all).
     Active slots never share writable blocks (the serve_pool allocator
     invariant), so in-kernel writes stay disjoint from every read."""
-    return _mega_call(
-        h, weights, pool_k, pool_v, k_scale, v_scale, lengths, active,
-        tables,
-        num_heads=num_heads, window=window, rolling=False,
+    ho, *caches = _call(
+        h[:, None], weights, pool_k, pool_v, k_scale, v_scale, lengths,
+        jnp.ones_like(lengths), active, tables,
+        num_heads=num_heads, window=window, rolling=False, commit=True,
         kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
-        rope_base=rope_base, block_c=None, cache_len=pool_k.shape[2],
-        interpret=interpret,
+        rope_base=rope_base, block_c=None, interpret=interpret,
     )
-
-
-def _verify_kernel(
-    *refs,
-    n_layers: int, nc: int, hkv_n: int, g: int, dh: int, L: int,
-    window: int | None, bs: int, kv_q: str | None, cd, rope: bool,
-    rope_base: float,
-):
-    plen_ref, slen_ref, act_ref, tab_ref = refs[:4]
-    i = 4
-    (h_ref, wq_ref, wk_ref, wv_ref, wo_ref, ln1s_ref, ln1b_ref,
-     ln2s_ref, ln2b_ref, wup_ref, bup_ref, wdn_ref, bdn_ref,
-     ck_ref, cv_ref) = refs[i:i + 15]
-    i += 15
-    if kv_q is not None:
-        ks_ref, vs_ref = refs[i:i + 2]
-        i += 2
-    i += 2 if kv_q is None else 4  # ANY-space alias sources, unread
-    if kv_q is not None:
-        ho_any, cko, cvo, kso, vso = refs[i:i + 5]
-        i += 5
-    else:
-        ho_any, cko, cvo = refs[i:i + 3]
-        kso = vso = None
-        i += 3
-    (h_scr, hn_scr, q_scr, m_scr, l_scr, acc_scr, attn_scr,
-     kf_scr, vf_scr) = refs[i:i + 9]
-    i += 9
-    if kv_q is not None:
-        ksc_scr, vsc_scr = refs[i:i + 2]
-        i += 2
-    else:
-        ksc_scr = vsc_scr = None
-    out_scr, sem = refs[i], refs[i + 1]
-
-    l_i = pl.program_id(0)
-    s_i = pl.program_id(1)
-    j = pl.program_id(2)
-    t_att = hkv_n * nc
-    jc = jnp.minimum(j, t_att - 1)
-    hkv = jc // nc
-    ic = jc % nc
-    plen = plen_ref[s_i]
-    slen = slen_ref[s_i]
-    is_act = act_ref[s_i] != 0
-    scale = 1.0 / math.sqrt(dh)
-    # Row r of the [g·L, …] q tiles is suffix position r % L of head
-    # hkv·g + r // L.
-    li_col = lax.broadcasted_iota(jnp.int32, (g * L, 1), 0) % L
-
-    @pl.when((l_i == 0) & (j == 0))
-    def _seed_residual():
-        pl.store(h_scr, (pl.ds(s_i * L, L), slice(None)), h_ref[0])
-
-    h_rows = pl.load(h_scr, (pl.ds(s_i * L, L), slice(None)))  # [L, d] f32
-
-    @pl.when(j == 0)
-    def _ln1():
-        hn_scr[:] = _ln_row(h_rows, ln1s_ref[0], ln1b_ref[0])
-
-    @pl.when((j < t_att) & (ic == 0))
-    def _head_start():
-        hn = hn_scr[:].astype(cd)
-        wq = wq_ref[0]
-        for gi in range(g):
-            q_scr[gi * L:(gi + 1) * L, :] = jnp.dot(
-                hn, wq[:, gi * dh:(gi + 1) * dh],
-                preferred_element_type=jnp.float32,
-            )
-        kf = jnp.dot(hn, wk_ref[0], preferred_element_type=jnp.float32)
-        vf = jnp.dot(hn, wv_ref[0], preferred_element_type=jnp.float32)
-        if rope:
-            plen_f = plen.astype(jnp.float32)
-            q_scr[:] = _rope_rows(
-                q_scr[:], plen_f + li_col.astype(jnp.float32), dh, rope_base
-            )
-            pos_k = plen_f + lax.broadcasted_iota(
-                jnp.float32, (L, 1), 0
-            )
-            kf = _rope_rows(kf, pos_k, dh, rope_base)
-        if kv_q is None:
-            kq_rows = kf.astype(kf_scr.dtype)
-            vq_rows = vf.astype(vf_scr.dtype)
-            kf_att = kq_rows.astype(jnp.float32)
-            vf_att = vq_rows.astype(jnp.float32)
-        else:
-            kq_rows, k_sc = _quant_row(kf, kv_q)  # [L, dh], [L, 1]
-            vq_rows, v_sc = _quant_row(vf, kv_q)
-            kf_att = (kq_rows.astype(jnp.float32) * k_sc).astype(cd).astype(
-                jnp.float32
-            )
-            vf_att = (vq_rows.astype(jnp.float32) * v_sc).astype(cd).astype(
-                jnp.float32
-            )
-            col = lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1) == hkv
-            ksc_scr[:] = jnp.where(col, k_sc, ksc_scr[:])
-            vsc_scr[:] = jnp.where(col, v_sc, vsc_scr[:])
-        pl.store(kf_scr, (pl.ds(hkv * L, L), slice(None)), kq_rows)
-        pl.store(vf_scr, (pl.ds(hkv * L, L), slice(None)), vq_rows)
-        # Softmax INIT from the fresh causal block: query row li attends
-        # suffix keys lj ≤ li (within the real suffix; windowed models
-        # also bound the band). Dead rows (no valid key) are guarded —
-        # their m is _NEG_INF and their l stays 0.
-        sf = jnp.dot(
-            q_scr[:], kf_att.T, preferred_element_type=jnp.float32
-        ) * scale  # [g·L, L]
-        lj = lax.broadcasted_iota(jnp.int32, (g * L, L), 1)
-        valid = (lj <= li_col) & (lj < slen)
-        if window is not None:
-            valid &= lj > li_col - window
-        sf = jnp.where(valid, sf, _NEG_INF)
-        m0 = jnp.max(sf, axis=-1, keepdims=True)
-        m_safe = jnp.where(m0 > _NEG_INF * 0.5, m0, 0.0)
-        p = jnp.where(valid, jnp.exp(sf - m_safe), 0.0)
-        m_scr[:] = m0
-        l_scr[:] = jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = jnp.dot(p, vf_att, preferred_element_type=jnp.float32)
-
-    def _attend():
-        kblk = ck_ref[0, 0, :, 0, :]  # [bs, Dh]
-        vblk = cv_ref[0, 0, :, 0, :]
-        if kv_q is None:
-            kb = kblk.astype(jnp.float32)
-            vb = vblk.astype(jnp.float32)
-        else:
-            hsel = (
-                lax.broadcasted_iota(jnp.int32, (1, hkv_n), 1) == hkv
-            ).astype(jnp.float32)
-            ksc = jnp.sum(ks_ref[0, 0] * hsel, axis=-1, keepdims=True)
-            vsc = jnp.sum(vs_ref[0, 0] * hsel, axis=-1, keepdims=True)
-            kb = (kblk.astype(jnp.float32) * ksc).astype(cd).astype(
-                jnp.float32
-            )
-            vb = (vblk.astype(jnp.float32) * vsc).astype(cd).astype(
-                jnp.float32
-            )
-        sblk = jnp.dot(
-            q_scr[:], kb.T, preferred_element_type=jnp.float32
-        ) * scale  # [g·L, bs]
-        idx = ic * bs + lax.broadcasted_iota(jnp.int32, (g * L, bs), 1)
-        valid = idx < plen  # STRICT: the kernel reads the PRE-write pool
-        if window is not None:
-            valid &= idx > plen + li_col - window
-        sblk = jnp.where(valid, sblk, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(sblk - m_new), 0.0)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32
-        )
-        m_scr[:] = m_new
-
-    live = (j < t_att) & (ic * bs < plen)
-    if window is not None:
-        # A row's band low edge is plen + li − W; the lowest (most
-        # permissive) belongs to li = 0 — skip only blocks that sit
-        # below EVERY row's band.
-        live &= (ic + 1) * bs - 1 > plen - window
-    pl.when(live)(_attend)
-
-    @pl.when((j < t_att) & (ic == nc - 1))
-    def _head_end():
-        out_h = jnp.where(l_scr[:] > 0, acc_scr[:] / l_scr[:], 0.0)
-        pl.store(attn_scr, (pl.ds(hkv * g * L, g * L), slice(None)), out_h)
-
-    @pl.when(j == t_att)
-    def _final():
-        attn = attn_scr[:].astype(cd)  # [Hq·L, Dh]
-        wo = wo_ref[0]
-        d = wo.shape[1]
-        out = jnp.zeros((L, d), jnp.float32)
-        for h in range(hkv_n * g):
-            out = out + jnp.dot(
-                attn[h * L:(h + 1) * L, :], wo[h * dh:(h + 1) * dh, :],
-                preferred_element_type=jnp.float32,
-            )
-        h1 = h_rows + out
-        hn2 = _ln_row(h1, ln2s_ref[0], ln2b_ref[0])
-        up = jnp.dot(
-            hn2.astype(cd), wup_ref[0], preferred_element_type=jnp.float32
-        ) + bup_ref[0]
-        dn = jnp.dot(
-            jax.nn.gelu(up).astype(cd), wdn_ref[0],
-            preferred_element_type=jnp.float32,
-        ) + bdn_ref[0]
-        h_new = h1 + dn
-        pl.store(h_scr, (pl.ds(s_i * L, L), slice(None)), h_new)
-
-        # Per-position commit: extend_paged's scatter validity is
-        # token_mask & admit = (li < slen) & active — invalid positions
-        # issue NO DMA (the sentinel-drop no-op, bit-for-bit).
-        for li in range(L):
-            @pl.when(is_act & (li < slen))
-            def _commit(li=li):
-                pos = plen + li
-                blk_i = tab_ref[s_i, pos // bs]
-                off = pos % bs
-                for hk in range(hkv_n):
-                    _dma(
-                        kf_scr.at[pl.ds(hk * L + li, 1)],
-                        cko.at[l_i, blk_i, off, pl.ds(hk, 1)], sem,
-                    )
-                    _dma(
-                        vf_scr.at[pl.ds(hk * L + li, 1)],
-                        cvo.at[l_i, blk_i, off, pl.ds(hk, 1)], sem,
-                    )
-                if kv_q is not None:
-                    _dma(
-                        ksc_scr.at[pl.ds(li, 1)],
-                        kso.at[l_i, blk_i, pl.ds(off, 1)], sem,
-                    )
-                    _dma(
-                        vsc_scr.at[pl.ds(li, 1)],
-                        vso.at[l_i, blk_i, pl.ds(off, 1)], sem,
-                    )
-
-        @pl.when(l_i == n_layers - 1)
-        def _emit():
-            out_scr[:] = h_new
-            _dma(out_scr, ho_any.at[s_i], sem)
+    return (ho[:, 0], *caches)
 
 
 def verify_tokens_paged(
@@ -1338,126 +843,10 @@ def verify_tokens_paged(
     ``(h_out [S, L, d] f32, pool_k', pool_v', k_scale', v_scale')`` with
     valid rows committed at extend_paged's exact indices; lengths and
     tables stay caller-owned (the round-11 commit contract)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    s, L, d = h.shape
-    n_layers = pool_k.shape[0]
-    hkv_n, dh = pool_k.shape[-2], pool_k.shape[-1]
-    g = num_heads // hkv_n
-    kv_q = None if kv_dtype == "bf16" else kv_dtype
-    bs = pool_k.shape[2]
-    nc = tables.shape[1]
-    t_total = hkv_n * nc + 1
-    t_att = hkv_n * nc
-
-    def _hkv_ic(j):
-        jc = jnp.minimum(j, t_att - 1)
-        return jc // nc, jc % nc
-
-    def cmap(l_i, s_i, j, plens, slens, act, tab):
-        hkv, ic = _hkv_ic(j)
-        return (l_i, tab[s_i, ic], 0, hkv, 0)
-
-    def smap(l_i, s_i, j, plens, slens, act, tab):
-        _, ic = _hkv_ic(j)
-        return (l_i, tab[s_i, ic], 0, 0)
-
-    def hmap(l_i, s_i, j, *pref):
-        return (s_i, 0, 0)
-
-    def headmap(l_i, s_i, j, *pref):
-        return (l_i, 0, _hkv_ic(j)[0])
-
-    def lconst(l_i, s_i, j, *pref):
-        return (l_i, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, L, d), hmap)]
-    in_specs += _stacked_weight_specs(weights, d, g, dh, headmap, lconst)
-    in_specs += [
-        pl.BlockSpec((1, 1, bs, 1, dh), cmap),
-        pl.BlockSpec((1, 1, bs, 1, dh), cmap),
-    ]
-    inputs = [h.astype(jnp.float32)]
-    inputs += _stacked_weight_inputs(weights, compute_dtype)
-    inputs += [pool_k, pool_v]
-    if kv_q is not None:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, hkv_n), smap),
-            pl.BlockSpec((1, 1, bs, hkv_n), smap),
-        ]
-        inputs += [k_scale, v_scale]
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    n_alias = 2 if kv_q is None else 4
-    n_prefetch = 4
-    alias_base = n_prefetch + len(inputs)
-    in_specs += [any_spec] * n_alias
-    inputs += (
-        [pool_k, pool_v]
-        if kv_q is None
-        else [pool_k, pool_v, k_scale, v_scale]
+    return _call(
+        h, weights, pool_k, pool_v, k_scale, v_scale, prefix_lens,
+        suffix_lens, active, tables,
+        num_heads=num_heads, window=window, rolling=False, commit=True,
+        kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
+        rope_base=rope_base, block_c=None, interpret=interpret,
     )
-
-    out_specs = [any_spec] * (1 + n_alias)
-    out_shape = [jax.ShapeDtypeStruct((s, L, d), jnp.float32)]
-    out_shape += [
-        jax.ShapeDtypeStruct(a.shape, a.dtype)
-        for a in (
-            [pool_k, pool_v]
-            if kv_q is None
-            else [pool_k, pool_v, k_scale, v_scale]
-        )
-    ]
-    aliases = {alias_base + i: 1 + i for i in range(n_alias)}
-
-    storage = pool_k.dtype
-    scratch = [
-        pltpu.VMEM((s * L, d), jnp.float32),
-        pltpu.VMEM((L, d), jnp.float32),
-        pltpu.VMEM((g * L, dh), jnp.float32),
-        pltpu.VMEM((g * L, 1), jnp.float32),
-        pltpu.VMEM((g * L, 1), jnp.float32),
-        pltpu.VMEM((g * L, dh), jnp.float32),
-        pltpu.VMEM((num_heads * L, dh), jnp.float32),
-        pltpu.VMEM((hkv_n * L, dh), storage),
-        pltpu.VMEM((hkv_n * L, dh), storage),
-    ]
-    if kv_q is not None:
-        scratch += [
-            pltpu.VMEM((L, hkv_n), jnp.float32),
-            pltpu.VMEM((L, hkv_n), jnp.float32),
-        ]
-    scratch += [
-        pltpu.VMEM((L, d), jnp.float32),
-        pltpu.SemaphoreType.DMA,
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(n_layers, s, t_total),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    kern = partial(
-        _verify_kernel,
-        n_layers=n_layers, nc=nc, hkv_n=hkv_n, g=g, dh=dh, L=L,
-        window=window, bs=bs, kv_q=kv_q, cd=compute_dtype,
-        rope=rope, rope_base=rope_base,
-    )
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=tuple(out_shape),
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(
-        prefix_lens.astype(jnp.int32),
-        suffix_lens.astype(jnp.int32),
-        active.astype(jnp.int32),
-        tables.astype(jnp.int32),
-        *inputs,
-    )
-    if kv_q is not None:
-        return outs
-    ho, nk, nv = outs
-    return ho, nk, nv, None, None

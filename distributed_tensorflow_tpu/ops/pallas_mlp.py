@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_tensorflow_tpu.models.mlp import MLPParams
+from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
 
 _LOG_EPS = 1e-30
 
@@ -117,8 +118,7 @@ def make_fused_train_step(
     """Build ``step(fused_state, x, y) -> (fused_state, cost)``, one kernel
     launch per call. ``interpret=None`` auto-selects the Pallas interpreter
     off-TPU (CI / CPU-mesh tests) and the Mosaic compiler on TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     f32 = jnp.float32
     call = pl.pallas_call(
@@ -290,8 +290,7 @@ def make_fused_epoch_fn(
     per-grid-step overhead is already hidden behind the batch-block DMA,
     and bigger blocks pipeline worse; see docs/performance.md).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     call = _epoch_call(
         steps=steps,
         batch_size=batch_size,
@@ -353,8 +352,7 @@ def make_fused_async_epoch_fn(
     """
     from jax.sharding import PartitionSpec as P
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     # Exchange cadence must match _scan_with_exchange exactly: rounds only
     # when a full avg_every round fits (an epoch shorter than avg_every
     # runs plain, with NO exchange — strategy.py:82's `steps >= avg_every`).
@@ -477,8 +475,7 @@ def make_fused_compiled_run_fn(
     Eval runs in f32 jnp ops on the current params (same math as
     ``MLP(compute_dtype=f32).apply``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     from distributed_tensorflow_tpu.train.compiled_run import wrapped_epoch_perm
 

@@ -253,9 +253,7 @@ def ring_flash_attention(
     # over — under a 2-D dp×sp shard_map that is {data, seq}, not just the
     # ring axis (jax.typeof reads the tracer's vma; a plain jit gives the
     # empty set plus the ring axis).
-    from distributed_tensorflow_tpu.ops.collectives import _vma_of
-
-    vma = _vma_of(q) | {axis_name}
+    vma = jax.typeof(q).vma | {axis_name}
     kw = dict(block_q=block_q, block_k=block_k, vma=tuple(vma))
 
     pvary = partial(to_varying, axis_name=(axis_name,))

@@ -16,6 +16,7 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libdtf_runtime.so")
+_SRC = os.path.join(_DIR, "csrc", "dtf_runtime.cc")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
@@ -34,22 +35,37 @@ def _build() -> bool:
         return False
 
 
+def _out_of_date() -> bool:
+    """True when there is no library, or its source is newer — the
+    library is git-ignored, so a copied tree can carry one built from
+    sources that have since changed."""
+    try:
+        return os.path.getmtime(_SO) < os.path.getmtime(_SRC)
+    except OSError:
+        return not os.path.exists(_SO)
+
+
 def load_library() -> ctypes.CDLL:
     """Load (building if needed) the native library; raises ImportError if
-    unavailable so callers can fall back to pure Python. A stale ``.so``
-    built from older sources (missing newer symbols) is rebuilt once; if
-    symbols are still missing the failure surfaces as ImportError so the
-    pure-Python fallbacks engage rather than AttributeError escaping."""
+    unavailable so callers can fall back to pure Python. A library older
+    than its source is rebuilt first (``make`` owns the dependency); one
+    that cannot be rebuilt is NOT loaded. A ``.so`` missing newer symbols
+    is rebuilt once; if symbols are still missing the failure surfaces as
+    ImportError so the pure-Python fallbacks engage rather than
+    AttributeError escaping."""
     global _lib, _tried
     with _lock:
         if _lib is not None:
             return _lib
         if os.environ.get("DTF_NO_NATIVE"):
             raise ImportError("native runtime disabled via DTF_NO_NATIVE")
-        if not os.path.exists(_SO):
-            if _tried or not _build():
+        if _out_of_date():
+            if _tried or not _build() or _out_of_date():
                 _tried = True
-                raise ImportError("libdtf_runtime.so unavailable (build failed)")
+                raise ImportError(
+                    "libdtf_runtime.so missing or older than its source "
+                    "(build failed)"
+                )
         _tried = True
         lib = ctypes.CDLL(_SO)
         try:

@@ -125,6 +125,7 @@ from distributed_tensorflow_tpu.serve_pool import RequestCancelled, RequestShed
 from distributed_tensorflow_tpu.train import failpoints, resilience
 from distributed_tensorflow_tpu.train.elastic import (
     ElasticAgent,
+    children_platform,
     HttpHealth,
     WorkerFailure,
 )
@@ -1796,6 +1797,10 @@ def local_fleet(
         router_kw.setdefault("migrate_dir", migrate_dir)
     run_id = f"fleet-{os.getpid()}"
     journal = EventJournal.in_dir(fleet_dir, run_id=run_id)
+    platform = children_platform(
+        {**os.environ, **(env or {})}, replicas, "local_fleet"
+    )
+    print_fn(f"local_fleet: {replicas} replica processes on {platform}")
     handles = []
     for i in range(replicas):
         name = f"replica{i}"
@@ -1928,6 +1933,11 @@ def run_replica(args) -> int:
         TextServer,
     )
 
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     obs_journal_mod.configure_from_env(announce=True)
     model = _model_from_kw(json.loads(args.model))
     buckets = (

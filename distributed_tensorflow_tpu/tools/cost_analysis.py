@@ -34,33 +34,12 @@ from distributed_tensorflow_tpu.ops import cross_entropy, sgd
 from distributed_tensorflow_tpu.parallel.strategy import SingleDevice
 
 # Peak numbers for rooflining, per chip. Sources: public TPU spec sheets
-# (bf16 matmul peak / HBM bandwidth). "cpu" is a rough placeholder so the
-# tool classifies in CPU test environments.
+# (bf16 matmul peak / HBM bandwidth). A device that is not listed —
+# the CPU the tests run on included — classifies as ``bound="unknown"``.
 CHIP_PEAKS = {
     "tpu v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
     "tpu v4": {"flops": 275e12, "hbm_bytes_per_s": 1228e9},
-    "cpu": {"flops": 1e11, "hbm_bytes_per_s": 5e10},
 }
-
-
-def measured_ceiling_tflops() -> float | None:
-    """The MEASURED bf16 ceiling from the committed roofline record
-    (docs/benchmarks/roofline_tpu.json), or None. Every MFU*-style column
-    must divide by THIS, not a hardcoded constant — a roofline re-measure
-    has to propagate to every committed table or the records silently mix
-    denominators (round-5 review finding)."""
-    import json as _json
-    import os as _os
-
-    path = _os.path.join(
-        _os.path.dirname(__file__), "..", "..", "docs", "benchmarks",
-        "roofline_tpu.json",
-    )
-    try:
-        with open(path) as f:
-            return _json.load(f).get("ceiling_bf16_tflops")
-    except Exception:
-        return None
 
 
 def _chip_peaks(device) -> dict | None:
@@ -79,8 +58,6 @@ def _roofline(compiled, batch_size: int, device) -> dict:
     balance-point classification and per-step floor — used verbatim by the
     classifier and LM analyzers so the two can't drift."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     intensity = flops / bytes_accessed if bytes_accessed else float("inf")

@@ -412,6 +412,11 @@ def launch(
     base_env = dict(os.environ)
     if env:
         base_env.update(env)
+    # Every task here runs on THIS host, and a chip has one owner.
+    from distributed_tensorflow_tpu.train.elastic import children_platform
+
+    platform = children_platform(base_env, num_workers, "launch_local")
+    print_fn(f"launch_local: {num_workers} worker processes on {platform}")
     # ps tasks no-op and exit on TPU: launch one-shot, never supervised —
     # a clean ps exit must not read as a gang failure, and a gang restart
     # must not respawn them.

@@ -121,6 +121,39 @@ from distributed_tensorflow_tpu.train import failpoints, resilience
 from distributed_tensorflow_tpu.utils.summary import lifecycle_event
 
 
+
+def children_platform(env: dict, count: int, who: str) -> str:
+    """The platform ``count`` child processes started with ``env`` will
+    run on, after checking that no chip gets a second owner. A chip
+    belongs to one process: a parent that has touched JAX holds it, and a
+    child that needs it then fails or hangs; and without a per-process
+    device choice (not built — ROADMAP R3) every accelerator child on one
+    host would open every local chip. So local children are CPU processes
+    by an explicit ``JAX_PLATFORMS=cpu`` in their environment, or there is
+    exactly one of them under a parent that never imported JAX. ``who``
+    names the launcher in the refusal."""
+    import sys
+
+    platform = env.get("JAX_PLATFORMS", "")
+    if platform == "cpu":
+        return "cpu"
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            f"{who}: this process has imported JAX and may hold the chip, "
+            "so children that are not CPU processes would fail or hang "
+            "opening it; put JAX_PLATFORMS=cpu in their environment, or "
+            "launch from a process that never imports JAX"
+        )
+    if count > 1:
+        raise RuntimeError(
+            f"{who}: {count} processes on platform "
+            f"{platform or 'default'!r} would each open every local chip; "
+            "per-process device choice is not built (ROADMAP R3) — "
+            "launch one, or CPU processes with JAX_PLATFORMS=cpu"
+        )
+    return platform or "default"
+
+
 class WorkerFailure(RuntimeError):
     """One or more gang members died or stalled. ``verdicts`` maps member
     name → verdict string (``rc=N``, ``dead``, ``stalled``, ``straggler``

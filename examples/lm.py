@@ -26,6 +26,9 @@ from distributed_tensorflow_tpu.config import TrainConfig
 from distributed_tensorflow_tpu.data import copy_corpus
 from distributed_tensorflow_tpu.models.gpt import GPTLM
 from distributed_tensorflow_tpu.train import LMTrainer
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    configure_compile_cache,
+)
 
 
 def main(epochs: int = 8, max_new: int = 16) -> None:
@@ -78,5 +81,6 @@ def main(epochs: int = 8, max_new: int = 16) -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     argv = [int(a) for a in sys.argv[1:3]]
     main(*argv)

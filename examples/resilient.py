@@ -21,8 +21,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 from distributed_tensorflow_tpu.config import TrainConfig
 from distributed_tensorflow_tpu.launch import build_trainer, config_from_env
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    configure_compile_cache,
+)
 
 if __name__ == "__main__":
+    configure_compile_cache()
     config = TrainConfig(
         checkpoint_dir="./checkpoints_resilient",
         keep_last_n=3,          # GC old steps; the last valid one survives

@@ -32,6 +32,9 @@ from distributed_tensorflow_tpu.data import (
 from distributed_tensorflow_tpu.models.gpt import GPTLM
 from distributed_tensorflow_tpu.serve import GenerationConfig, TextServer
 from distributed_tensorflow_tpu.train import LMTrainer
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    configure_compile_cache,
+)
 
 
 def main(epochs: int = 4, max_new: int = 32) -> None:
@@ -86,5 +89,6 @@ def main(epochs: int = 4, max_new: int = 32) -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     argv = [int(a) for a in sys.argv[1:3]]
     main(*argv)

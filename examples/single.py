@@ -14,7 +14,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 from distributed_tensorflow_tpu.config import TrainConfig
 from distributed_tensorflow_tpu.launch import build_trainer, config_from_env
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    configure_compile_cache,
+)
 
 if __name__ == "__main__":
+    configure_compile_cache()
     trainer = build_trainer(config_from_env(TrainConfig()))
     trainer.run()

@@ -31,6 +31,9 @@ from distributed_tensorflow_tpu.data import (
 )
 from distributed_tensorflow_tpu.models.gpt import GPTLM
 from distributed_tensorflow_tpu.train import LMTrainer
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    configure_compile_cache,
+)
 
 
 def main(epochs: int = 6, max_new: int = 48, bpe_merges: int = 0) -> None:
@@ -82,5 +85,6 @@ def main(epochs: int = 6, max_new: int = 48, bpe_merges: int = 0) -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     argv = [int(a) for a in sys.argv[1:4]]
     main(*argv)

@@ -1,0 +1,200 @@
+"""Ask the chip's compiler, without the chip.
+
+Every Pallas kernel on the main path is compiled here for a DESCRIBED
+TPU v5e (``topologies.get_topology_desc("tpu", "v5e:2x2")``) at the
+widths ``chip_smoke.py`` runs: the installed TPU compiler refuses what
+the interpreter tolerates — a block whose last two dimensions break the
+(8, 128) tiling, a slice narrower than a lane tile, more fast memory than
+a kernel may use. Nothing runs, so these cases say nothing about results
+or times; a compile that passes is not a chip run. They guard every
+later change at no chip time.
+
+The file skips itself where the topology cannot be described (no TPU
+compiler installed). The persistent compile cache is off around the
+cases: an executable compiled for a described device is written to it
+but cannot be read back without a chip.
+"""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from distributed_tensorflow_tpu.ops import pallas_decode, pallas_mlp  # noqa: E402
+from distributed_tensorflow_tpu.ops.pallas_attention import flash_attention  # noqa: E402
+from distributed_tensorflow_tpu.ops.pallas_mode import has_compiled_kernel  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip, as a sharding for abstract arguments."""
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args) -> str:
+    """Compile for the described chip; returns the program text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# -- training side: flash attention at the full-width shapes, MLP kernels ----
+
+B, L, H, DH = 8, 2048, 16, 128  # gpt-xl-L2048-flash-remat's attention
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd-split", "bwd-fused"])
+def test_flash_attention_compiles_at_full_width(chip, which):
+    q = jax.ShapeDtypeStruct((B, L, H, DH), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v, fused=None):
+        return flash_attention(
+            q, k, v, causal=True, interpret=False, fused=fused
+        )
+
+    if which == "fwd":
+        fn = fwd
+    else:
+        fused = which == "bwd-fused"
+        fn = jax.grad(
+            lambda q, k, v: fwd(q, k, v, fused).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )
+    assert has_compiled_kernel(_compile(fn, q, q, q))
+
+
+@pytest.mark.parametrize("which", ["epoch", "per-step"])
+def test_mlp_kernels_compile(chip, which):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)  # noqa: E731
+    state = pallas_mlp.FusedState(
+        f32(784, 100), f32(1, 100), f32(100, 10), f32(1, 10)
+    )
+    if which == "epoch":  # bench.py: five 550-step epochs, bf16 stream
+        steps, stream = 2750, jnp.bfloat16
+        fn = pallas_mlp.make_fused_epoch_fn(
+            steps=steps, batch_size=100, stream_dtype=stream, interpret=False
+        )
+    else:
+        steps, stream = 550, jnp.float32
+        fn = pallas_mlp.make_fused_scanned_fn(batch_size=100, interpret=False)
+    xs = jax.ShapeDtypeStruct((steps, 100, 784), stream, sharding=chip)
+    ys = jax.ShapeDtypeStruct((steps, 100, 10), stream, sharding=chip)
+    assert has_compiled_kernel(_compile(fn, state, xs, ys))
+
+
+# -- serving side: the decode kernels at d=512 (gpt-m) -----------------------
+
+D, LAYERS, CACHE, SLOTS, POOL, BS = 512, 8, 1024, 8, 256, 16
+
+
+def _decode_call(entry: str, heads: int, kv: str, chip, kv_heads=None):
+    """(fn, args) for one ops/pallas_decode entry point at d=512 with
+    ``heads`` query heads (``kv_heads`` KV heads, default MHA) and ``kv``
+    cache dtype; abstract arguments only."""
+    dh = D // heads
+    kv_heads = kv_heads or heads
+    token = entry.startswith(("decode_token", "verify"))
+    paged = entry.endswith("paged")
+    lead = (LAYERS,) if token else ()
+    arr = lambda dt, *s: jax.ShapeDtypeStruct(lead + s, dt, sharding=chip)  # noqa: E731
+    flat = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=chip)  # noqa: E731
+    f32, i32 = jnp.float32, jnp.int32
+    weights = dict(
+        wq=arr(f32, D, D), wk=arr(f32, D, kv_heads * dh),
+        wv=arr(f32, D, kv_heads * dh),
+        wo=arr(f32, D, D), ln1_scale=arr(f32, D), ln1_bias=arr(f32, D),
+        ln2_scale=arr(f32, D), ln2_bias=arr(f32, D),
+        w_up=arr(f32, D, 4 * D), b_up=arr(f32, 4 * D),
+        w_down=arr(f32, 4 * D, D), b_down=arr(f32, D),
+    )
+    rows = (POOL, BS) if paged else (SLOTS, CACHE)
+    storage = jnp.int8 if kv == "int8" else jnp.bfloat16
+    cache = arr(storage, *rows, kv_heads, dh)
+    scale = arr(f32, *rows, kv_heads) if kv == "int8" else None
+    lens, act = flat(i32, SLOTS), flat(jnp.bool_, SLOTS)
+    tables = flat(i32, SLOTS, CACHE // BS)
+    kw = dict(num_heads=heads, kv_dtype=kv, interpret=False)
+    fn = getattr(pallas_decode, entry)
+    if entry == "verify_tokens_paged":
+        h = flat(f32, SLOTS, 4, D)  # spec_draft=3 → 4 rows per slot
+        args = (h, weights, cache, cache, scale, scale, tables, lens, lens, act)
+    else:
+        h = flat(f32, SLOTS, D)
+        args = (h, weights, cache, cache, scale, scale)
+        args += (tables,) if paged else ()
+        args += (lens, act) if token else (lens,)
+    return (lambda *a: fn(*a, **kw)), args
+
+
+_ENTRIES = (
+    "decode_block_slab", "decode_block_paged", "decode_token_slab",
+    "decode_token_paged", "verify_tokens_paged",
+)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("entry", _ENTRIES)
+def test_decode_kernels_compile_at_head_dim_128(chip, entry, kv):
+    """All five entry points at 4 heads — the width `decode_engine="auto"`
+    resolves to the megakernel at (GPTLM._resolve_decode_engine)."""
+    fn, args = _decode_call(entry, 4, kv, chip)
+    assert has_compiled_kernel(_compile(fn, *args))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("entry", _ENTRIES[:2])
+def test_per_layer_kernels_compile_at_head_dim_64(chip, entry, kv):
+    """gpt-m's own 8 heads: the per-layer kernel (`"pallas-layer"`)."""
+    fn, args = _decode_call(entry, 8, kv, chip)
+    assert has_compiled_kernel(_compile(fn, *args))
+
+
+@pytest.mark.parametrize("entry", _ENTRIES[2:])
+def test_megakernel_is_refused_at_head_dim_64(chip, entry):
+    """The other side of the auto rule (GPTLM._megakernel_compiles): at
+    head_dim 64 the in-kernel commit is refused by the compiler, in its
+    own words — which is what an explicit `decode_engine="pallas"` raises
+    there. When this stops failing, let `auto` admit the width."""
+    fn, args = _decode_call(entry, 8, "bf16", chip)
+    with pytest.raises(Exception, match="aligned to tiling|shape cast"):
+        _compile(fn, *args)
+
+
+def test_megakernel_is_refused_at_two_kv_heads_int8(chip):
+    """The rule's second half: a one-byte cache packs four rows to a
+    sublane tile, so a [2, Dh] commit row group cannot be sliced."""
+    fn, args = _decode_call("decode_token_paged", 4, "int8", chip, kv_heads=2)
+    with pytest.raises(Exception, match=r"aligned to tiling \(4\)"):
+        _compile(fn, *args)
+
+
+def test_auto_rule_matches_what_compiles():
+    from distributed_tensorflow_tpu.models.gpt import GPTLM
+
+    def admits(**kw):
+        return GPTLM(model_dim=512, **kw)._megakernel_compiles()
+
+    assert admits(num_heads=4)
+    assert not admits(num_heads=8)                   # head_dim 64
+    assert not admits(num_heads=4, num_kv_heads=2)   # two-row commit group

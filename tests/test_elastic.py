@@ -762,6 +762,28 @@ def test_launch_local_rejects_unsupervised_elastic(tmp_path):
         )
 
 
+def test_launch_local_refuses_a_chip_gang_on_one_host(tmp_path, monkeypatch):
+    """A chip belongs to one process: several local workers on anything
+    but JAX_PLATFORMS=cpu would each open every local chip, so the
+    launcher refuses before spawning; a CPU gang says what it is."""
+    from distributed_tensorflow_tpu.tools.launch_local import launch
+
+    import sys
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="has imported JAX"):
+        launch(["true"], 1, logdir=str(tmp_path / "a"))
+    monkeypatch.delitem(sys.modules, "jax")  # a lean driver never imports it
+    with pytest.raises(RuntimeError, match="would each open every local chip"):
+        launch(["true"], 2, logdir=str(tmp_path / "a"))
+    lines = []
+    assert launch(
+        ["true"], 2, logdir=str(tmp_path / "b"),
+        env={"JAX_PLATFORMS": "cpu"}, print_fn=lines.append,
+    ) == 0
+    assert lines[0] == "launch_local: 2 worker processes on cpu"
+
+
 def test_launch_local_cli_defaults_from_env(monkeypatch):
     """A pod scheduler's DTF_* env arms the elastic driver with no flag
     changes (the TrainConfig.max_restarts / config_from_env mirror)."""

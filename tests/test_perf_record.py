@@ -1,30 +1,49 @@
-"""Number-of-record freshness (round 8, VERDICT r5 weak #6): the perf
-docs' bench citation is GENERATED from the newest ``BENCH_r*.json`` and
-this module pins the committed docs against the newest committed
-artifact — landing a new driver artifact without running
-``perf_record --write-docs`` fails here instead of shipping a stale
-number-of-record. No jax needed (pure file checks)."""
+"""Number-of-record freshness (round 8, VERDICT r5 weak #6): a perf
+doc's bench citation is GENERATED from the newest ``BENCH_r*.json`` beside
+it, and ``perf_record`` pins docs against the newest artifact — landing a
+new driver artifact without running ``perf_record --write-docs`` is caught
+instead of shipping a stale number-of-record. The driver's own artifacts
+left the repo with PR 22 (their logs named an installation that is gone;
+the ledger replaces them), so these cases build their artifacts and docs
+under ``tmp_path``. No jax needed (pure file checks)."""
 
 import json
 import os
 
+import pytest
+
 from distributed_tensorflow_tpu.tools import perf_record
 
 
-def test_latest_bench_resolves_highest_round():
-    latest = perf_record.latest_bench()
-    assert latest is not None
-    name, parsed = latest
-    # Highest-numbered artifact at the repo root wins.
-    rounds = [
-        int(f[7:-5])
-        for f in os.listdir(perf_record.repo_root())
-        if f.startswith("BENCH_r") and f.endswith(".json")
-    ]
-    assert name == f"BENCH_r{max(rounds):02d}.json" or name == (
-        f"BENCH_r{max(rounds)}.json"
+def _bench(root, n, value, impl="pallas-epoch"):
+    payload = {"rc": 0, "parsed": {
+        "value": value, "vs_baseline": value / 42000.0, "impl": impl,
+    }}
+    (root / f"BENCH_r{n:02d}.json").write_text(json.dumps(payload))
+
+
+@pytest.fixture
+def record_root(tmp_path):
+    """A repo-shaped root: three driver artifacts and the three docs that
+    carry a bench-record span, citing a STALE artifact."""
+    for n, value in ((1, 1.0e7), (2, 9.5e6), (10, 5.9e7)):
+        _bench(tmp_path, n, value)
+    stale = perf_record.citation(
+        "BENCH_r01.json", {"value": 1.0e7, "vs_baseline": 238.1, "impl": "x"}
     )
-    assert parsed["value"] > 0 and "impl" in parsed
+    for rel in perf_record.DOC_FILES:
+        path = tmp_path / rel
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(f"# a doc\n\nbefore {stale} after\n")
+    return tmp_path
+
+
+def test_latest_bench_resolves_highest_round(record_root):
+    name, parsed = perf_record.latest_bench(str(record_root))
+    # Highest-NUMBERED artifact wins (r10 over r02 — numeric, not lexical).
+    assert name == "BENCH_r10.json"
+    assert parsed["value"] == 5.9e7 and "impl" in parsed
+    assert perf_record.latest_bench(str(record_root / "docs")) is None
 
 
 def test_latest_bench_skips_unparseable(tmp_path):
@@ -37,16 +56,20 @@ def test_latest_bench_skips_unparseable(tmp_path):
     assert name == "BENCH_r01.json"  # r02/r03 carry no parseable metric
 
 
-def test_committed_docs_cite_newest_artifact():
-    stale = perf_record.check_docs()
-    assert not stale, (
-        f"stale bench-record citations in {stale}; run "
-        "python -m distributed_tensorflow_tpu.tools.perf_record --write-docs"
-    )
+def test_committed_docs_cite_newest_artifact(record_root):
+    root = str(record_root)
+    assert perf_record.check_docs(root) == list(perf_record.DOC_FILES)
+    assert perf_record.write_docs(root, print_fn=lambda *a: None) is True
+    assert perf_record.check_docs(root) == []
+    text = (record_root / "README.md").read_text()
+    assert "BENCH_r10.json" in text and "BENCH_r01.json" not in text
+    assert text.startswith("# a doc\n\nbefore ") and text.endswith(" after\n")
 
 
-def test_write_docs_is_idempotent():
-    assert perf_record.write_docs(print_fn=lambda *a: None) is False
+def test_write_docs_is_idempotent(record_root):
+    root = str(record_root)
+    perf_record.write_docs(root, print_fn=lambda *a: None)
+    assert perf_record.write_docs(root, print_fn=lambda *a: None) is False
 
 
 def test_lm_phases_docs_match_committed_artifact(tmp_path):
@@ -56,9 +79,6 @@ def test_lm_phases_docs_match_committed_artifact(tmp_path):
     (round 13: the plain-vs-selective backward pair) cannot land without
     regenerating the doc — the serving.md staleness discipline."""
     from distributed_tensorflow_tpu.tools import lm_phase_bench
-    from distributed_tensorflow_tpu.tools.cost_analysis import (
-        measured_ceiling_tflops,
-    )
 
     root = os.path.abspath(
         os.path.join(
@@ -71,7 +91,9 @@ def test_lm_phases_docs_match_committed_artifact(tmp_path):
     with open(os.path.join(root, "lm_phases.md")) as f:
         committed = f.read()
     table = lm_phase_bench.render(payload["rows"])
-    lm_phase_bench._write_md(str(tmp_path), table, measured_ceiling_tflops())
+    lm_phase_bench._write_md(
+        str(tmp_path), table, lm_phase_bench.recorded_ceiling(payload["rows"])
+    )
     with open(tmp_path / "lm_phases.md") as f:
         regenerated = f.read()
     assert regenerated == committed, (

@@ -223,6 +223,36 @@ def test_stale_library_missing_symbols_raises_importerror(tmp_path, monkeypatch)
     assert lib.dtf_crc32c(b"x", 1) != 0
 
 
+def test_library_older_than_source_is_rebuilt(tmp_path, monkeypatch):
+    """The library is git-ignored and a copied tree can carry one built
+    from older sources: a source newer than the library triggers the
+    on-demand build, and a library that cannot be rebuilt is not loaded."""
+    import shutil
+
+    native.load_library()  # ensure the real library exists on disk
+    so = tmp_path / "libdtf_runtime.so"
+    shutil.copy(native._SO, so)
+    src = tmp_path / "dtf_runtime.cc"
+    src.write_text("// newer than the library\n")
+    os.utime(so, (1, 1))
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+
+    monkeypatch.setattr(native, "_build", lambda: False)
+    with pytest.raises(ImportError, match="older than its source"):
+        native.load_library()
+
+    built = []
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(
+        native, "_build", lambda: bool(built.append(1) or os.utime(so) or True)
+    )
+    assert native.load_library().dtf_crc32c(b"x", 1) != 0
+    assert built == [1]
+
+
 def test_native_crc32c_matches_python_table():
     pytest.importorskip("distributed_tensorflow_tpu.runtime.native")
     from distributed_tensorflow_tpu.runtime import native
