@@ -34,6 +34,7 @@ import numpy as np
 from jax import lax
 
 from distributed_tensorflow_tpu.models.base import layernorm as _layernorm
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops import pallas_mode
 from distributed_tensorflow_tpu.ops.collectives import to_varying
 from distributed_tensorflow_tpu.ops.quantized import (
@@ -708,9 +709,10 @@ class GPTLM:
                 f"sequence length {tokens.shape[1]} exceeds max_len "
                 f"{self.max_len}"
             )
-        h = params.embed[tokens]
-        if self.pos_embedding == "learned":
-            h = h + jnp.take(params.pos, positions, axis=0)
+        with jax.named_scope(names.EMBED):
+            h = params.embed[tokens]
+            if self.pos_embedding == "learned":
+                h = h + jnp.take(params.pos, positions, axis=0)
         return h
 
     def _moe_capacity(self, tokens: int) -> int:
@@ -807,26 +809,34 @@ class GPTLM:
         truth for the block, so sp==dense and ep==dense stay pinned by
         construction."""
         b, l, d = h.shape
-        hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
-        kv_shape = (b, l, self.num_kv_heads, self.head_dim)
-        q = self._dot(hn, blk.wq).reshape(b, l, self.num_heads, self.head_dim)
-        k = self._dot(hn, blk.wk).reshape(kv_shape)
-        v = self._dot(hn, blk.wv).reshape(kv_shape)
-        if self.pos_embedding == "rope":
-            q = _rope(q, positions)
-            k = _rope(k, positions)
-        attn = (attend or self._attend)(q, k, v)
-        h = h + self._dot(attn.reshape(b, l, d), blk.wo)
-        hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
-        if ffn is not None:
-            ffn_out, aux = ffn(blk, hn2)
-        else:
-            ffn_out, aux = self._ffn(blk, hn2, token_mask)
-        return h + ffn_out, (k, v), aux
+        with jax.named_scope(names.ATTN_QKV):
+            hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
+            kv_shape = (b, l, self.num_kv_heads, self.head_dim)
+            q = self._dot(hn, blk.wq).reshape(
+                b, l, self.num_heads, self.head_dim
+            )
+            k = self._dot(hn, blk.wk).reshape(kv_shape)
+            v = self._dot(hn, blk.wv).reshape(kv_shape)
+            if self.pos_embedding == "rope":
+                q = _rope(q, positions)
+                k = _rope(k, positions)
+        with jax.named_scope(names.ATTN_CORE):
+            attn = (attend or self._attend)(q, k, v)
+        with jax.named_scope(names.ATTN_OUT):
+            h = h + self._dot(attn.reshape(b, l, d), blk.wo)
+        with jax.named_scope(names.MLP):
+            hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
+            if ffn is not None:
+                ffn_out, aux = ffn(blk, hn2)
+            else:
+                ffn_out, aux = self._ffn(blk, hn2, token_mask)
+            h = h + ffn_out
+        return h, (k, v), aux
 
     def _logits(self, p: GPTLMParams, h):
-        hf = _layernorm(h, p.lnf_scale, p.lnf_bias)
-        return self._dot_full(hf, p.embed.T)
+        with jax.named_scope(names.LM_HEAD):
+            hf = _layernorm(h, p.lnf_scale, p.lnf_bias)
+            return self._dot_full(hf, p.embed.T)
 
     # -- training forward --------------------------------------------------
 
@@ -1281,18 +1291,19 @@ class GPTLM:
         rows = jnp.arange(ck0.shape[0])
         c = self.cache_len
         slot = lengths % c if self.window is not None else lengths
-        kw = jnp.where(act[:, None, None], kq, ck0[rows, slot])
-        vw = jnp.where(act[:, None, None], vq, cv0[rows, slot])
-        ck = ck0.at[rows, slot].set(kw)
-        cv = cv0.at[rows, slot].set(vw)
-        if ks0 is None:
-            return ck, cv, None, None
-        nks = ks0.at[rows, slot].set(
-            jnp.where(act[:, None], ksc, ks0[rows, slot])
-        )
-        nvs = vs0.at[rows, slot].set(
-            jnp.where(act[:, None], vsc, vs0[rows, slot])
-        )
+        with jax.named_scope(names.KV_WRITE):
+            kw = jnp.where(act[:, None, None], kq, ck0[rows, slot])
+            vw = jnp.where(act[:, None, None], vq, cv0[rows, slot])
+            ck = ck0.at[rows, slot].set(kw)
+            cv = cv0.at[rows, slot].set(vw)
+            if ks0 is None:
+                return ck, cv, None, None
+            nks = ks0.at[rows, slot].set(
+                jnp.where(act[:, None], ksc, ks0[rows, slot])
+            )
+            nvs = vs0.at[rows, slot].set(
+                jnp.where(act[:, None], vsc, vs0[rows, slot])
+            )
         return ck, cv, nks, nvs
 
     def _commit_paged_rows(
@@ -1351,21 +1362,26 @@ class GPTLM:
             return h, kv
 
         h, (ks, vs) = lax.scan(body, h, params.blocks)
-        ks = ks.astype(self.compute_dtype)
-        vs = vs.astype(self.compute_dtype)
         c = self.cache_len
-        if l <= c:
-            pad = [(0, 0), (0, 0), (0, c - l), (0, 0), (0, 0)]
-            # Positions land at slot pos % c = pos (l <= c): plain pad.
-            ck, cv = jnp.pad(ks, pad), jnp.pad(vs, pad)
-        else:
-            # Rolling: keep the last c positions at slots pos % c (static
-            # index arrays — l and c are compile-time).
-            ps = np.arange(l - c, l)
-            slots = ps % c
-            shape = ks.shape[:2] + (c,) + ks.shape[3:]
-            ck = jnp.zeros(shape, ks.dtype).at[:, :, slots].set(ks[:, :, ps])
-            cv = jnp.zeros(shape, vs.dtype).at[:, :, slots].set(vs[:, :, ps])
+        with jax.named_scope(names.KV_WRITE):
+            ks = ks.astype(self.compute_dtype)
+            vs = vs.astype(self.compute_dtype)
+            if l <= c:
+                pad = [(0, 0), (0, 0), (0, c - l), (0, 0), (0, 0)]
+                # Positions land at slot pos % c = pos (l <= c): plain pad.
+                ck, cv = jnp.pad(ks, pad), jnp.pad(vs, pad)
+            else:
+                # Rolling: keep the last c positions at slots pos % c
+                # (static index arrays — l and c are compile-time).
+                ps = np.arange(l - c, l)
+                slots = ps % c
+                shape = ks.shape[:2] + (c,) + ks.shape[3:]
+                ck = jnp.zeros(shape, ks.dtype).at[:, :, slots].set(
+                    ks[:, :, ps]
+                )
+                cv = jnp.zeros(shape, vs.dtype).at[:, :, slots].set(
+                    vs[:, :, ps]
+                )
         cache = KVCache(k=ck, v=cv, length=jnp.asarray(l, jnp.int32))
         return self._logits(params, h)[:, -1], cache
 
@@ -1374,20 +1390,24 @@ class GPTLM:
         Dh] (this layer's cache). Returns (h, updated ck, updated cv)."""
         b = h.shape[0]
         c = self.cache_len
-        hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
-        kv_shape = (b, 1, self.num_kv_heads, self.head_dim)
-        q = self._dot(hn, blk.wq).reshape(b, 1, self.num_heads, self.head_dim)
-        k = self._dot(hn, blk.wk).reshape(kv_shape)
-        v = self._dot(hn, blk.wv).reshape(kv_shape)
-        if self.pos_embedding == "rope":
-            pos1 = jnp.reshape(length, (1,))
-            q = _rope(q, pos1)
-            k = _rope(k, pos1)
-        k = k.astype(ck.dtype)
-        v = v.astype(cv.dtype)
+        with jax.named_scope(names.ATTN_QKV):
+            hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
+            kv_shape = (b, 1, self.num_kv_heads, self.head_dim)
+            q = self._dot(hn, blk.wq).reshape(
+                b, 1, self.num_heads, self.head_dim
+            )
+            k = self._dot(hn, blk.wk).reshape(kv_shape)
+            v = self._dot(hn, blk.wv).reshape(kv_shape)
+            if self.pos_embedding == "rope":
+                pos1 = jnp.reshape(length, (1,))
+                q = _rope(q, pos1)
+                k = _rope(k, pos1)
         slot = length % c if self.window is not None else length
-        ck = lax.dynamic_update_slice(ck, k, (0, slot, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v, (0, slot, 0, 0))
+        with jax.named_scope(names.KV_WRITE):
+            k = k.astype(ck.dtype)
+            v = v.astype(cv.dtype)
+            ck = lax.dynamic_update_slice(ck, k, (0, slot, 0, 0))
+            cv = lax.dynamic_update_slice(cv, v, (0, slot, 0, 0))
         # Attend the one query against the whole static-length cache,
         # masking invalid slots. GQA runs WITHOUT materializing the head
         # repeat: q groups to [B, Hkv, g, Dh] (group_query_heads — the one
@@ -1400,10 +1420,6 @@ class GPTLM:
             group_query_heads,
         )
 
-        qg = group_query_heads(q[:, 0], self.num_kv_heads)
-        scores = jnp.einsum(
-            "bhgd,bkhd->bhgk", qg, ck, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(jnp.asarray(self.head_dim, jnp.float32))
         idx = jnp.arange(c)
         if self.window is not None:
             # Rolling buffer: slot i holds absolute position
@@ -1415,18 +1431,40 @@ class GPTLM:
             valid = slot_pos >= 0
         else:
             valid = idx <= length  # [cache_len]
-        scores = jnp.where(valid[None, None, None, :], scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum(
-            "bhgk,bkhd->bhgd",
-            w.astype(cv.dtype),
-            cv,
-            preferred_element_type=jnp.float32,
-        ).reshape(b, 1, self.num_heads, self.head_dim)
-        h = h + self._dot(attn.reshape(b, 1, self.model_dim), blk.wo)
-        hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
-        ffn_out, _ = self._ffn(blk, hn2)  # aux unused: decode never drops
-        return h + ffn_out, ck, cv
+        attn = self._decode_attend(
+            "bhgd,bkhd->bhgk", "bhgk,bkhd->bhgd",
+            group_query_heads(q[:, 0], self.num_kv_heads), ck, cv,
+            valid[None, None, None, :],
+        )
+        return self._decode_block_tail(blk, h, attn), ck, cv
+
+    def _decode_attend(self, qk, wv, qg, ck, cv, valid):
+        """One query per row against a whole static-length cache, invalid
+        slots masked: the core every single-token decode path shares
+        (``qk``/``wv`` are the two einsum specs — the batch letter is all
+        that differs). f32 softmax; the value product runs at the cache's
+        dtype."""
+        with jax.named_scope(names.ATTN_CORE):
+            scores = jnp.einsum(
+                qk, qg, ck, preferred_element_type=jnp.float32
+            ) / jnp.sqrt(jnp.asarray(self.head_dim, jnp.float32))
+            scores = jnp.where(valid, scores, -1e30)
+            w = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum(
+                wv, w.astype(cv.dtype), cv,
+                preferred_element_type=jnp.float32,
+            )
+
+    def _decode_block_tail(self, blk, h, attn):
+        """Attention-out projection and FFN of a single-token block step
+        (``attn`` [rows, Hkv, g, Dh]); returns the new residual."""
+        rows = h.shape[0]
+        with jax.named_scope(names.ATTN_OUT):
+            h = h + self._dot(attn.reshape(rows, 1, self.model_dim), blk.wo)
+        with jax.named_scope(names.MLP):
+            hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
+            ffn_out, _ = self._ffn(blk, hn2)  # aux unused: decode never drops
+            return h + ffn_out
 
     def decode_step(
         self,
@@ -1503,40 +1541,39 @@ class GPTLM:
             nks, nvs = [], []
             for i in range(self.num_layers):
                 blk = jax.tree.map(lambda x: x[i], params.blocks)
+                ck0, cv0, _, _ = _cache_layer(cache, i)
                 hr, kq, vq, _, _ = decode_block_slab(
                     hr, self._decode_kernel_weights(blk),
-                    cache.k[i], cache.v[i], None, None, lengths,
+                    ck0, cv0, None, None, lengths,
                     num_heads=self.num_heads, window=self.window,
                     kv_dtype="bf16", compute_dtype=self.compute_dtype,
                     rope=self.pos_embedding == "rope",
                 )
                 # Commit with the XLA engine's exact index math (the
                 # scalar-slot dynamic_update_slice of _decode_block).
-                nks.append(
-                    lax.dynamic_update_slice(
-                        cache.k[i], kq[:, None], (0, slot, 0, 0)
+                with jax.named_scope(names.KV_WRITE):
+                    nks.append(
+                        lax.dynamic_update_slice(
+                            ck0, kq[:, None], (0, slot, 0, 0)
+                        )
                     )
-                )
-                nvs.append(
-                    lax.dynamic_update_slice(
-                        cache.v[i], vq[:, None], (0, slot, 0, 0)
+                    nvs.append(
+                        lax.dynamic_update_slice(
+                            cv0, vq[:, None], (0, slot, 0, 0)
+                        )
                     )
-                )
-            new_cache = KVCache(
-                k=jnp.stack(nks), v=jnp.stack(nvs), length=cache.length + 1
-            )
+            nk, nv, _, _ = _restack(nks, nvs)
+            new_cache = KVCache(k=nk, v=nv, length=cache.length + 1)
             return self._logits(params, hr[:, None])[:, 0], new_cache
         nks, nvs = [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
-            h, ck, cv = self._decode_block(
-                blk, h, cache.k[i], cache.v[i], cache.length
-            )
+            ck0, cv0, _, _ = _cache_layer(cache, i)
+            h, ck, cv = self._decode_block(blk, h, ck0, cv0, cache.length)
             nks.append(ck)
             nvs.append(cv)
-        new_cache = KVCache(
-            k=jnp.stack(nks), v=jnp.stack(nvs), length=cache.length + 1
-        )
+        nk, nv, _, _ = _restack(nks, nvs)
+        new_cache = KVCache(k=nk, v=nv, length=cache.length + 1)
         return self._logits(params, h)[:, 0], new_cache
 
     # -- slot-wise decoding (the serving surface, serve.py) ----------------
@@ -1643,56 +1680,57 @@ class GPTLM:
             return h, kv
 
         h, (ks, vs) = lax.scan(body, h, params.blocks)
-        if qd is None:
-            ks = ks.astype(self.compute_dtype)  # [n, S, L, Hkv, Dh]
-            vs = vs.astype(self.compute_dtype)
-            ksc = vsc = None
-        else:
-            # Quantize-on-write (round 15): payload rows plus the per-
-            # (position, head) scale side tensors, which follow the same
-            # pad/rolling relayout minus the lane axis.
-            ks, ksc = quantize_kv(ks, qd)  # [n,S,L,Hkv,Dh] + [n,S,L,Hkv]
-            vs, vsc = quantize_kv(vs, qd)
-        if l <= c:
-            # Every prompt position p < lengths[s] <= c lands at slot
-            # p % c = p: plain pad (the same layout prefill() writes).
-            pad = [(0, 0), (0, 0), (0, c - l), (0, 0), (0, 0)]
-            nk, nv = jnp.pad(ks, pad), jnp.pad(vs, pad)
-            if qd is not None:
-                nksc = jnp.pad(ksc, pad[:-1])
-                nvsc = jnp.pad(vsc, pad[:-1])
-        else:
-            # Rolling window (c < L): per ROW, keep that row's last
-            # min(c, len) real positions at slots p % c. Cache slot j
-            # holds the largest prompt position p < len with p ≡ j
-            # (mod c): p = j + c·⌊(len−1−j)/c⌋ — per-row dynamic, unlike
-            # prefill()'s static arrays, because each row has its own len.
-            idx = jnp.arange(c)[None, :]  # [1, c]
-            p = idx + c * ((lengths[:, None] - 1 - idx) // c)  # [S, c]
-            gather = jnp.clip(p, 0, l - 1)[None, :, :, None, None]
-            nk = jnp.take_along_axis(ks, gather, axis=2)
-            nv = jnp.take_along_axis(vs, gather, axis=2)
-            if qd is not None:
-                nksc = jnp.take_along_axis(ksc, gather[..., 0], axis=2)
-                nvsc = jnp.take_along_axis(vsc, gather[..., 0], axis=2)
-            # p < 0 rows (len <= j and no earlier wrap) hold garbage —
-            # unreachable: the decode mask derives validity from lengths.
-        m = admit[None, :, None, None, None]
-        new_cache = SlotKVCache(
-            k=jnp.where(m, nk, cache.k),
-            v=jnp.where(m, nv, cache.v),
-            lengths=jnp.where(admit, lengths, cache.lengths),
-            k_scale=(
-                None
-                if qd is None
-                else jnp.where(m[..., 0], nksc, cache.k_scale)
-            ),
-            v_scale=(
-                None
-                if qd is None
-                else jnp.where(m[..., 0], nvsc, cache.v_scale)
-            ),
-        )
+        with jax.named_scope(names.KV_WRITE):
+            if qd is None:
+                ks = ks.astype(self.compute_dtype)  # [n, S, L, Hkv, Dh]
+                vs = vs.astype(self.compute_dtype)
+                ksc = vsc = None
+            else:
+                # Quantize-on-write (round 15): payload rows plus the per-
+                # (position, head) scale side tensors, which follow the same
+                # pad/rolling relayout minus the lane axis.
+                ks, ksc = quantize_kv(ks, qd)  # [n,S,L,Hkv,Dh] + [n,S,L,Hkv]
+                vs, vsc = quantize_kv(vs, qd)
+            if l <= c:
+                # Every prompt position p < lengths[s] <= c lands at slot
+                # p % c = p: plain pad (the same layout prefill() writes).
+                pad = [(0, 0), (0, 0), (0, c - l), (0, 0), (0, 0)]
+                nk, nv = jnp.pad(ks, pad), jnp.pad(vs, pad)
+                if qd is not None:
+                    nksc = jnp.pad(ksc, pad[:-1])
+                    nvsc = jnp.pad(vsc, pad[:-1])
+            else:
+                # Rolling window (c < L): per ROW, keep that row's last
+                # min(c, len) real positions at slots p % c. Cache slot j
+                # holds the largest prompt position p < len with p ≡ j
+                # (mod c): p = j + c·⌊(len−1−j)/c⌋ — per-row dynamic, unlike
+                # prefill()'s static arrays, because each row has its own len.
+                idx = jnp.arange(c)[None, :]  # [1, c]
+                p = idx + c * ((lengths[:, None] - 1 - idx) // c)  # [S, c]
+                gather = jnp.clip(p, 0, l - 1)[None, :, :, None, None]
+                nk = jnp.take_along_axis(ks, gather, axis=2)
+                nv = jnp.take_along_axis(vs, gather, axis=2)
+                if qd is not None:
+                    nksc = jnp.take_along_axis(ksc, gather[..., 0], axis=2)
+                    nvsc = jnp.take_along_axis(vsc, gather[..., 0], axis=2)
+                # p < 0 rows (len <= j and no earlier wrap) hold garbage —
+                # unreachable: the decode mask derives validity from lengths.
+            m = admit[None, :, None, None, None]
+            new_cache = SlotKVCache(
+                k=jnp.where(m, nk, cache.k),
+                v=jnp.where(m, nv, cache.v),
+                lengths=jnp.where(admit, lengths, cache.lengths),
+                k_scale=(
+                    None
+                    if qd is None
+                    else jnp.where(m[..., 0], nksc, cache.k_scale)
+                ),
+                v_scale=(
+                    None
+                    if qd is None
+                    else jnp.where(m[..., 0], nvsc, cache.v_scale)
+                ),
+            )
         h_last = jnp.take_along_axis(
             h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
         )  # [S, 1, d]
@@ -1714,32 +1752,25 @@ class GPTLM:
         )
 
         s = h.shape[0]
-        hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
-        kv_shape = (s, 1, self.num_kv_heads, self.head_dim)
-        q = self._dot(hn, blk.wq).reshape(s, 1, self.num_heads, self.head_dim)
-        k = self._dot(hn, blk.wk).reshape(kv_shape)
-        v = self._dot(hn, blk.wv).reshape(kv_shape)
-        if self.pos_embedding == "rope":
-            pos = lengths[:, None]  # [S, 1] — per-row absolute position
-            q = _rope(q, pos)
-            k = _rope(k, pos)
+        with jax.named_scope(names.ATTN_QKV):
+            hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
+            kv_shape = (s, 1, self.num_kv_heads, self.head_dim)
+            q = self._dot(hn, blk.wq).reshape(
+                s, 1, self.num_heads, self.head_dim
+            )
+            k = self._dot(hn, blk.wk).reshape(kv_shape)
+            v = self._dot(hn, blk.wv).reshape(kv_shape)
+            if self.pos_embedding == "rope":
+                pos = lengths[:, None]  # [S, 1] — per-row absolute position
+                q = _rope(q, pos)
+                k = _rope(k, pos)
         ck, cv, valid, state = cache_update(k, v)
-        qg = group_query_heads(q[:, 0], self.num_kv_heads)
-        scores = jnp.einsum(
-            "shgd,skhd->shgk", qg, ck, preferred_element_type=jnp.float32
-        ) / jnp.sqrt(jnp.asarray(self.head_dim, jnp.float32))
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum(
-            "shgk,skhd->shgd",
-            w.astype(cv.dtype),
-            cv,
-            preferred_element_type=jnp.float32,
-        ).reshape(s, 1, self.num_heads, self.head_dim)
-        h = h + self._dot(attn.reshape(s, 1, self.model_dim), blk.wo)
-        hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
-        ffn_out, _ = self._ffn(blk, hn2)  # aux unused: decode never drops
-        return h + ffn_out, state
+        attn = self._decode_attend(
+            "shgd,skhd->shgk", "shgk,skhd->shgd",
+            group_query_heads(q[:, 0], self.num_kv_heads), ck, cv,
+            valid[:, None, None, :],
+        )
+        return self._decode_block_tail(blk, h, attn), state
 
     def _decode_block_slots(
         self, blk, h, ck0, cv0, lengths, act, ks0=None, vs0=None, qd=None
@@ -1841,22 +1872,18 @@ class GPTLM:
         nks, nvs, nksc, nvsc = [], [], [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
+            ck0, cv0, ks0, vs0 = _cache_layer(cache, i)
             h, (ck, cv, ksc, vsc) = self._decode_block_slots(
-                blk, h, cache.k[i], cache.v[i], cache.lengths, act,
-                None if qd is None else cache.k_scale[i],
-                None if qd is None else cache.v_scale[i],
-                qd,
+                blk, h, ck0, cv0, cache.lengths, act, ks0, vs0, qd
             )
             nks.append(ck)
             nvs.append(cv)
             nksc.append(ksc)
             nvsc.append(vsc)
+        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
         new_cache = SlotKVCache(
-            k=jnp.stack(nks),
-            v=jnp.stack(nvs),
+            k=nk, v=nv, k_scale=nks, v_scale=nvs,
             lengths=cache.lengths + act.astype(jnp.int32),
-            k_scale=None if qd is None else jnp.stack(nksc),
-            v_scale=None if qd is None else jnp.stack(nvsc),
         )
         return self._logits(params, h)[:, 0], new_cache
 
@@ -1876,9 +1903,7 @@ class GPTLM:
         nks, nvs, nksc, nvsc = [], [], [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
-            ck0, cv0 = cache.k[i], cache.v[i]
-            ks0 = None if qd is None else cache.k_scale[i]
-            vs0 = None if qd is None else cache.v_scale[i]
+            ck0, cv0, ks0, vs0 = _cache_layer(cache, i)
             hr, kq, vq, ksc, vsc = decode_block_slab(
                 hr, self._decode_kernel_weights(blk), ck0, cv0, ks0, vs0,
                 lengths,
@@ -1893,12 +1918,10 @@ class GPTLM:
             nvs.append(cv)
             nksc.append(ksn)
             nvsc.append(vsn)
+        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
         new_cache = SlotKVCache(
-            k=jnp.stack(nks),
-            v=jnp.stack(nvs),
+            k=nk, v=nv, k_scale=nks, v_scale=nvs,
             lengths=lengths + act.astype(jnp.int32),
-            k_scale=None if qd is None else jnp.stack(nksc),
-            v_scale=None if qd is None else jnp.stack(nvsc),
         )
         return self._logits(params, hr[:, None])[:, 0], new_cache
 
@@ -2243,23 +2266,19 @@ class GPTLM:
         nks, nvs, nksc, nvsc = [], [], [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
+            pk, pv, pks, pvs = _cache_layer(cache, i)
             h, (pk, pv, pks, pvs) = self._decode_block_paged(
-                blk, h, cache.k[i], cache.v[i], cache.block_tables,
-                cache.lengths, act,
-                None if qd is None else cache.k_scale[i],
-                None if qd is None else cache.v_scale[i],
-                qd,
+                blk, h, pk, pv, cache.block_tables, cache.lengths, act,
+                pks, pvs, qd,
             )
             nks.append(pk)
             nvs.append(pv)
             nksc.append(pks)
             nvsc.append(pvs)
+        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
         new_cache = cache._replace(
-            k=jnp.stack(nks),
-            v=jnp.stack(nvs),
+            k=nk, v=nv, k_scale=nks, v_scale=nvs,
             lengths=cache.lengths + act.astype(jnp.int32),
-            k_scale=None if qd is None else jnp.stack(nksc),
-            v_scale=None if qd is None else jnp.stack(nvsc),
         )
         return self._logits(params, h)[:, 0], new_cache
 
@@ -2282,9 +2301,7 @@ class GPTLM:
         nks, nvs, nksc, nvsc = [], [], [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
-            pk, pv = cache.k[i], cache.v[i]
-            pks = None if qd is None else cache.k_scale[i]
-            pvs = None if qd is None else cache.v_scale[i]
+            pk, pv, pks, pvs = _cache_layer(cache, i)
             hr, kq, vq, ksc, vsc = decode_block_paged(
                 hr, self._decode_kernel_weights(blk), pk, pv, pks, pvs,
                 tables, lengths,
@@ -2299,12 +2316,10 @@ class GPTLM:
             nvs.append(nv)
             nksc.append(ksn)
             nvsc.append(vsn)
+        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
         new_cache = cache._replace(
-            k=jnp.stack(nks),
-            v=jnp.stack(nvs),
+            k=nk, v=nv, k_scale=nks, v_scale=nvs,
             lengths=lengths + act.astype(jnp.int32),
-            k_scale=None if qd is None else jnp.stack(nksc),
-            v_scale=None if qd is None else jnp.stack(nvsc),
         )
         return self._logits(params, hr[:, None])[:, 0], new_cache
 
@@ -2566,6 +2581,34 @@ class GPTLM:
         return jnp.concatenate([prompt, best_seq], axis=1)
 
 
+def _cache_layer(cache, i: int):
+    """Layer ``i``'s ``(k, v, k_scale, v_scale)`` out of a layer-stacked
+    cache (scales None on a bf16 cache, and on a :class:`KVCache`, which
+    has none). With :func:`_restack` this is the per-step restack of the
+    whole cache that the unrolled decode loops pay — scoped, so a trace
+    shows what it costs."""
+    with jax.named_scope(names.KV_RESTACK):
+        ks = getattr(cache, "k_scale", None)
+        vs = getattr(cache, "v_scale", None)
+        return (
+            cache.k[i], cache.v[i],
+            None if ks is None else ks[i],
+            None if vs is None else vs[i],
+        )
+
+
+def _restack(nks, nvs, nksc=(), nvsc=()):
+    """The per-layer results of an unrolled decode loop back into the
+    layer-stacked layout (scale lists of Nones, or empty, give None)."""
+    with jax.named_scope(names.KV_RESTACK):
+        scaled = bool(nksc) and nksc[0] is not None
+        return (
+            jnp.stack(nks), jnp.stack(nvs),
+            jnp.stack(nksc) if scaled else None,
+            jnp.stack(nvsc) if scaled else None,
+        )
+
+
 def export_kv_blocks(cache: PagedKVCache, block_ids) -> dict:
     """Lift the named pool blocks out of a :class:`PagedKVCache` as host
     arrays — the wire half of the round-23 prefill→decode handoff. The
@@ -2656,14 +2699,15 @@ def _ce_from_logits(logits, tokens, lengths=None):
     CE arithmetic shared by :meth:`GPTLM.loss_and_metrics` and every
     parallel train-step factory below (a divergence here would silently
     break their proven equality with the single-device step)."""
-    nll = _picked_nll(logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
-    if lengths is None:
-        return jnp.mean(nll)
-    # Target at position i is token i+1 → valid iff i+1 < lengths[b].
-    w = (
-        jnp.arange(tokens.shape[1] - 1)[None, :] < (lengths[:, None] - 1)
-    ).astype(jnp.float32)
-    return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
+    with jax.named_scope(names.LOSS):
+        nll = _picked_nll(logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
+        if lengths is None:
+            return jnp.mean(nll)
+        # Target at position i is token i+1 → valid iff i+1 < lengths[b].
+        w = (
+            jnp.arange(tokens.shape[1] - 1)[None, :] < (lengths[:, None] - 1)
+        ).astype(jnp.float32)
+        return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
 
 
 def expert_parallel_specs(model: GPTLM, axis_name: str = "expert"):
@@ -3380,9 +3424,11 @@ def make_lm_train_step(
             loss, grads = jax.value_and_grad(model.loss)(params, tokens)
             # Pin to the TP layout: the update stays local to each
             # device's weight shard.
-            params, opt_state = _pinned_update(
-                optimizer, params, opt_state, grads, shardings, opt_shardings
-            )
+            with jax.named_scope(names.OPTIMIZER):
+                params, opt_state = _pinned_update(
+                    optimizer, params, opt_state, grads, shardings,
+                    opt_shardings,
+                )
             return params, opt_state, loss
 
         return step
@@ -3392,8 +3438,9 @@ def make_lm_train_step(
         @jax.jit
         def step(params, opt_state, tokens):
             loss, grads = jax.value_and_grad(model.loss)(params, tokens)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope(names.OPTIMIZER):
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         return step
@@ -3407,8 +3454,9 @@ def make_lm_train_step(
         # AD's auto-psum summed the per-device grads of the replicated
         # params; the global-mean loss needs their mean.
         grads = jax.tree.map(lambda g: g / n, grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(names.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, lax.pmean(loss, axis)
 
     mapped = jax.shard_map(

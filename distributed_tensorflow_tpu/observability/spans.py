@@ -18,9 +18,19 @@ a dispatch without it raises instead of silently recording enqueue time
 Generic host work (compile, file I/O) uses :meth:`SpanRecorder.span`,
 which has no such requirement.
 
+One clock (PR 26): while a ``jax.profiler`` session is on, every span is
+ALSO a ``jax.profiler.TraceAnnotation`` named ``dtf:<span name>`` in the
+profile's ``/host:CPU`` plane, carrying the span's scalar arguments — it
+opens where the span opens and closes where the span closes (a dispatch
+span: at the fetch), on the clock of the device planes, so a device gap
+is laid against what the program was doing with no arithmetic between
+clocks. Outside a session an annotation costs half a microsecond.
+
 jax-free (lean-import convention): the fetch coerces via ``__array__`` /
 ``float`` — a jax array's ``__array__`` IS the D2H copy, and numpy is
-imported lazily only when an array-likes is fetched.
+imported lazily only when an array-likes is fetched; the annotation is
+taken from ``jax`` only when the process has imported it already (a
+program that dispatches has).
 """
 
 from __future__ import annotations
@@ -29,8 +39,11 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
+
+from distributed_tensorflow_tpu.observability.names import ANNOTATION_PREFIX
 
 
 def force_host(value):
@@ -53,10 +66,36 @@ def force_host(value):
     return float(value)
 
 
+def _open_annotation(name: str, args: dict):
+    """Enter a ``jax.profiler.TraceAnnotation`` named ``dtf:<name>`` with
+    the scalar ``args`` as its own, and return it for
+    :func:`_close`; None where this process has not imported
+    jax (nothing to profile, and the package stays importable without
+    it). Lists (``rids``) stay in the span event only."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(
+        ANNOTATION_PREFIX + name,
+        **{k: v for k, v in args.items()
+           if isinstance(v, (bool, int, float, str))},
+    )
+    ann.__enter__()
+    return ann
+
+
+def _close(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 class DispatchSpan:
     """An open dispatch span. ``fetch(value)`` is the only way to close
     it cleanly: it performs the D2H materialization and stamps the span's
-    end time AT the fetch — the honest dispatch+execute window."""
+    end time AT the fetch — the honest dispatch+execute window — and
+    closes the span's profiler annotation there too. ``args`` may be
+    added to between the fetch and the end of the ``with`` block (what
+    the dispatch delivered is known only then)."""
 
     def __init__(self, recorder: "SpanRecorder", name: str, args: dict):
         self._rec = recorder
@@ -64,11 +103,17 @@ class DispatchSpan:
         self.args = args
         self._t0 = recorder._now()
         self._t_fetch = None
+        self._ann = _open_annotation(name, args)
 
     def fetch(self, value):
         host = force_host(value)
         self._t_fetch = self._rec._now()
+        self._close_annotation()
         return host
+
+    def _close_annotation(self) -> None:
+        ann, self._ann = self._ann, None
+        _close(ann)
 
     @property
     def fetched(self) -> bool:
@@ -127,9 +172,11 @@ class SpanRecorder:
     def span(self, name: str, cat: str = "host", **args):
         """Generic host span (compile, checkpoint I/O, scheduler work)."""
         t0 = self._now()
+        ann = _open_annotation(name, args)
         try:
             yield
         finally:
+            _close(ann)
             self._record(name, cat, t0, self._now(), args)
 
     @contextlib.contextmanager
@@ -144,12 +191,14 @@ class SpanRecorder:
             yield sp
         except BaseException:
             # The dispatch died: record what we know, never mask the error.
+            sp._close_annotation()
             self._record(
                 name, "dispatch", sp._t0, self._now(),
-                {**args, "error": True},
+                {**sp.args, "error": True},
             )
             raise
         if not sp.fetched:
+            sp._close_annotation()
             raise RuntimeError(
                 f"dispatch span {name!r} closed without a D2H fetch: call "
                 "span.fetch(<value the dispatch produced>) before exiting "
@@ -157,28 +206,9 @@ class SpanRecorder:
                 "measures enqueue, not execution (CLAUDE.md TIMING TRAP)"
             )
         self._record(
-            name, "dispatch", sp._t0, sp._t_fetch, {**args, "barrier": "d2h"}
+            name, "dispatch", sp._t0, sp._t_fetch,
+            {**sp.args, "barrier": "d2h"},
         )
-
-    def mark(self) -> float:
-        """A start-of-dispatch timestamp for :meth:`dispatch_fetch` —
-        take it immediately before issuing the dispatch."""
-        return self._now()
-
-    def dispatch_fetch(self, name: str, value, *, start: float | None = None,
-                       **args):
-        """One-call dispatch span for straight-line code: materializes
-        ``value`` on the host (the D2H barrier — this call CANNOT record
-        without fetching, same honesty guarantee as :meth:`dispatch`) and
-        records the span from ``start`` (a :meth:`mark` taken before the
-        dispatch; default: now, i.e. fetch-wait only). Returns the host
-        value, so it drops in where ``jax.device_get`` was."""
-        t0 = self._now() if start is None else float(start)
-        host = force_host(value)
-        self._record(
-            name, "dispatch", t0, self._now(), {**args, "barrier": "d2h"}
-        )
-        return host
 
     # -- export ------------------------------------------------------------
 

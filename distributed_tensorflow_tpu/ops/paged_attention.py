@@ -34,6 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops.ring_attention import group_query_heads
 
 _NEG_INF = -1e30
@@ -46,9 +47,10 @@ def gather_block_view(pool_layer: jax.Array, block_tables: jax.Array):
     position ``p`` of the slot. Unused table entries gather garbage that
     the caller's validity mask must keep out of the softmax."""
     bs = pool_layer.shape[1]
-    view = jnp.take(pool_layer, block_tables, axis=0)  # [S, NB, bs, H, D]
     s, tabs = block_tables.shape
-    return view.reshape(s, tabs * bs, *pool_layer.shape[2:])
+    with jax.named_scope(names.KV_GATHER):
+        view = jnp.take(pool_layer, block_tables, axis=0)  # [S,NB,bs,H,D]
+        return view.reshape(s, tabs * bs, *pool_layer.shape[2:])
 
 
 def scatter_token_kv(
@@ -83,14 +85,15 @@ def scatter_token_kv_all_layers(
     """All-layer variant (the extend path scatters once after its layer
     scan): ``pool`` [n, NB, bs, Hkv, Dh], ``kvs`` [n, S, L, Hkv, Dh]."""
     n, nb, bs = pool.shape[0], pool.shape[1], pool.shape[2]
-    bidx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-    bidx = jnp.where(valid, bidx, nb)
-    off = positions % bs
     s, l = positions.shape
-    flat = kvs.reshape(n, s * l, *kvs.shape[3:])
-    return pool.at[:, bidx.reshape(-1), off.reshape(-1)].set(
-        flat, mode="drop"
-    )
+    with jax.named_scope(names.KV_WRITE):
+        bidx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        bidx = jnp.where(valid, bidx, nb)
+        off = positions % bs
+        flat = kvs.reshape(n, s * l, *kvs.shape[3:])
+        return pool.at[:, bidx.reshape(-1), off.reshape(-1)].set(
+            flat, mode="drop"
+        )
 
 
 def paged_extend_attention(

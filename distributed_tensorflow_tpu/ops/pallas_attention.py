@@ -60,6 +60,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
 
 _NEG_INF = -1e30
@@ -318,6 +319,7 @@ def _fwd_call(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name=names.KERNEL_FLASH_FWD,
     )(*inputs)
 
 
@@ -607,6 +609,7 @@ def _bwd_call(
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
             interpret=interpret,
+            name=names.KERNEL_FLASH_BWD_FUSED,
         )(*kv_inputs)
         return jnp.sum(dqp, axis=0).astype(q.dtype), dk, dv
 
@@ -637,6 +640,7 @@ def _bwd_call(
         out_shape=sds((bh, l, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name=names.KERNEL_FLASH_BWD_DQ,
     )(*dq_inputs)
 
     dk, dv = pl.pallas_call(
@@ -657,6 +661,7 @@ def _bwd_call(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name=names.KERNEL_FLASH_BWD_DKV,
     )(*kv_inputs)
     return dq, dk, dv
 

@@ -90,6 +90,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
 
 _NEG_INF = -1e30
@@ -445,7 +446,7 @@ def _vmem_limit(blocks, scratch_bytes: int) -> int:
 def _call(
     h, w, ck, cv, k_scale, v_scale, prefix_lens, suffix_lens, active,
     tables, *, num_heads, window, rolling, commit, kv_dtype,
-    compute_dtype, rope, rope_base, block_c, interpret,
+    compute_dtype, rope, rope_base, block_c, interpret, name,
 ):
     """The one launch builder. ``h`` [S, L, d]; ``w`` the layer-STACKED
     weight dict; ``ck``/``cv`` [n_layers, S, C, Hkv, Dh] (slab) or
@@ -453,7 +454,8 @@ def _call(
     the tables ride as scalar prefetch and the pool gather is index-map
     arithmetic). ``commit`` selects in-kernel commit through aliased
     ANY-space cache operands (returns the committed caches) against
-    fresh rows returned as outputs."""
+    fresh rows returned as outputs. ``name`` is the kernel's name in a
+    trace (``observability/names.py``)."""
     interpret = resolve_interpret(interpret)
     rows = h.shape[1]
     if rows > 1 and rows % 8:
@@ -596,6 +598,7 @@ def _call(
             vmem_limit_bytes=_vmem_limit(blocks, scratch_bytes),
         ),
         interpret=interpret,
+        name=name,
     )(*prefetch, *inputs)
     ho, nk, nv = outs[0][:, :rows], outs[1], outs[2]
     if kv_q is None:
@@ -644,7 +647,7 @@ def _block_call(h, weights, ck, cv, k_scale, v_scale, lengths, tables, **kw):
     ho, kq, vq, ksc, vsc = _call(
         h[:, None], jax.tree.map(lead, weights), ck[None], cv[None],
         lead(k_scale), lead(v_scale), lengths, ones, ones, tables,
-        commit=False, **kw,
+        commit=False, name=names.KERNEL_DECODE_LAYER, **kw,
     )
     squeeze = lambda a: None if a is None else a[:, 0]  # noqa: E731
     return ho[:, 0], kq[:, 0], vq[:, 0], squeeze(ksc), squeeze(vsc)
@@ -765,7 +768,7 @@ def decode_token_slab(
         num_heads=num_heads, window=window, rolling=window is not None,
         commit=True, kv_dtype=kv_dtype, compute_dtype=compute_dtype,
         rope=rope, rope_base=rope_base, block_c=block_c,
-        interpret=interpret,
+        interpret=interpret, name=names.KERNEL_DECODE_TOKEN,
     )
     return (ho[:, 0], *caches)
 
@@ -803,6 +806,7 @@ def decode_token_paged(
         num_heads=num_heads, window=window, rolling=False, commit=True,
         kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
         rope_base=rope_base, block_c=None, interpret=interpret,
+        name=names.KERNEL_DECODE_TOKEN,
     )
     return (ho[:, 0], *caches)
 
@@ -849,4 +853,5 @@ def verify_tokens_paged(
         num_heads=num_heads, window=window, rolling=False, commit=True,
         kv_dtype=kv_dtype, compute_dtype=compute_dtype, rope=rope,
         rope_base=rope_base, block_c=None, interpret=interpret,
+        name=names.KERNEL_VERIFY_TOKENS,
     )

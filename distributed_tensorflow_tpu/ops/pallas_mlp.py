@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_tensorflow_tpu.models.mlp import MLPParams
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops.pallas_mode import resolve_interpret
 
 _LOG_EPS = 1e-30
@@ -141,6 +142,7 @@ def make_fused_train_step(
         # Params update in place: new W/b alias the incoming buffers.
         input_output_aliases={2: 0, 3: 1, 4: 2, 5: 3},
         interpret=interpret,
+        name=names.KERNEL_MLP_TRAIN_STEP,
     )
 
     @jax.jit
@@ -258,6 +260,7 @@ def _epoch_call(
             jax.ShapeDtypeStruct((-(-steps // 8) * 8, 128), f32),
         ),
         interpret=interpret,
+        name=names.KERNEL_MLP_TRAIN_EPOCH,
     )
 
 

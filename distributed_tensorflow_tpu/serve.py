@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from distributed_tensorflow_tpu.models.gpt import GPTLM, GPTLMParams
 from distributed_tensorflow_tpu.observability import journal as obs_journal
-from distributed_tensorflow_tpu.observability import tracing
+from distributed_tensorflow_tpu.observability import names, tracing
 from distributed_tensorflow_tpu.observability.exporter import MetricsExporter
 from distributed_tensorflow_tpu.observability.metrics import MetricsRegistry
 from distributed_tensorflow_tpu.observability.spans import SpanRecorder
@@ -688,29 +688,30 @@ class TextServer:
         its noise bits match the in-process B=1 call exactly (the parity
         contract)."""
 
-        amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope(names.PICK):
+            amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        def row(lg, kd, t, p):
-            lt = lg.astype(jnp.float32) / t
-            order = jnp.argsort(lt)[::-1]
-            sorted_l = lt[order]
-            probs = jax.nn.softmax(sorted_l)
-            keep_sorted = jnp.cumsum(probs) - probs < p
-            keep = jnp.zeros(lt.shape, bool).at[order].set(keep_sorted)
-            lt = jnp.where(keep, lt, -jnp.inf)
-            return jax.random.categorical(
-                jax.random.wrap_key_data(kd), lt[None, :], axis=-1
-            )[0].astype(jnp.int32)
+            def row(lg, kd, t, p):
+                lt = lg.astype(jnp.float32) / t
+                order = jnp.argsort(lt)[::-1]
+                sorted_l = lt[order]
+                probs = jax.nn.softmax(sorted_l)
+                keep_sorted = jnp.cumsum(probs) - probs < p
+                keep = jnp.zeros(lt.shape, bool).at[order].set(keep_sorted)
+                lt = jnp.where(keep, lt, -jnp.inf)
+                return jax.random.categorical(
+                    jax.random.wrap_key_data(kd), lt[None, :], axis=-1
+                )[0].astype(jnp.int32)
 
-        def mixed(_):
-            sampled = jax.vmap(row)(logits, key_data, temp, top_p)
-            return jnp.where(greedy, amax, sampled)
+            def mixed(_):
+                sampled = jax.vmap(row)(logits, key_data, temp, top_p)
+                return jnp.where(greedy, amax, sampled)
 
-        # Greedy-only banks (the default config) skip the full-vocab
-        # sort/softmax/gumbel machinery entirely — it is O(V log V) per
-        # slot per token in the hot chunk graph, and jnp.where alone
-        # would still evaluate it.
-        return jax.lax.cond(jnp.all(greedy), lambda _: amax, mixed, None)
+            # Greedy-only banks (the default config) skip the full-vocab
+            # sort/softmax/gumbel machinery entirely — it is O(V log V) per
+            # slot per token in the hot chunk graph, and jnp.where alone
+            # would still evaluate it.
+            return jax.lax.cond(jnp.all(greedy), lambda _: amax, mixed, None)
 
     def _split_keys(self, key_data):
         """Per-slot ``key, sub = jax.random.split(key)`` on key-data rows —
@@ -1693,7 +1694,7 @@ class TextServer:
                     ),
                 )
             with self.spans.dispatch(
-                "prefill", bucket=int(lb), admitted=len(members),
+                names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
                 rids=[int(m[1].rid) for m in members],
             ) as sp:
                 self._state = self._prefill_jit(
@@ -1751,7 +1752,7 @@ class TextServer:
                     slot, req, lb, key, budget, greedy, temp, top_p, eos
                 )
             with self.spans.dispatch(
-                "prefill", bucket=int(lb), admitted=len(members),
+                names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
                 rids=[int(r.rid) for _, r in members],
             ) as sp:
                 self._state = self._prefill_jit(
@@ -1891,11 +1892,6 @@ class TextServer:
             now = time.perf_counter()
             latency = now - req.t_submit
             self.metrics.counter("completions_total").inc()
-            # A completion IS the slot eviction in this engine (no
-            # preemptive eviction yet); counted under both names so the
-            # scheduler-side math (admissions - evictions = occupancy)
-            # reads naturally.
-            self.metrics.counter("slot_evictions_total").inc()
             self.metrics.counter("tokens_generated_total").inc(len(req.out))
             self.metrics.histogram("request_latency_s").observe(latency)
             self.journal.emit(
@@ -1911,6 +1907,24 @@ class TextServer:
                     6,
                 ),
             )
+
+    def _account_delivery(self, sp, valid, occupied: int, steps: int) -> None:
+        """What a decode dispatch delivered, written into its span after
+        the fetch and before the span closes: ``emitted``, the tokens it
+        delivered to each resident request (one count per entry of the
+        span's ``rids``, same order), and ``slot_steps``, the slot-steps
+        it ran (``active`` × the steps of the program). A slot that
+        finishes inside a chunk rides masked to the chunk's end: 1 − Σ
+        emitted ÷ Σ slot_steps is that tail (under speculation, the
+        rejected drafts' share)."""
+        sp.args["emitted"] = [
+            int(valid[:, slot].sum())
+            for slot, req in enumerate(self._slot_req) if req is not None
+        ]
+        sp.args["slot_steps"] = int(occupied) * int(steps)
+        self.metrics.counter("decode_slot_steps_total").inc(
+            sp.args["slot_steps"]
+        )
 
     def _spec_dispatch(self, occupied: int):
         """One speculative decode tick (replaces the chunk scan when
@@ -1954,7 +1968,8 @@ class TextServer:
                     slens[slot] = 1 + len(d)
                     proposed += len(d)
         with self.spans.dispatch(
-            "spec_verify", draft=self.spec_draft, active=int(occupied),
+            names.SPAN_SPEC_VERIFY, draft=self.spec_draft,
+            active=int(occupied),
             rids=[int(r.rid) for r in self._slot_req if r is not None],
         ) as sp:
             self._state, toks, valid = self._verify_jit(
@@ -1965,7 +1980,8 @@ class TextServer:
             )
             # D2H fetch = execution barrier (closes the span).
             toks = sp.fetch(toks)
-        valid = np.asarray(valid)
+            valid = np.asarray(valid)
+            self._account_delivery(sp, valid, occupied, d1)
         accepted = int(valid.sum()) - int(occupied)
         self.metrics.counter("spec_tokens_proposed").inc(proposed)
         self.metrics.counter("spec_tokens_accepted").inc(accepted)
@@ -2014,7 +2030,8 @@ class TextServer:
                 toks, valid = self._spec_dispatch(occupied)
             else:
                 with self.spans.dispatch(
-                    "decode_chunk", chunk=self.chunk, active=int(occupied),
+                    names.SPAN_DECODE_CHUNK, chunk=self.chunk,
+                    active=int(occupied),
                     rids=[
                         int(r.rid) for r in self._slot_req if r is not None
                     ],
@@ -2024,7 +2041,8 @@ class TextServer:
                     )
                     # D2H fetch = execution barrier (closes the span).
                     toks = sp.fetch(toks)
-                valid = np.asarray(valid)
+                    valid = np.asarray(valid)
+                    self._account_delivery(sp, valid, occupied, self.chunk)
             fin = np.asarray(self._state.finished)
             emitted = 0
             for slot, req in enumerate(self._slot_req):

@@ -102,6 +102,7 @@ import optax
 from distributed_tensorflow_tpu.config import TrainConfig
 from distributed_tensorflow_tpu.models.gpt import GPTLM, make_lm_train_step
 from distributed_tensorflow_tpu.observability import journal as obs_journal
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.observability.metrics import MetricsRegistry
 from distributed_tensorflow_tpu.observability.spans import SpanRecorder
 from distributed_tensorflow_tpu.ops import optim as optim_lib
@@ -1175,9 +1176,11 @@ class LMTrainer:
                 # a reduce-scatter; tp: Megatron column/row shards; pp:
                 # stage-owned layer groups) — the update stays local to
                 # each chip's slice.
-                params, opt_state = pinned_update(
-                    opt, params, opt_state, grads, shardings, opt_shardings
-                )
+                with jax.named_scope(names.OPTIMIZER):
+                    params, opt_state = pinned_update(
+                        opt, params, opt_state, grads, shardings,
+                        opt_shardings,
+                    )
                 return params, opt_state, loss
 
             return zstep
@@ -1191,8 +1194,9 @@ class LMTrainer:
                 loss, grads = jax.value_and_grad(model.loss)(
                     params, toks, lens
                 )
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope(names.OPTIMIZER):
+                    updates, opt_state = opt.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, loss
 
             return step
@@ -1254,14 +1258,15 @@ class LMTrainer:
             toks = shard(toks_all[idx])
             lens = lens_all[idx] if ragged else None
             loss, grads = jax.value_and_grad(loss_fn)(params, toks, lens)
-            if pinned:
-                params, opt_state = pinned_update(
-                    opt, params, opt_state, grads,
-                    self._param_shardings, self._opt_shardings,
-                )
-            else:
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+            with jax.named_scope(names.OPTIMIZER):
+                if pinned:
+                    params, opt_state = pinned_update(
+                        opt, params, opt_state, grads,
+                        self._param_shardings, self._opt_shardings,
+                    )
+                else:
+                    updates, opt_state = opt.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
             return (params, opt_state, step + 1), loss
 
         return body
@@ -1430,16 +1435,16 @@ class LMTrainer:
             )
         )
         step_before = self.global_step
-        mark = self.spans.mark()
-        t0 = time.time()
-        self.state, costs, ppls = run_fn(
-            self.state, toks, lens, idxs, val_toks, val_lens
-        )
-        # D2H fetch = execution barrier; dispatch_fetch also records the
-        # honest dispatch span (CLAUDE.md timing trap).
-        costs = self.spans.dispatch_fetch(
-            "lm_compiled_run", costs, start=mark, epochs=int(epochs)
-        )
+        with self.spans.dispatch(
+            names.SPAN_LM_COMPILED_RUN, epochs=int(epochs)
+        ) as sp:
+            t0 = time.time()
+            self.state, costs, ppls = run_fn(
+                self.state, toks, lens, idxs, val_toks, val_lens
+            )
+            # D2H fetch = execution barrier (CLAUDE.md timing trap): it
+            # closes the honest dispatch span and its dtf: annotation.
+            costs = sp.fetch(costs)
         ppls = jax.device_get(ppls)
         elapsed = time.time() - t0
         avg_ms = elapsed * 1000 / max(epochs * steps, 1)
@@ -1631,13 +1636,15 @@ class LMTrainer:
             toks = self._stage("train_tokens", train.tokens)
             lens = self._train_lens()
             idxs = self._replicated(self._epoch_indices(steps, cfg.batch_size))
-            mark = self.spans.mark()
-            t0 = time.time()
-            self.state, costs = self._scanned_fn(self.state, toks, lens, idxs)
-            # D2H fetch = execution barrier (+ the honest dispatch span).
-            costs = self.spans.dispatch_fetch(
-                "lm_epoch_scan", costs, start=mark, epoch=int(epoch)
-            )
+            with self.spans.dispatch(
+                names.SPAN_LM_EPOCH_SCAN, epoch=int(epoch)
+            ) as sp:
+                t0 = time.time()
+                self.state, costs = self._scanned_fn(
+                    self.state, toks, lens, idxs
+                )
+                # D2H fetch = execution barrier (+ the honest dispatch span).
+                costs = sp.fetch(costs)
             avg_ms = (time.time() - t0) * 1000 / steps
             self._observe_step_time(avg_ms)
             self.last_cost = float(costs[-1])

@@ -34,6 +34,7 @@ import warnings
 
 from typing import TYPE_CHECKING
 
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.train import resilience
 
 if TYPE_CHECKING:  # jax-backed; the probe half of this module is file I/O
@@ -331,7 +332,7 @@ class Supervisor:
             span = (
                 contextlib.nullcontext()
                 if quiet
-                else self._span("checkpoint_save", step=int(step))
+                else self._span(names.SPAN_CHECKPOINT_SAVE, step=int(step))
             )
             with span:
                 self._retry(_write, f"save step_{step}")

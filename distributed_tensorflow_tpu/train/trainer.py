@@ -19,12 +19,14 @@ TPU-first deltas from the reference loop:
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Callable
 
 import jax
 
 from distributed_tensorflow_tpu.config import TrainConfig
 from distributed_tensorflow_tpu.observability import journal as obs_journal
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.observability.metrics import MetricsRegistry
 from distributed_tensorflow_tpu.observability.spans import SpanRecorder
 from distributed_tensorflow_tpu.ops import losses as losses_lib
@@ -513,10 +515,7 @@ class Trainer:
                 perm.reshape(steps, global_batch).astype(_np.int32),
                 getattr(self.strategy, "replicated_sharding", None),
             )
-            step_before = self.strategy.global_step(self.state)
-            mark = self.spans.mark()
-            t0 = time.time()
-            self.state, costs = self._indexed_fn(self.state, xs, ys, idxs)
+            dispatch = partial(self._indexed_fn, self.state, xs, ys, idxs)
         else:
             from distributed_tensorflow_tpu.train.scan import stage_epoch
 
@@ -526,16 +525,16 @@ class Trainer:
             sharding = self.strategy.stage_sharding
             xs = jax.device_put(xs_np, sharding) if sharding else jax.numpy.asarray(xs_np)
             ys = jax.device_put(ys_np, sharding) if sharding else jax.numpy.asarray(ys_np)
-            step_before = self.strategy.global_step(self.state)
-            mark = self.spans.mark()
+            dispatch = partial(self._scanned_fn, self.state, xs, ys)
+        step_before = self.strategy.global_step(self.state)
+        # The fetch IS the execution barrier (CLAUDE.md timing trap), and
+        # the span records the honest dispatch→D2H window.
+        with self.spans.dispatch(
+            names.SPAN_EPOCH_SCAN, epoch=int(epoch)
+        ) as sp:
             t0 = time.time()
-            self.state, costs = self._scanned_fn(self.state, xs, ys)
-        # dispatch_fetch = jax.device_get + the host span: the fetch IS the
-        # execution barrier (CLAUDE.md timing trap), and the span records
-        # the honest dispatch→D2H window.
-        costs = self.spans.dispatch_fetch(
-            "epoch_scan", costs, start=mark, epoch=int(epoch)
-        )
+            self.state, costs = dispatch()
+            costs = sp.fetch(costs)
         elapsed = time.time() - t0
         self.last_cost = costs[-1]
         self._epoch_costs = costs  # anomaly guard sees every step's cost
@@ -674,27 +673,33 @@ class Trainer:
             stage("test_y", test.labels),
             shuffle_key,
         )
-        mark = self.spans.mark()
-        if use_pallas:
-            from distributed_tensorflow_tpu.ops.pallas_mlp import (
-                from_fused,
-                to_fused,
-            )
-            from distributed_tensorflow_tpu.parallel.strategy import TrainState
+        with self.spans.dispatch(
+            names.SPAN_COMPILED_RUN, epochs=int(epochs), engine=cfg.engine
+        ) as sp:
+            if use_pallas:
+                from distributed_tensorflow_tpu.ops.pallas_mlp import (
+                    from_fused,
+                    to_fused,
+                )
+                from distributed_tensorflow_tpu.parallel.strategy import (
+                    TrainState,
+                )
 
-            fused, metrics = run_fn(to_fused(self.state.params), *staged_args)
-            n_steps = int(metrics["costs"].shape[0] * metrics["costs"].shape[1])
-            self.state = TrainState(
-                from_fused(fused), self.state.opt_state, self.state.step + n_steps
-            )
-        else:
-            self.state, metrics = run_fn(self.state, *staged_args)
-        # D2H fetches double as the execution barrier (CLAUDE.md timing
-        # trap); dispatch_fetch also records the honest dispatch span.
-        costs = self.spans.dispatch_fetch(
-            "compiled_run", metrics["costs"], start=mark,
-            epochs=int(epochs), engine=cfg.engine,
-        )
+                fused, metrics = run_fn(
+                    to_fused(self.state.params), *staged_args
+                )
+                n_steps = int(
+                    metrics["costs"].shape[0] * metrics["costs"].shape[1]
+                )
+                self.state = TrainState(
+                    from_fused(fused), self.state.opt_state,
+                    self.state.step + n_steps,
+                )
+            else:
+                self.state, metrics = run_fn(self.state, *staged_args)
+            # D2H fetches double as the execution barrier (CLAUDE.md
+            # timing trap) and close the honest dispatch span.
+            costs = sp.fetch(metrics["costs"])
         accs = jax.device_get(metrics["accuracy"])
         elapsed = time.time() - t0
         batch_count = costs.shape[1]

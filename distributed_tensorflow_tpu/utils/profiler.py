@@ -6,14 +6,16 @@ loop (AvgTime/Total Time, reference tfdist_between.py:98-110) — kept as-is in
 prescribes: ``jax.profiler`` traces (XLA op-level timelines viewable in
 TensorBoard/Perfetto) and an on-demand profiling server.
 
-Round 10: both wrappers compose with the host-side span layer
+Round 10: :func:`trace` composes with the host-side span layer
 (``observability/spans.py``) — pass a :class:`~observability.spans.
-SpanRecorder` and the device trace window / annotation also lands as a
-host span, so ``obs_report --trace``'s chrome-trace export shows WHERE in
-the run the device capture happened. The device trace remains the
-authority on what the chip did; host spans are the authority on what the
-host waited for (and their dispatch flavor enforces the D2H barrier that
-``jax.profiler`` does not).
+SpanRecorder` and the device trace window also lands as a host span, so
+``obs_report --trace``'s chrome-trace export shows WHERE in the run the
+device capture happened. The device trace remains the authority on what
+the chip did; host spans are the authority on what the host waited for
+(and their dispatch flavor enforces the D2H barrier that ``jax.profiler``
+does not). PR 26: the recorder itself writes every span into the profile
+as a ``dtf:<name>`` annotation, so a named region on the device timeline
+is a ``recorder.span(name)`` — no wrapper here.
 """
 
 from __future__ import annotations
@@ -50,16 +52,3 @@ def start_server(port: int = 9999):
     """Start the on-demand profiling server (connect with TensorBoard's
     profile tab or `xprof`); returns the server object."""
     return jax.profiler.start_server(port)
-
-
-@contextlib.contextmanager
-def annotate(name: str, recorder=None):
-    """Named region on the device trace timeline — and, when ``recorder``
-    is given, the same region as a host span (one name, both views)."""
-    ctx = (
-        recorder.span(name, cat="annotation")
-        if recorder is not None
-        else contextlib.nullcontext()
-    )
-    with ctx, jax.profiler.TraceAnnotation(name):
-        yield

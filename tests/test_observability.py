@@ -29,6 +29,7 @@ import struct
 import subprocess
 import sys
 import textwrap
+import time
 
 import pytest
 
@@ -185,14 +186,26 @@ def test_dispatch_span_error_is_recorded_not_masked():
     assert rec.spans[-1]["args"]["error"] is True
 
 
-def test_dispatch_fetch_with_jax_array():
+def test_dispatch_span_with_jax_array():
+    """The case ``mark()`` + ``dispatch_fetch`` used to cover (PR 26 moved
+    their callers to the ``with`` form): a device array comes back as a
+    host value, and arguments written between the fetch and the end of
+    the block land in the span."""
     import jax.numpy as jnp
 
     rec = obs.SpanRecorder()
-    mark = rec.mark()
-    host = rec.dispatch_fetch("scan", jnp.arange(4.0), start=mark, epoch=0)
+    with rec.dispatch("scan", epoch=0) as sp:
+        host = sp.fetch(jnp.arange(4.0))
+        sp.args["delivered"] = 4
     assert list(host) == [0.0, 1.0, 2.0, 3.0]
-    assert rec.spans[-1]["args"] == {"epoch": 0, "barrier": "d2h"}
+    assert rec.spans[-1]["args"] == {
+        "epoch": 0, "delivered": 4, "barrier": "d2h",
+    }
+    # the span ends AT the fetch, not at the end of the block
+    with rec.dispatch("scan") as sp:
+        sp.fetch(1.0)
+        time.sleep(0.05)
+    assert rec.spans[-1]["dur_us"] < 40_000
 
 
 def test_chrome_trace_export_loads(tmp_path):
@@ -659,7 +672,6 @@ def test_text_server_telemetry(tmp_path):
     m = srv.metrics
     assert m.counter("admissions_total").value == 3
     assert m.counter("completions_total").value == 3
-    assert m.counter("slot_evictions_total").value == 3
     assert m.counter("tokens_generated_total").value == 18
     assert m.histogram("ttft_s").count == 3
     assert m.histogram("request_latency_s").count == 3

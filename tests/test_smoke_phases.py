@@ -79,9 +79,24 @@ def test_block_until_ready_probe_shape(smoke):
 
 
 def test_mlp_phase(smoke, meter, small_datasets, capsys):
+    from distributed_tensorflow_tpu.data.mnist import DataSet, Datasets
+
+    # The session's arrays under a shuffle state of their own: the shared
+    # fixture's position depends on which tests ran before on this worker,
+    # and "the cost fell" over two short epochs read 9.03 -> 9.27 once in a
+    # whole run of the suite (PR 26) where it passes alone.
+    fresh = Datasets(
+        train=DataSet(
+            small_datasets.train.images, small_datasets.train.labels, seed=1
+        ),
+        validation=small_datasets.validation,
+        test=DataSet(
+            small_datasets.test.images, small_datasets.test.labels, seed=2
+        ),
+    )
     smoke.phase_mlp(
         meter, compiled_kernels=False, epochs=2, fused_steps=4,
-        datasets=small_datasets,
+        datasets=fresh,
     )
     (line,) = _lines(capsys)
     assert line["phase"] == "mlp" and line["passed"]
