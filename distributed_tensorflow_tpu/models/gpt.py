@@ -1309,12 +1309,16 @@ class GPTLM:
     def _commit_paged_rows(
         self, pk, pv, pks, pvs, kq, vq, ksc, vsc, tables, lengths, act
     ):
-        """The ONE paged fresh-row commit (scatter through the block
+        """One layer's paged fresh-row commit (scatter through the block
         tables at position ``lengths[s]``; inactive rows drop at the
-        sentinel) — shared by the XLA engine (``_decode_block_paged``)
-        and the fused Pallas engine (``_decode_paged_pallas``), same
-        by-construction guarantee as :meth:`_commit_slot_rows`.
-        Row/scale shapes as there. Returns (nk, nv, nks, nvs)."""
+        sentinel) for the per-layer Pallas engine
+        (``_decode_paged_pallas``), which still takes each layer's pool
+        out of the stack and stacks them back. The XLA engine commits
+        all layers at once into the stacked pool
+        (``ops/paged_attention.commit_token_rows``); both reach the pool
+        through the one ``_table_index`` arithmetic there, so the two
+        engines write identical pools by construction. Row/scale shapes
+        as in :meth:`_commit_slot_rows`. Returns (nk, nv, nks, nvs)."""
         from distributed_tensorflow_tpu.ops import paged_attention as paged
 
         pos = lengths[:, None]
@@ -1597,18 +1601,16 @@ class GPTLM:
             self.num_kv_heads,
             self.head_dim,
         )
-        z = jnp.zeros(shape, kv_storage_dtype(kv_dtype, self.compute_dtype))
-        sc = (
-            None
-            if kv_dtype == "bf16"
-            else jnp.zeros(shape[:-1], jnp.float32)
-        )
+        # One buffer each: a server donates its cache to the programs
+        # that return it, and a buffer can be given away once.
+        dt = kv_storage_dtype(kv_dtype, self.compute_dtype)
+        scaled = kv_dtype != "bf16"
         return SlotKVCache(
-            k=z,
-            v=z,
+            k=jnp.zeros(shape, dt),
+            v=jnp.zeros(shape, dt),
             lengths=jnp.zeros((slots,), jnp.int32),
-            k_scale=sc,
-            v_scale=sc,
+            k_scale=jnp.zeros(shape[:-1], jnp.float32) if scaled else None,
+            v_scale=jnp.zeros(shape[:-1], jnp.float32) if scaled else None,
         )
 
     def reset_slots(self, cache: SlotKVCache, free: jax.Array) -> SlotKVCache:
@@ -1993,19 +1995,16 @@ class GPTLM:
             self.num_kv_heads,
             self.head_dim,
         )
-        z = jnp.zeros(shape, kv_storage_dtype(kv_dtype, self.compute_dtype))
-        sc = (
-            None
-            if kv_dtype == "bf16"
-            else jnp.zeros(shape[:-1], jnp.float32)
-        )
+        # One buffer each, as in :meth:`empty_slot_cache`.
+        dt = kv_storage_dtype(kv_dtype, self.compute_dtype)
+        scaled = kv_dtype != "bf16"
         return PagedKVCache(
-            k=z,
-            v=z,
+            k=jnp.zeros(shape, dt),
+            v=jnp.zeros(shape, dt),
             block_tables=jnp.zeros((slots, nb_slot), jnp.int32),
             lengths=jnp.zeros((slots,), jnp.int32),
-            k_scale=sc,
-            v_scale=sc,
+            k_scale=jnp.zeros(shape[:-1], jnp.float32) if scaled else None,
+            v_scale=jnp.zeros(shape[:-1], jnp.float32) if scaled else None,
         )
 
     def extend_paged(
@@ -2172,59 +2171,63 @@ class GPTLM:
             k=nk, v=nv, k_scale=nks, v_scale=nvs
         )
 
-    def _decode_block_paged(self, blk, h, pk, pv, block_tables, lengths,
-                            act, pks=None, pvs=None, qd=None):
+    def _decode_block_paged(self, blk, h, cache, layer, act, qd=None):
         """Per-slot single-token block step against the BLOCK POOL —
-        :meth:`_decode_block_slots` with the slab row replaced by a
-        scatter-then-gather through the block tables: the fresh K/V row
-        lands at ``(table[s, len // bs], len % bs)`` (inactive rows drop
-        at the sentinel), then the slot's contiguous view is gathered
-        back and attended with the same ``idx <= lengths`` validity.
-        Windowed models band by mask (``idx > lengths − W``) — absolute
-        addressing, no rolling arithmetic. Quantized pools (``qd`` +
-        pks/pvs scale pools) quantize the fresh row before its scatter
-        and dequantize the gathered view before the softmax — the scale
-        pools ride the same scatter/gather index math."""
+        :meth:`_decode_block_slots` with the slab row replaced by a read
+        through the block tables. The layer-stacked pool is READ-ONLY
+        here: layer ``layer``'s contiguous per-slot view is gathered
+        through ``(layer, block_tables)`` straight out of the stack, and
+        the fresh storage-dtype K/V row takes its place in that small
+        view at position ``lengths[s]`` (active rows only — an inactive
+        row sees what the pool holds there, as after a write dropped at
+        the sentinel), so the view is bit for bit what a
+        scatter-then-gather of the pool gives. Attention masks by the
+        same ``idx <= lengths`` validity; windowed models band by mask
+        (``idx > lengths − W``) — absolute addressing, no rolling
+        arithmetic. Quantized pools (``qd``) quantize the fresh row
+        before it is placed and dequantize the view before the softmax;
+        the scale side pools ride the same gather and the same
+        placement. Returns ``(h, (kq, vq, ksc, vsc))``: the rows
+        (``[S, Hkv, Dh]``, scales ``[S, Hkv]`` or None) that
+        :meth:`decode_paged` commits for all layers at once."""
         from distributed_tensorflow_tpu.ops import paged_attention as paged
+
+        tables, lengths = cache.block_tables, cache.lengths
+
+        def view(pool, row):
+            got = paged.gather_block_view(pool, tables, layer)
+            with jax.named_scope(names.KV_GATHER):
+                # a pool carried with its rows flat: back to the row's axes
+                got = got.reshape(got.shape[:2] + row.shape[1:])
+                here = (jnp.arange(got.shape[1])[None, :]
+                        == lengths[:, None]) & act[:, None]  # [S, C]
+                here = here.reshape(here.shape + (1,) * (got.ndim - 2))
+                return jnp.where(here, row[:, None], got)
 
         def cache_update(k, v):
             if qd is None:
-                kq = k.astype(pk.dtype)[:, 0]
-                vq = v.astype(pv.dtype)[:, 0]
+                kq = k.astype(cache.k.dtype)[:, 0]
+                vq = v.astype(cache.v.dtype)[:, 0]
                 ksc = vsc = None
             else:
                 kq, ksc = quantize_kv(k[:, 0], qd)  # [S,Hkv,Dh] + [S,Hkv]
                 vq, vsc = quantize_kv(v[:, 0], qd)
-            # The shared commit (round 18: also the Pallas engine's) —
-            # scatter through the block tables, inactive rows dropping
-            # at the sentinel.
-            nk, nv, nks, nvs = self._commit_paged_rows(
-                pk, pv, pks, pvs, kq, vq, ksc, vsc, block_tables,
-                lengths, act,
-            )
-            state = (nk, nv, nks, nvs)
-            ck = paged.gather_block_view(nk, block_tables)  # [S, C, Hkv, Dh]
-            cv = paged.gather_block_view(nv, block_tables)
+            ck, cv = view(cache.k, kq), view(cache.v, vq)  # [S, C, Hkv, Dh]
             if qd is not None:
                 # compute_dtype view, not f32 (see _decode_block_slots).
                 ck = dequantize_kv(
-                    ck,
-                    paged.gather_block_view(nks, block_tables),
-                    self.compute_dtype,
+                    ck, view(cache.k_scale, ksc), self.compute_dtype
                 )
                 cv = dequantize_kv(
-                    cv,
-                    paged.gather_block_view(nvs, block_tables),
-                    self.compute_dtype,
+                    cv, view(cache.v_scale, vsc), self.compute_dtype
                 )
             idx = jnp.arange(ck.shape[1])[None, :]  # [1, C] absolute
             valid = idx <= lengths[:, None]  # [S, C]
             if self.window is not None:
                 valid &= idx > lengths[:, None] - self.window
-            return ck, cv, valid, state
+            return ck, cv, valid, (kq, vq, ksc, vsc)
 
-        h, state = self._decode_block_step(blk, h, lengths, cache_update)
-        return h, state
+        return self._decode_block_step(blk, h, lengths, cache_update)
 
     def decode_paged(
         self,
@@ -2237,11 +2240,26 @@ class GPTLM:
     ):
         """Append one token per slot through the block tables — the
         paged counterpart of :meth:`decode_slots` (same masking
-        contract: inactive rows untouched, garbage logits to discard;
-        layer loop UNROLLED for the same double-buffering reason).
+        contract: inactive rows untouched, garbage logits to discard).
         The caller guarantees each active slot's table covers position
         ``lengths[s]`` (the engine reserves ``prompt + max_new`` blocks
-        at admission, so generation never outgrows the table)."""
+        at admission, so generation never outgrows the table).
+
+        The XLA engine never moves the pool: the layer loop (UNROLLED,
+        as in :meth:`decode_step`) reads each layer through
+        ``(layer, block_tables)`` out of the one stacked
+        ``[layers, blocks, block_size, Hkv, Dh]`` array, and ONE update
+        for K and one for V (and one per scale pool) commits every
+        layer's fresh row after it
+        (``ops/paged_attention.commit_token_rows``: the places and the
+        sentinel-drop of the commit :meth:`extend_paged` makes after its
+        layer scan). The result is the argument changed at
+        ``slots × layers`` rows, which XLA does in place on a loop carry
+        and on a donated argument (``TextServer`` gives it both). The
+        pools may also come with each position's ``[Hkv, Dh]`` row flat,
+        ``[layers, blocks, block_size, Hkv·Dh]``, as the server's chunk
+        scan carries them (the chip tiles an array by its two minor
+        axes); they go back as they came."""
         act = (
             jnp.ones((token.shape[0],), bool) if active is None else active
         )
@@ -2263,21 +2281,25 @@ class GPTLM:
             return self._decode_paged_mega(params, h, cache, act, qd)
         if eng == "pallas-layer":
             return self._decode_paged_pallas(params, h, cache, act, qd)
-        nks, nvs, nksc, nvsc = [], [], [], []
+        from distributed_tensorflow_tpu.ops import paged_attention as paged
+
+        fresh = []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
-            pk, pv, pks, pvs = _cache_layer(cache, i)
-            h, (pk, pv, pks, pvs) = self._decode_block_paged(
-                blk, h, pk, pv, cache.block_tables, cache.lengths, act,
-                pks, pvs, qd,
+            h, rows = self._decode_block_paged(blk, h, cache, i, act, qd)
+            fresh.append(rows)
+
+        def commit(pool, rows):
+            return paged.commit_token_rows(
+                pool, jnp.stack(rows), cache.block_tables, cache.lengths, act
             )
-            nks.append(pk)
-            nvs.append(pv)
-            nksc.append(pks)
-            nvsc.append(pvs)
-        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
+
+        kq, vq, ksc, vsc = zip(*fresh)
         new_cache = cache._replace(
-            k=nk, v=nv, k_scale=nks, v_scale=nvs,
+            k=commit(cache.k, kq),
+            v=commit(cache.v, vq),
+            k_scale=None if qd is None else commit(cache.k_scale, ksc),
+            v_scale=None if qd is None else commit(cache.v_scale, vsc),
             lengths=cache.lengths + act.astype(jnp.int32),
         )
         return self._logits(params, h)[:, 0], new_cache
@@ -2288,9 +2310,9 @@ class GPTLM:
         block tables ride as scalar-prefetch args — the pool is read
         block-by-block in the grid, no contiguous ``gather_block_view``
         copy), then the fresh row committed through
-        :meth:`_commit_paged_rows` — the SAME helper the XLA engine's
-        ``cache_update`` calls, so both engines write identical pools
-        by construction."""
+        :meth:`_commit_paged_rows` — the table arithmetic of the XLA
+        engine's all-layer commit, so both engines write identical
+        pools by construction."""
         from distributed_tensorflow_tpu.ops.pallas_decode import (
             decode_block_paged,
         )
@@ -2585,8 +2607,11 @@ def _cache_layer(cache, i: int):
     """Layer ``i``'s ``(k, v, k_scale, v_scale)`` out of a layer-stacked
     cache (scales None on a bf16 cache, and on a :class:`KVCache`, which
     has none). With :func:`_restack` this is the per-step restack of the
-    whole cache that the unrolled decode loops pay — scoped, so a trace
-    shows what it costs."""
+    whole cache that the unrolled loops of ``decode_step``,
+    ``decode_slots`` and the two ``pallas-layer`` variants pay — scoped,
+    so a trace shows what it costs (``decode_paged``'s XLA engine reads
+    the stack in place instead: a third of its step at gpt2-large's
+    size, PERF.md §6, PR 27)."""
     with jax.named_scope(names.KV_RESTACK):
         ks = getattr(cache, "k_scale", None)
         vs = getattr(cache, "v_scale", None)
@@ -2599,7 +2624,8 @@ def _cache_layer(cache, i: int):
 
 def _restack(nks, nvs, nksc=(), nvsc=()):
     """The per-layer results of an unrolled decode loop back into the
-    layer-stacked layout (scale lists of Nones, or empty, give None)."""
+    layer-stacked layout (scale lists of Nones, or empty, give None):
+    the other half of :func:`_cache_layer`, for the same callers."""
     with jax.named_scope(names.KV_RESTACK):
         scaled = bool(nksc) and nksc[0] is not None
         return (

@@ -40,17 +40,26 @@ from distributed_tensorflow_tpu.ops.ring_attention import group_query_heads
 _NEG_INF = -1e30
 
 
-def gather_block_view(pool_layer: jax.Array, block_tables: jax.Array):
+def gather_block_view(
+    pool: jax.Array, block_tables: jax.Array, layer: int | None = None
+):
     """One layer's per-slot contiguous K (or V) view through the block
     tables: ``[num_blocks, bs, Hkv, Dh]`` + ``[S, NB]`` →
     ``[S, NB*bs, Hkv, Dh]``, where view position ``p`` is logical
-    position ``p`` of the slot. Unused table entries gather garbage that
-    the caller's validity mask must keep out of the softmax."""
-    bs = pool_layer.shape[1]
+    position ``p`` of the slot. With ``layer`` given, ``pool`` is the
+    layer-STACKED pool ``[n, num_blocks, bs, Hkv, Dh]`` and the view is
+    read through ``(layer, block_tables)`` in one gather, so no layer's
+    slice of the pool is materialized on the way (the decode step, which
+    must leave the stacked pool where it lies). Unused table entries
+    gather garbage that the caller's validity mask must keep out of the
+    softmax."""
     s, tabs = block_tables.shape
     with jax.named_scope(names.KV_GATHER):
-        view = jnp.take(pool_layer, block_tables, axis=0)  # [S,NB,bs,H,D]
-        return view.reshape(s, tabs * bs, *pool_layer.shape[2:])
+        if layer is None:
+            view = jnp.take(pool, block_tables, axis=0)  # [S,NB,bs,H,D]
+        else:
+            view = pool[layer, block_tables]
+        return view.reshape(s, tabs * view.shape[2], *view.shape[3:])
 
 
 def scatter_token_kv(
@@ -75,6 +84,14 @@ def scatter_token_kv(
     )[0]
 
 
+def _table_index(block_tables, positions, valid, num_blocks, block_size):
+    """``(block, offset)`` of logical ``positions`` [S, L] through the
+    tables, masked writes routed to the sentinel block ``num_blocks``:
+    the one place the out-of-range discipline's arithmetic lives."""
+    bidx = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
+    return jnp.where(valid, bidx, num_blocks), positions % block_size
+
+
 def scatter_token_kv_all_layers(
     pool: jax.Array,
     kvs: jax.Array,
@@ -87,12 +104,38 @@ def scatter_token_kv_all_layers(
     n, nb, bs = pool.shape[0], pool.shape[1], pool.shape[2]
     s, l = positions.shape
     with jax.named_scope(names.KV_WRITE):
-        bidx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-        bidx = jnp.where(valid, bidx, nb)
-        off = positions % bs
+        bidx, off = _table_index(block_tables, positions, valid, nb, bs)
         flat = kvs.reshape(n, s * l, *kvs.shape[3:])
         return pool.at[:, bidx.reshape(-1), off.reshape(-1)].set(
             flat, mode="drop"
+        )
+
+
+def commit_token_rows(
+    pool: jax.Array,
+    rows: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    active: jax.Array,
+):
+    """The decode step's commit: ``rows`` [n, S, ...] holds one fresh row
+    per layer and slot, written at position ``lengths[s]`` of slot ``s``
+    where ``active``, dropped at the sentinel where not — the values and
+    the places of ``scatter_token_kv_all_layers`` with ``L = 1``. The
+    update is indexed by ``(layer, block, offset)`` triples, so its
+    window is a row's own trailing axes and nothing else: XLA:TPU then
+    leaves the pool in the layout the step's gathers read and updates it
+    where it lies. With the window over the layer axis (the extend
+    path's form, right for its ``S × L`` rows) it wants that axis minor
+    and copies the whole pool there and back on every step (compiled
+    for a v5e at gpt2-large's size, PERF.md §6, PR 27)."""
+    n, nb, bs = pool.shape[0], pool.shape[1], pool.shape[2]
+    with jax.named_scope(names.KV_WRITE):
+        bidx, off = _table_index(
+            block_tables, lengths[:, None], active[:, None], nb, bs
+        )
+        return pool.at[jnp.arange(n)[:, None], bidx.T, off.T].set(
+            rows.reshape(rows.shape[:2] + pool.shape[3:]), mode="drop"
         )
 
 

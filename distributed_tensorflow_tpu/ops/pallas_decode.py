@@ -17,7 +17,7 @@ into ONE kernel body (:func:`_decode_kernel`) behind five entry points:
 - :func:`decode_block_slab` / :func:`decode_block_paged` —
   ``decode_engine="pallas-layer"``: one launch per LAYER; the fresh K/V
   rows come back as outputs and ``models/gpt.py`` commits them with the
-  XLA engine's own scatter (``_commit_slot_rows`` /
+  XLA engine's own scatter arithmetic (``_commit_slot_rows`` /
   ``_commit_paged_rows``), so the two engines' caches agree by
   construction.
 - :func:`decode_token_slab` / :func:`decode_token_paged` —

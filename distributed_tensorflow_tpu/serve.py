@@ -7,7 +7,10 @@ is the LM analog — text in, text out, from a checkpoint directory — built
 from the pieces rounds 5-8 left on the table: the cross-topology canonical
 restore (``step_N.layout.json`` sidecars), the ``tokenizer.json`` the
 LMTrainer ships into ``checkpoint_dir``, and the unrolled-layer KV-cache
-decode step. Three serving-engine ideas, on one chip whose every
+decode step (the paged one updates the stacked pool where it lies, and
+every program that returns the server's state takes it donated, so the
+cache is never held twice nor moved: PERF.md §6, PR 27). Three
+serving-engine ideas, on one chip whose every
 dispatch-and-fetch has a fixed host cost (not measured on a directly
 attached chip yet — ROADMAP S2):
 
@@ -36,6 +39,7 @@ output does not depend on what shared the batch with it.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import os
@@ -579,11 +583,19 @@ class TextServer:
         # caught this live); that measurement is discarded instead.
         self._tok_first_dispatch = True
         self._state = self._init_state()
+        # One rule for every program that returns the state: it takes the
+        # state DONATED, so the KV cache is updated where it lies and the
+        # device never holds it twice (:meth:`_state_lent` is the other
+        # half: what a dispatch that fails owes the server).
         self._prefill_jit = jax.jit(
-            self._paged_prefill_graph if paged else self._prefill_graph
+            self._paged_prefill_graph if paged else self._prefill_graph,
+            donate_argnums=1,
         )
-        self._chunk_jit = jax.jit(self._chunk_graph)
-        self._verify_jit = jax.jit(self._verify_graph) if spec_draft else None
+        self._chunk_jit = jax.jit(self._chunk_graph, donate_argnums=1)
+        self._verify_jit = (
+            jax.jit(self._verify_graph, donate_argnums=1)
+            if spec_draft else None
+        )
         if paged:
             self.metrics.gauge("kv_blocks_total").set(self.kv_blocks)
             self.metrics.gauge("kv_blocks_used").set(0)
@@ -677,6 +689,42 @@ class TextServer:
             v_scale=cache.v_scale,
             **common,
         )
+
+    @contextlib.contextmanager
+    def _state_lent(self):
+        """The stretch of a dispatch, fetch included, in which a program
+        owns the server's state (the three jitted programs take it
+        donated). One that raises before it runs — tracing, compiling, an
+        argument refused — has taken nothing, and the server stands as it
+        stood. One that fails holding the buffers leaves no state to go
+        back to and every resident's KV is lost with it: the residents
+        return to the head of the queue, to be served again from their
+        prompts on a vacant state, and the error goes on to the caller."""
+        lent = self._state
+        try:
+            yield
+        except BaseException:
+            if isinstance(lent.k, jax.Array) and lent.k.is_deleted():
+                self._restart_residents()
+            raise
+
+    def _restart_residents(self) -> None:
+        residents = [r for r in self._slot_req if r is not None]
+        for req in residents:
+            # A migrated-in request keeps the tokens its first leg
+            # emitted: its payload is imported again at admission.
+            kept = 0 if req.resume is None else req.resume["meta"]["emitted"]
+            del req.out[int(kept):]
+            req.t_admit = req.t_first = None
+        for slot in range(self.slots):  # planned blocks too, resident or not
+            self._release_slot(slot)
+        if self._prefix is not None:
+            # The radix names blocks of a pool that is gone; with no
+            # resident left every one of them is cache-only.
+            self._prefix.evict(self._prefix.evictable_blocks())
+        self._queue.extendleft(reversed(residents))
+        self.metrics.gauge("queue_depth").set(len(self._queue))
+        self._state = self._init_state()
 
     def _pick(self, logits, key_data, greedy, temp, top_p):
         """Per-slot next-token pick, the exact arithmetic of
@@ -912,6 +960,20 @@ class TextServer:
         decode = (
             self.model.decode_paged if self.paged else self.model.decode_slots
         )
+        # The XLA engine's paged step updates the pool where it lies, and
+        # the scan carries it with each position's [Hkv, Dh] row FLAT:
+        # the chip tiles an array's two minor axes (8 × 128 words), so a
+        # carry ending in [20, 64] is padded 3.2 times over, where
+        # [.., 1280] is not and a block is one contiguous tile to gather.
+        # Two reshapes a chunk, none a step.
+        pool_shape = st.k.shape
+        rows_flat = self.paged and (
+            self.model._resolve_decode_engine(self.decode_engine, params)
+            == "xla"
+        )
+        if rows_flat:
+            flat = pool_shape[:3] + (-1,)
+            st = st._replace(k=st.k.reshape(flat), v=st.v.reshape(flat))
 
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
@@ -947,6 +1009,10 @@ class TextServer:
         st, (toks, valid) = jax.lax.scan(
             body, st, None, length=self.chunk
         )
+        if rows_flat:
+            st = st._replace(
+                k=st.k.reshape(pool_shape), v=st.v.reshape(pool_shape)
+            )
         return st, toks, valid
 
     # -- the scheduler (host side) -----------------------------------------
@@ -1693,7 +1759,7 @@ class TextServer:
                         wave=int(wave),
                     ),
                 )
-            with self.spans.dispatch(
+            with self._state_lent(), self.spans.dispatch(
                 names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
                 rids=[int(m[1].rid) for m in members],
             ) as sp:
@@ -1751,7 +1817,7 @@ class TextServer:
                 self._admit_member_row(
                     slot, req, lb, key, budget, greedy, temp, top_p, eos
                 )
-            with self.spans.dispatch(
+            with self._state_lent(), self.spans.dispatch(
                 names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
                 rids=[int(r.rid) for _, r in members],
             ) as sp:
@@ -1967,7 +2033,7 @@ class TextServer:
                     suffix[slot, 1 : 1 + len(d)] = d
                     slens[slot] = 1 + len(d)
                     proposed += len(d)
-        with self.spans.dispatch(
+        with self._state_lent(), self.spans.dispatch(
             names.SPAN_SPEC_VERIFY, draft=self.spec_draft,
             active=int(occupied),
             rids=[int(r.rid) for r in self._slot_req if r is not None],
@@ -2029,7 +2095,7 @@ class TextServer:
             if spec:
                 toks, valid = self._spec_dispatch(occupied)
             else:
-                with self.spans.dispatch(
+                with self._state_lent(), self.spans.dispatch(
                     names.SPAN_DECODE_CHUNK, chunk=self.chunk,
                     active=int(occupied),
                     rids=[

@@ -198,3 +198,49 @@ def test_auto_rule_matches_what_compiles():
     assert admits(num_heads=4)
     assert not admits(num_heads=8)                   # head_dim 64
     assert not admits(num_heads=4, num_kv_heads=2)   # two-row commit group
+
+
+# -- serving side: the XLA engine's paged chunk program ----------------------
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
+    """gpt2-large's head geometry (20 × 64, the benchmark's chat cell) at
+    three layers: inside the chunk's loop the chip's compiler makes no
+    copy of the pool and no second pool, only the two scatters that
+    update it. XLA:TPU lays an array out by its two minor axes, and a
+    commit whose window spans the layer axis, or a carry that ends in
+    [20, 64], each brought two whole-pool copies a step back (PR 27)."""
+    import re
+
+    import numpy as np
+
+    from distributed_tensorflow_tpu.models.gpt import GPTLM
+    from distributed_tensorflow_tpu.serve import TextServer
+
+    model = GPTLM(
+        vocab_size=512, max_len=256, model_dim=1280, num_heads=20, num_layers=3
+    )
+    srv = TextServer(
+        model, None, slots=4, chunk=4, paged=True, block_size=16,
+        kv_blocks=64, kv_dtype=kv, decode_engine="xla",
+    )
+    on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=chip)
+    text = srv._chunk_jit.lower(
+        jax.tree.map(on_chip, jax.eval_shape(model.init)),
+        jax.tree.map(on_chip, srv._state),
+    ).compile().as_text()
+    assert "input_output_alias" in text
+    loop = text[: text.index("\nENTRY ")]  # every computation but the entry
+    size = srv._state.k.size  # no other array of the program has it
+    big = [
+        (op, name) for name, dims, op in re.findall(
+            r"%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", loop)
+        if np.prod([int(d) for d in dims.split(",") if d]) == size
+        and op not in ("parameter", "get-tuple-element", "bitcast", "tuple")
+    ]
+    moved = [b for b in big if b[0] in (
+        "copy", "concatenate", "slice", "dynamic-slice", "dynamic-update-slice")]
+    assert not moved, moved
+    assert sum(op == "scatter" for op, _ in big) == 2, big

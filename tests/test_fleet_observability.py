@@ -365,7 +365,7 @@ def test_gang_chrome_trace_tracks_and_mirrored_restart(tmp_path):
 # ---------------------------------------------------------------------------
 
 _GANG_WORKER = """
-import os, sys
+import os, sys, time
 import distributed_tensorflow_tpu.observability as obs
 j = obs.configure_from_env()           # DTF_JOURNAL_DIR/DTF_RANK from driver
 rank = os.environ["DTF_RANK"]
@@ -373,6 +373,14 @@ j.emit("step", step=1, epoch=1, batch=1, batch_count=2, cost=1.0, avg_ms=2.0)
 marker = os.path.join(os.environ["DTF_JOURNAL_DIR"], "fail_once")
 if rank == "0" and not os.path.exists(marker):
     open(marker, "w").close()
+    # Die only once rank 1 has announced itself: the driver kills the gang
+    # at this exit, and on a loaded machine an incarnation killed before
+    # its first event never reaches its journal.
+    other = obs.rank_journal_path(os.environ["DTF_JOURNAL_DIR"], 1)
+    until = time.time() + 60
+    while time.time() < until and not (
+            os.path.exists(other) and os.path.getsize(other)):
+        time.sleep(0.01)
     j.close()
     sys.exit(3)                         # first incarnation dies -> restart
 j.emit("step", step=2, epoch=1, batch=2, batch_count=2, cost=0.5, avg_ms=2.0)

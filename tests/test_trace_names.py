@@ -249,8 +249,7 @@ PROGRAM_CASES = {
     # program -> (paged, jitted attribute, scopes beyond SERVING_SCOPES)
     names.PROGRAM_PAGED_PREFILL: (True, "_prefill_jit", {names.KV_GATHER}),
     names.PROGRAM_PREFILL: (False, "_prefill_jit", set()),
-    names.PROGRAM_CHUNK: (
-        True, "_chunk_jit", {names.KV_GATHER, names.KV_RESTACK}),
+    names.PROGRAM_CHUNK: (True, "_chunk_jit", {names.KV_GATHER}),
     names.PROGRAM_CHUNK + "[slab]": (
         False, "_chunk_jit", {names.KV_RESTACK}),
     names.PROGRAM_VERIFY: (True, "_verify_jit", {names.KV_GATHER}),
