@@ -13,7 +13,11 @@ points:
   remat — for a few steps, saving a checkpoint through its Supervisor;
 - the text server (``serve.TextServer.from_checkpoint``) on that
   checkpoint, paged cache, continuous batching, and the Pallas decode
-  kernels against the XLA engine at d=512.
+  kernels against the XLA engine at d=512;
+- the hybrid stack (``models.hybrid.HybridLM``: a state-space, an expert
+  and an attention layer) through the same ``LMTrainer``: its first
+  step's loss against the plain reference
+  (``benchmark/lib/reference_nemotron_h.py``), then a few steps.
 
 Each phase prints one JSON line: its name, seconds (compile apart from
 run, from ``jax.monitoring``), the compile-cache traffic, and what it
@@ -56,6 +60,7 @@ from distributed_tensorflow_tpu.data import copy_corpus, read_data_sets
 from distributed_tensorflow_tpu.launch import build_trainer
 from distributed_tensorflow_tpu.models import MLP
 from distributed_tensorflow_tpu.models.gpt import GPTLM
+from distributed_tensorflow_tpu.models.hybrid import HybridLM
 from distributed_tensorflow_tpu.ops import cross_entropy, sgd
 from distributed_tensorflow_tpu.ops.pallas_mlp import (
     make_fused_epoch_fn,
@@ -82,6 +87,26 @@ FULL_LM_BATCH = 8
 # heads head_dim is 64; at 4 heads it is 128, a geometry the chip's
 # compiler accepts the megakernel at (GPTLM._megakernel_compiles).
 KERNEL_LM = dict(vocab_size=8192, max_len=1024, model_dim=512, num_layers=8)
+
+# One layer of each kind of the hybrid stack, heads and states at the
+# sizes the published models use (state-space heads of 64 with state 128,
+# attention heads of 128, chunk 128), the rest small: seconds on the chip.
+HYBRID_LM = dict(
+    vocab_size=4096, model_dim=1024, pattern="EM*",
+    ssm_heads=16, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+    chunk_size=128, num_experts=16, experts_per_token=2, expert_dim=512,
+    shared_dim=1024, routed_scale=2.5, experts_held=(4, 8),
+    num_heads=8, num_kv_heads=2, head_dim=128,
+    attention_impl="flash", remat=True, balance_rounds=2,
+)
+HYBRID_LEN = 1024
+# The hybrid step's first loss against the float32 reference: bfloat16
+# matmul operands move a loss near ln(vocabulary) by a few parts in 1e4.
+HYBRID_LOSS_RTOL = 2e-3
+# ... and its gradient over the whole tree: the norm of the difference
+# over the reference's norm. bfloat16 operands read about 0.01 (PERF.md
+# section 2, ``moment_rel_err_all``); int8 would read 0.03 and more.
+HYBRID_GRAD_RTOL = 2.5e-2
 
 # The fused MLP kernel's costs against the XLA scan. tests/test_pallas_mlp.py
 # holds them to rtol 1e-5 in true f32 (the interpreter). On the chip the
@@ -428,6 +453,119 @@ def phase_lm_train(
             ).get("peak_bytes_in_use"),
         )
     return model, optimizer, params
+
+
+# -- hybrid ----------------------------------------------------------------
+
+
+def _reference_dims(model: HybridLM) -> dict:
+    """The sizes benchmark/lib/reference_nemotron_h.py reads, from the
+    model's own attributes."""
+    return {
+        "pattern": model.pattern, "eps": model.norm_eps,
+        "ssm_heads": model.ssm_heads, "ssm_head_dim": model.ssm_head_dim,
+        "ssm_groups": model.ssm_groups, "ssm_state": model.ssm_state,
+        "inner": model.ssm_inner, "conv_dim": model.conv_dim,
+        "conv_kernel": model.conv_kernel, "chunk": model.chunk_size,
+        "experts": model.num_experts, "held": model.experts_held,
+        "top_k": model.experts_per_token, "routed_scale": model.routed_scale,
+        "heads": model.num_heads, "kv_heads": model.num_kv_heads,
+        "head_dim": model.head_dim,
+    }
+
+
+def phase_hybrid_train(
+    meter: Meter, *, compiled_kernels: bool, model_kw: dict = HYBRID_LM,
+    seq_len: int = HYBRID_LEN, batch: int = 2, steps: int = 3, seed: int = 0,
+    loss_rtol: float = HYBRID_LOSS_RTOL, grad_rtol: float = HYBRID_GRAD_RTOL,
+):
+    """The hybrid stack through ``LMTrainer``'s scanned epoch: the loss
+    falls, the flash kernel is in the compiled step and the expert layer's
+    counters came back with the costs; then one step's loss and gradient
+    on the trainer's own initial weights against the plain reference."""
+    from benchmark.lib import reference_nemotron_h
+
+    with meter.phase("hybrid_train") as out:
+        model = HybridLM(**model_kw)
+        corpus = copy_corpus(
+            num=(steps + 2) * batch, half_len=seq_len // 2,
+            vocab=model.vocab_size, n_val=batch, n_test=batch, seed=seed,
+        )
+        lines: list[str] = []
+        trainer = LMTrainer(
+            model, corpus,
+            TrainConfig(
+                batch_size=batch, epochs=1, optimizer="adamw",
+                learning_rate=3e-4, log_frequency=1, logs_path="",
+                scan_epoch=True, seed=seed + 1,
+            ),
+            print_fn=lambda *a: lines.append(" ".join(map(str, a))),
+        )
+        start = jax.tree.map(jnp.copy, trainer.state.params)
+        first_batch = corpus.train.tokens[:batch]
+        trainer.run(epochs=2)
+        costs = _costs(lines)
+        check(
+            len(costs) == 2 * steps and all(np.isfinite(costs)),
+            f"expected {2 * steps} finite step costs, got {costs}",
+        )
+        check(costs[-1] < costs[0],
+              f"hybrid loss did not fall: {costs[0]} -> {costs[-1]}")
+        # One step's loss and gradient on the trainer's initial weights,
+        # the model's against the plain reference's.
+        def as_dict(params) -> dict:
+            d = params._asdict()
+            return {k: v._asdict() if hasattr(v, "_asdict") else v
+                    for k, v in d.items()}
+
+        tree = as_dict(start)
+        toks = jnp.asarray(corpus.train.tokens[:batch])
+        got_loss, got = jax.jit(jax.value_and_grad(model.loss))(start, toks)
+        dims = _reference_dims(model)
+        want_loss, want = jax.jit(jax.value_and_grad(
+            lambda t, x: reference_nemotron_h.loss(
+                t, x, dims, balance=model.balance_rounds)))(tree, toks)
+        gap = abs(float(got_loss) - float(want_loss)) / abs(float(want_loss))
+        check(
+            gap <= loss_rtol,
+            f"hybrid loss {float(got_loss)} against the reference's "
+            f"{float(want_loss)}: {gap:.2e} apart, allowed {loss_rtol:.0e}",
+        )
+        pairs_of = list(zip(
+            jax.tree.leaves(as_dict(got)), jax.tree.leaves(want)))
+        grad_err = float(
+            jnp.sqrt(sum(jnp.sum(jnp.square(a - b)) for a, b in pairs_of))
+            / jnp.sqrt(sum(jnp.sum(jnp.square(b)) for _, b in pairs_of)))
+        check(
+            grad_err <= grad_rtol,
+            f"hybrid gradient {grad_err:.2e} from the reference's over the "
+            f"whole tree, allowed {grad_rtol:.0e}",
+        )
+        flash = has_compiled_kernel(
+            _scanned_epoch_program(trainer, steps).as_text())
+        check(
+            flash == compiled_kernels,
+            f"flash kernel in the compiled hybrid step: {flash}, expected "
+            f"{compiled_kernels}",
+        )
+        gauges = {g.name: g.value for g in trainer.metrics
+                  if g.name.startswith("moe_")}
+        pairs = batch * seq_len * model.experts_per_token
+        check(
+            0 < gauges.get("moe_rows_per_step", 0) <= pairs
+            * model.counts["E"],
+            f"rows landed on the held experts: {gauges}",
+        )
+        out.update(
+            model={k: model_kw[k] for k in (
+                "model_dim", "pattern", "num_experts", "experts_held")},
+            params=int(sum(p.size for p in jax.tree.leaves(start))),
+            batch=batch, seq_len=seq_len, steps=2 * steps,
+            first_loss=costs[0], last_loss=costs[-1],
+            loss=float(got_loss), reference_loss=float(want_loss),
+            loss_gap=gap, gradient_rel_err=grad_err,
+            flash_kernel_compiled=flash, gauges=gauges,
+        )
 
 
 # -- serve -----------------------------------------------------------------
@@ -866,6 +1004,8 @@ def run(chips: int, seed: int) -> dict:
             del params
             gc.collect()
             phase_decode_kernels(meter, compiled_kernels=True, seed=seed)
+            gc.collect()
+            phase_hybrid_train(meter, compiled_kernels=True, seed=seed)
     finally:
         meter.close()
     return dict(

@@ -68,9 +68,25 @@ KV_RESTACK = "kv_restack"
 PICK = "pick"
 OPTIMIZER = "optimizer"
 MODEL_SCOPES = (EMBED, ATTN_QKV, ATTN_CORE, ATTN_OUT, MLP, LM_HEAD)
+# The hybrid stack's other mixers (models/hybrid.py). A state-space layer:
+# its projections in and out with the gated norm between, the causal
+# depthwise convolution, the chunked scan. An expert layer: the router, the
+# gather of routed rows and the weighted return, the routed experts'
+# products, the shared expert.
+SSM_PROJ = "ssm_proj"
+SSM_CONV = "ssm_conv"
+SSM_SCAN = "ssm_scan"
+MOE_ROUTE = "moe_route"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+HYBRID_SCOPES = (
+    SSM_PROJ, SSM_CONV, SSM_SCAN, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
+    MOE_SHARED,
+)
 SCOPES = MODEL_SCOPES + (
     LOSS, KV_WRITE, KV_GATHER, KV_RESTACK, PICK, OPTIMIZER,
-)
+) + HYBRID_SCOPES
 
 # -- Pallas kernels -----------------------------------------------------------
 KERNEL_FLASH_FWD = "flash_fwd"

@@ -63,6 +63,8 @@ def force_host(value):
         return np.asarray(value)
     if isinstance(value, (list, tuple)):
         return type(value)(force_host(v) for v in value)
+    if isinstance(value, dict):
+        return {k: force_host(v) for k, v in value.items()}
     return float(value)
 
 

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_tensorflow_tpu.observability import names
+
 
 class MoEParams(NamedTuple):
     wg: jax.Array  # [D, E] gate
@@ -292,3 +294,194 @@ def moe_ffn(
     gathered = back[rows, cols]  # [T_loc, k, D]
     result = _combine(gate_w, keep, gathered)
     return (result, aux) if with_aux else result
+
+
+# -- the dropless layer, told which experts it holds ---------------------------
+#
+# The capacity layer above gives every expert a fixed buffer and drops what
+# does not fit; the ``ep`` mode keeps it. The layer below has no capacity:
+# every (token, choice) pair that lands on an expert held here is computed,
+# whatever the routing. It is what one chip of an expert-parallel layer
+# runs: it routes over ALL experts, is told ``first`` (and holds
+# ``w_up.shape[0]`` experts from there), computes only the choices that
+# landed on those, and adds nothing for the others. On one chip it runs
+# without its exchange; nothing here stands in for the other chips.
+
+
+def kth_largest(v, j: int):
+    """The ``j``-th largest of ``v`` [r, n, e] over its axis 1 -> [r, e],
+    by bisection on the value (32 halvings of [min, max], each a compare
+    and a count over ``v``: no sort), then the smallest value that reaches
+    the bound found — the order statistic itself, not a value near it."""
+    lo, hi = jnp.min(v, axis=1), jnp.max(v, axis=1)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = 0.5 * (lo + hi)
+        reached = jnp.sum(v >= mid[:, None], axis=1) >= j
+        return jnp.where(reached, mid, lo), jnp.where(reached, hi, mid)
+
+    lo, _ = lax.fori_loop(0, 32, halve, (lo, hi))
+    return jnp.min(jnp.where(v >= lo[:, None], v, jnp.inf), axis=1)
+
+
+def balancing_bias(logits, k: int, balance_tokens: int, rounds: int):
+    """The bias that deals each run of ``balance_tokens`` tokens (a
+    sequence) evenly over the experts when a token chooses its ``k``
+    largest ``logit + bias``: a few rounds of an auction over the run's
+    own logits. To begin, each expert's bias is minus its MARK, the logit
+    that exactly an even share of the run's tokens (``balance_tokens * k
+    / E``) reach. Then ``rounds`` times: every token draws its cut line
+    between its ``k``-th and ``k+1``-th largest ``logit + bias``, and
+    every expert marks itself anew against those lines, so that an even
+    share of tokens would put it above their line. Logits that spread
+    alike and apart from each other are dealt to 6% by the marks alone
+    and to 2% after two rounds; the rounds matter where training has made
+    the experts' logits move together over the tokens: with half of their
+    spread in one direction the marks leave experts at 2 to 6 times their
+    share, and a round takes about a third off the excess (PERF.md
+    section 6, PR 28). Taken on the logits, not the scores: an expert
+    pushed toward 0 or 1 keeps its spread there. A rule without memory:
+    it follows a router and a
+    residual stream that the optimizer moves by tenths a step, and a
+    sequence needs no other sequence's logits, on this chip or another.
+    No gradient passes. logits [T, E] -> bias [T, E] (a run's bias
+    repeated over it)."""
+    t, e = logits.shape
+    runs = lax.stop_gradient(logits).reshape(
+        t // balance_tokens, balance_tokens, e)
+    even = max(1, balance_tokens * k // e)
+    bias = -kth_largest(runs, even)
+    for _ in range(rounds):
+        top, _ = lax.top_k(runs + bias[:, None], k + 1)
+        line = 0.5 * (top[..., k - 1] + top[..., k])
+        bias = -kth_largest(runs - line[..., None], even)
+    return jnp.broadcast_to(bias[:, None], runs.shape).reshape(t, e)
+
+
+def route_sigmoid_topk(x, router, bias, k: int, scale: float,
+                       balance: tuple[int, int] | None = None):
+    """Sigmoid scores over all experts (float32); the ``k`` experts with
+    the largest ``score + bias`` are chosen (``bias`` is a buffer: no
+    gradient reaches it), and weighed by their *scores*, normalised over
+    the chosen and times ``scale``. With ``balance`` = (tokens a run,
+    rounds) the buffer is not read: the ``k`` largest ``logit +``
+    :func:`balancing_bias` are chosen (``T`` is a multiple of the run),
+    weighed by their scores as before. x [T, D], router [D, E], bias [E]
+    -> (expert index [T, k] int32, weight [T, k] float32)."""
+    f32 = jnp.float32
+    logits = jnp.dot(
+        x.astype(f32), router.astype(f32), precision=lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    by = (s + bias.astype(f32) if balance is None
+          else logits + balancing_bias(logits, k, *balance))
+    _, idx = lax.top_k(lax.stop_gradient(by), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    w = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w
+
+
+def dispatch_held(idx, first: int, count: int, experts: int):
+    """Which (token, choice) pairs landed on the ``count`` experts from
+    ``first``, in expert order. idx [T, k] -> (``order`` [T*k]: the pair
+    ids (token * k + choice) sorted by held expert, pairs that landed
+    elsewhere last; ``load`` [experts] int32: pairs per expert, over ALL
+    experts)."""
+    flat = idx.reshape(-1)
+    local = flat - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    load = jnp.sum(
+        flat[:, None] == jnp.arange(experts, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    return order, load
+
+
+def relu2_experts(rows, w_up, w_down, group_sizes, compute_dtype):
+    """Each expert's rows through its own ``w_down relu(w_up x)^2`` (not
+    gated): ``rows`` [M, D] sorted by expert, ``group_sizes`` [E_held]
+    rows each; rows past their sum are not computed (read them as
+    undefined). Operands in ``compute_dtype``, float32 accumulation."""
+    cd = compute_dtype
+    up = lax.ragged_dot(rows.astype(cd), w_up.astype(cd), group_sizes,
+                        preferred_element_type=jnp.float32)
+    act = jnp.square(jax.nn.relu(up))
+    return lax.ragged_dot(act.astype(cd), w_down.astype(cd), group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def held_block_rows(pairs: int, held: int, experts: int) -> int:
+    """Rows of one block of the held experts' row buffer: twice what an
+    even routing sends to ``held`` of ``experts``, in whole tiles of 512,
+    at most every pair. A block size, not a capacity: rows beyond the
+    first block are computed in further blocks (:func:`moe_ffn_held`)."""
+    even = -(-2 * pairs * held // experts)
+    return min(pairs, -(-even // 512) * 512)
+
+
+def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
+                 scale: float, compute_dtype=jnp.bfloat16,
+                 balance: tuple[int, int] | None = None,
+                 block_rows: int | None = None):
+    """The routed part of a sigmoid-scored, top-``k`` expert layer that
+    this holder of experts ``first .. first + w_up.shape[0] - 1``
+    computes. x [T, D], router [D, E] over all E experts, bias [E], w_up
+    [E_held, D, F], w_down [E_held, F, D] -> (out [T, D] float32: the sum,
+    over the choices that landed here, of weight * expert(x); load [E]
+    int32: the (token, choice) pairs that chose each expert, held or not —
+    ``load[first:first + E_held]`` landed here). ``balance`` = (tokens a
+    sequence, rounds): the choice is by each sequence's own
+    :func:`balancing_bias`.
+
+    Dropless, with no capacity: the pairs that landed here, sorted by
+    expert, are computed ``block_rows`` at a time (default
+    :func:`held_block_rows`; tests set it to reach the loop at a size
+    they can hold). Where they fit one block — the usual case — that
+    block is the whole computation; where a routing sends more, a loop
+    over as many blocks as hold every pair computes them all, each block
+    recomputed in the backward pass so that memory stays one block's (as
+    the only path the loop would recompute the usual case's products a
+    third time under a layer's remat, or keep every block's residuals).
+    Shapes are static either way, and only rows that landed here are
+    multiplied: a step's time follows the load on the held experts."""
+    t, d = x.shape
+    pairs = t * k
+    count = w_up.shape[0]
+    r = block_rows or held_block_rows(pairs, count, router.shape[1])
+    nb = -(-pairs // r)
+    with jax.named_scope(names.MOE_ROUTE):
+        idx, w = route_sigmoid_topk(x, router, bias, k, scale, balance)
+    with jax.named_scope(names.MOE_DISPATCH):
+        order, load = dispatch_held(idx, first, count, router.shape[1])
+        rows = load[first:first + count]
+        order = jnp.pad(order, (0, nb * r - pairs))
+        ends = jnp.cumsum(rows)
+        landed = ends[-1]
+        xc = x.astype(compute_dtype)
+        w_flat = w.reshape(-1)
+
+    def block(start):
+        """[T, D]: what the pairs at sorted positions start .. start+r-1
+        add."""
+        with jax.named_scope(names.MOE_DISPATCH):
+            ids = lax.dynamic_slice(order, (start,), (r,))
+            here = start + jnp.arange(r) < landed
+            tok = ids // k
+            # The products leave rows past the landed ones undefined, in
+            # the backward pass too: select, never multiply, them away.
+            taken = jnp.where(here[:, None], xc[tok], 0)
+            sizes = (jnp.clip(ends - start, 0, r)
+                     - jnp.clip(ends - rows - start, 0, r))
+        with jax.named_scope(names.MOE_EXPERTS):
+            y = relu2_experts(taken, w_up, w_down, sizes, compute_dtype)
+        with jax.named_scope(names.MOE_DISPATCH):
+            y = jnp.where(here[:, None], y, 0.0) * w_flat[ids][:, None]
+            return jnp.zeros((t, d), jnp.float32).at[tok].add(y)
+
+    def every_block():
+        body = jax.checkpoint(block)
+        return lax.fori_loop(
+            0, nb, lambda b, acc: acc + body(b * r),
+            jnp.zeros((t, d), jnp.float32))
+
+    return lax.cond(landed <= r, lambda: block(0), every_block), load

@@ -1207,7 +1207,7 @@ class LMTrainer:
 
         return step
 
-    def _make_step_body(self, toks_all, lens_all):
+    def _make_step_body(self, toks_all, lens_all, with_counters=False):
         """The ONE compiled step body per mode, shared by the scanned-epoch
         and whole-run paths (a divergence here would silently break their
         proven equality): gather the batch by index from the staged
@@ -1252,12 +1252,23 @@ class LMTrainer:
         loss_fn = self._pp_loss if self.mode == "pp" else model.loss
         if pinned:
             from distributed_tensorflow_tpu.parallel import pinned_update
+        # A model that counts what its step did (``loss_and_counters``:
+        # the rows each held expert was sent) hands the counters back
+        # beside the loss; they leave the scan with the step costs.
+        counted = (
+            getattr(model, "loss_and_counters", None)
+            if with_counters and not pinned
+            else None
+        )
 
         def body(carry, idx):
             params, opt_state, step = carry
             toks = shard(toks_all[idx])
             lens = lens_all[idx] if ragged else None
-            loss, grads = jax.value_and_grad(loss_fn)(params, toks, lens)
+            # With counters, ``loss`` is the pair (loss, counters).
+            loss, grads = jax.value_and_grad(
+                counted or loss_fn, has_aux=counted is not None
+            )(params, toks, lens)
             with jax.named_scope(names.OPTIMIZER):
                 if pinned:
                     params, opt_state = pinned_update(
@@ -1273,7 +1284,7 @@ class LMTrainer:
 
     def _build_scanned_fn(self):
         def epoch(state, toks_all, lens_all, idxs):
-            body = self._make_step_body(toks_all, lens_all)
+            body = self._make_step_body(toks_all, lens_all, with_counters=True)
             carry = (state.params, state.opt_state, state.step)
             (p, o, s), losses = jax.lax.scan(body, carry, idxs)
             return TrainState(p, o, s), losses
@@ -1645,6 +1656,10 @@ class LMTrainer:
                 )
                 # D2H fetch = execution barrier (+ the honest dispatch span).
                 costs = sp.fetch(costs)
+            if isinstance(costs, tuple):
+                # (step costs, the model's counters): one transfer.
+                costs, counters = costs
+                self._set_step_gauges(counters)
             avg_ms = (time.time() - t0) * 1000 / steps
             self._observe_step_time(avg_ms)
             self.last_cost = float(costs[-1])
@@ -1704,6 +1719,20 @@ class LMTrainer:
         self._emit_comm_stats(
             epoch=epoch, steps=steps, count_before=step_before
         )
+
+    def _set_step_gauges(self, counters: dict) -> None:
+        """Gauges from the counters a model's steps returned with their
+        costs (leaves lead with the dispatch's steps). ``moe_expert_rows``
+        [steps, expert layers, experts held]: the (token, choice) pairs
+        that landed on each held expert."""
+        rows = counters.get("moe_expert_rows")
+        if rows is not None and rows.size:
+            rows = np.asarray(rows, np.float64)
+            self.metrics.gauge("moe_rows_per_step").set(
+                float(rows.sum(axis=(1, 2)).mean())
+            )
+            self.metrics.gauge("moe_expert_rows_max").set(float(rows.max()))
+            self.metrics.gauge("moe_expert_rows_mean").set(float(rows.mean()))
 
     def _emit_comm_stats(
         self, *, epoch: int, steps: int, count_before: int

@@ -244,3 +244,24 @@ def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
         "copy", "concatenate", "slice", "dynamic-slice", "dynamic-update-slice")]
     assert not moved, moved
     assert sum(op == "scatter" for op, _ in big) == 2, big
+
+
+# -- the hybrid stack's routed experts: the kernel a trace names ---------------
+
+
+def test_ragged_products_compile_to_the_kernel_the_benchmark_reads(chip):
+    """``lax.ragged_dot`` becomes a Mosaic kernel the chip's compiler names
+    ``ragged-dot-*``, outside every scope of the program: the benchmark's
+    ``moe_experts_share_pct.nemo`` finds the routed experts' products by
+    that prefix (``benchmark/lib/scope_shares.py``). A compiler that names
+    it otherwise fails here before a traced run reads the shared expert
+    alone."""
+    from distributed_tensorflow_tpu.ops.moe import relu2_experts
+
+    held, d, f, rows = 8, 256, 384, 1024
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    text = _compile(
+        lambda x, up, down, sizes: relu2_experts(x, up, down, sizes, jnp.bfloat16),
+        sds((rows, d), jnp.float32), sds((held, d, f), jnp.float32),
+        sds((held, f, d), jnp.float32), sds((held,), jnp.int32))
+    assert text.count('op_name="ragged-dot-') >= 2, "no ragged-dot kernel by name"

@@ -132,6 +132,27 @@ def test_lm_train_then_serve_phases(smoke, meter, tmp_path, capsys):
     assert serve["sampled_reproduced"] == 2
 
 
+def test_hybrid_train_phase(smoke, meter, capsys):
+    smoke.phase_hybrid_train(
+        meter, compiled_kernels=False,
+        model_kw=dict(
+            vocab_size=64, model_dim=32, pattern="EM*", ssm_heads=4,
+            ssm_head_dim=8, ssm_state=16, ssm_groups=2, chunk_size=8,
+            num_experts=8, experts_per_token=2, expert_dim=16, shared_dim=32,
+            routed_scale=2.5, experts_held=(2, 4), num_heads=4,
+            num_kv_heads=2, head_dim=8, attention_impl="flash",
+            flash_min_len=0, remat=True, compute_dtype=jnp.float32,
+        ),
+        seq_len=32, batch=4, steps=3, loss_rtol=1e-5, grad_rtol=1e-3,
+    )
+    (line,) = _lines(capsys)
+    assert line["phase"] == "hybrid_train" and line["passed"]
+    assert line["last_loss"] < line["first_loss"]
+    assert line["loss_gap"] <= 1e-5 and line["gradient_rel_err"] <= 1e-3
+    assert line["steps"] == 6
+    assert 0 < line["gauges"]["moe_rows_per_step"] <= 4 * 32 * 2
+
+
 def test_decode_kernels_phase(smoke, meter, capsys):
     smoke.phase_decode_kernels(
         meter, compiled_kernels=False,

@@ -213,6 +213,58 @@ def test_train_step_program_carries_every_scope(trainer):
     assert names.OPTIMIZER not in got["backward"] | got["recompute"]
 
 
+HYBRID_SCOPES = set(names.HYBRID_SCOPES)
+
+
+def test_hybrid_train_step_program_carries_every_scope():
+    """The stack of models/hybrid.py through the same trainer: the
+    state-space and expert layers' sections in the forward pass, the
+    backward pass and remat's replay of each layer."""
+    from distributed_tensorflow_tpu.config import TrainConfig
+    from distributed_tensorflow_tpu.data.tokens import copy_corpus
+    from distributed_tensorflow_tpu.models.hybrid import HybridLM
+    from distributed_tensorflow_tpu.train.lm_trainer import LMTrainer
+
+    model = HybridLM(
+        61, 32, "EM*", ssm_heads=4, ssm_head_dim=8, ssm_state=16,
+        ssm_groups=2, chunk_size=32, num_experts=8, experts_per_token=2,
+        expert_dim=16, shared_dim=32, routed_scale=2.5, experts_held=(2, 4),
+        num_heads=4, num_kv_heads=2, head_dim=16, compute_dtype=jnp.float32,
+        attention_impl="flash", flash_min_len=128, remat=True,
+    )
+    trainer = LMTrainer(
+        model,
+        copy_corpus(num=12, half_len=64, vocab=61, n_val=4, n_test=4, seed=0),
+        TrainConfig(epochs=1, batch_size=2, optimizer="adam", scan_epoch=True,
+                    logs_path=""),
+        print_fn=lambda *a: None,
+    )
+    train = trainer.datasets.train
+    steps = train.num_examples // trainer.config.batch_size
+    fn = trainer._build_scanned_fn()
+    assert fn.__name__ == names.PROGRAM_EPOCH
+    compiled = fn.lower(
+        trainer.state,
+        trainer._stage("train_tokens", train.tokens),
+        trainer._train_lens(),
+        trainer._replicated(
+            trainer._epoch_indices(steps, trainer.config.batch_size)
+        ),
+    ).compile()
+    got = _scopes_by_phase(compiled)
+    attention = {names.ATTN_QKV, names.ATTN_CORE, names.ATTN_OUT}
+    ends = {names.EMBED, names.LM_HEAD, names.LOSS}
+    assert got["forward"] >= HYBRID_SCOPES | attention | ends | {
+        names.OPTIMIZER}, (HYBRID_SCOPES | attention | ends) - got["forward"]
+    assert got["backward"] >= HYBRID_SCOPES | attention | ends, (
+        (HYBRID_SCOPES | attention | ends) - got["backward"])
+    # remat replays each layer up to what its backward reads: not the
+    # attention's out-projection, whose output only joins the residual
+    replayed = HYBRID_SCOPES | {names.ATTN_QKV, names.ATTN_CORE}
+    assert got["recompute"] >= replayed, replayed - got["recompute"]
+    assert names.MLP not in got["forward"]  # no attention + FFN pair here
+
+
 def _server(paged: bool, **kw):
     m = _tiny_model()
     kw.setdefault("slots", 2)
@@ -282,6 +334,7 @@ def test_serving_program_carries_every_scope(case):
 
 def test_every_scope_and_program_is_held_by_some_case():
     held = MODEL_SCOPES | {names.LOSS, names.OPTIMIZER} | SERVING_SCOPES
+    held |= HYBRID_SCOPES  # test_hybrid_train_step_program_carries_every_scope
     for _, _, extra in PROGRAM_CASES.values():
         held |= extra
     assert held == set(names.SCOPES)
