@@ -1738,21 +1738,18 @@ class GPTLM:
         )  # [S, 1, d]
         return self._logits(params, h_last)[:, 0], new_cache
 
-    def _decode_block_step(self, blk, h, lengths, cache_update):
+    def _decode_block_step(self, blk, h, lengths, attend):
         """Shared per-slot single-token block math (layernorm / QKV /
-        rope / GQA attention / FFN) for BOTH single-token decode cache
-        layouts. ``cache_update(k, v)`` owns everything layout-specific:
-        it commits the fresh K/V row ([S, 1, Hkv, Dh]) to its cache,
-        returns the per-slot contiguous K/V to attend over
-        ([S, C, Hkv, Dh] each), the validity mask [S, C], and the
-        updated cache state threaded back to the caller. Keeping the
-        math in ONE body is what keeps the slab and paged paths in
-        lockstep (their bitwise equality is pinned by test_gpt.py /
-        test_serve.py parity tests)."""
-        from distributed_tensorflow_tpu.ops.ring_attention import (
-            group_query_heads,
-        )
-
+        rope / attention / FFN) for BOTH single-token decode cache
+        layouts. ``attend(q, k, v)`` owns everything layout-specific:
+        given the query ([S, 1, Hq, Dh]) and the fresh K/V row
+        ([S, 1, Hkv, Dh]) it returns the attention output
+        ([S, Hkv, G, Dh]) and the cache state threaded back to the
+        caller — the slab commits the row and attends the slot's whole
+        static-length row (:meth:`_decode_attend`), the paged pool is
+        read through its live-block list. Keeping the rest in ONE body
+        is what keeps the slab and paged paths in lockstep (their token
+        streams are pinned equal by test_gpt.py / test_serve.py)."""
         s = h.shape[0]
         with jax.named_scope(names.ATTN_QKV):
             hn = _layernorm(h, blk.ln1_scale, blk.ln1_bias)
@@ -1766,12 +1763,7 @@ class GPTLM:
                 pos = lengths[:, None]  # [S, 1] — per-row absolute position
                 q = _rope(q, pos)
                 k = _rope(k, pos)
-        ck, cv, valid, state = cache_update(k, v)
-        attn = self._decode_attend(
-            "shgd,skhd->shgk", "shgk,skhd->shgd",
-            group_query_heads(q[:, 0], self.num_kv_heads), ck, cv,
-            valid[:, None, None, :],
-        )
+        attn, state = attend(q, k, v)
         return self._decode_block_tail(blk, h, attn), state
 
     def _decode_block_slots(
@@ -1788,9 +1780,13 @@ class GPTLM:
         caches (``qd`` + ks0/vs0 scale rows) quantize the fresh row on
         write and attend the dequantized view — same math, fewer bytes
         resident."""
+        from distributed_tensorflow_tpu.ops.ring_attention import (
+            group_query_heads,
+        )
+
         c = self.cache_len
 
-        def cache_update(k, v):
+        def attend(q, k, v):
             slot = lengths % c if self.window is not None else lengths
             if qd is None:
                 kq, vq = k.astype(ck0.dtype)[:, 0], v.astype(cv0.dtype)[:, 0]
@@ -1823,10 +1819,14 @@ class GPTLM:
                 valid = slot_pos >= 0  # [S, c]
             else:
                 valid = idx <= lengths[:, None]  # [S, c]
-            return ck_att, cv_att, valid, state
+            attn = self._decode_attend(
+                "shgd,skhd->shgk", "shgk,skhd->shgd",
+                group_query_heads(q[:, 0], self.num_kv_heads),
+                ck_att, cv_att, valid[:, None, None, :],
+            )
+            return attn, state
 
-        h, state = self._decode_block_step(blk, h, lengths, cache_update)
-        return h, state
+        return self._decode_block_step(blk, h, lengths, attend)
 
     def decode_slots(
         self,
@@ -2171,63 +2171,45 @@ class GPTLM:
             k=nk, v=nv, k_scale=nks, v_scale=nvs
         )
 
-    def _decode_block_paged(self, blk, h, cache, layer, act, qd=None):
-        """Per-slot single-token block step against the BLOCK POOL —
-        :meth:`_decode_block_slots` with the slab row replaced by a read
-        through the block tables. The layer-stacked pool is READ-ONLY
-        here: layer ``layer``'s contiguous per-slot view is gathered
-        through ``(layer, block_tables)`` straight out of the stack, and
-        the fresh storage-dtype K/V row takes its place in that small
-        view at position ``lengths[s]`` (active rows only — an inactive
-        row sees what the pool holds there, as after a write dropped at
-        the sentinel), so the view is bit for bit what a
-        scatter-then-gather of the pool gives. Attention masks by the
-        same ``idx <= lengths`` validity; windowed models band by mask
-        (``idx > lengths − W``) — absolute addressing, no rolling
-        arithmetic. Quantized pools (``qd``) quantize the fresh row
-        before it is placed and dequantize the view before the softmax;
-        the scale side pools ride the same gather and the same
-        placement. Returns ``(h, (kq, vq, ksc, vsc))``: the rows
+    def _decode_block_paged(self, blk, h, cache, layer, live, qd=None):
+        """Per-slot single-token block step against the BLOCK POOL. The
+        layer-stacked pool is READ-ONLY here: attention walks the
+        live-block list ``live`` through layer ``layer`` of the stack
+        (``ops/paged_attention.paged_decode_attention``: a tile of
+        resident blocks a turn, folded into a per-slot running softmax)
+        and meets the row this step writes as one more key, so no view
+        of a slot's whole table is ever built. A pool position is valid
+        below ``lengths[s]``; windowed models band by mask
+        (``> lengths − W``) — absolute addressing, no rolling arithmetic.
+        Quantized pools (``qd``) quantize the fresh row and attend it
+        dequantized again, as it will be read back; the tiles are
+        dequantized with the scale side pools read through the same
+        list. Returns ``(h, (kq, vq, ksc, vsc))``: the rows
         (``[S, Hkv, Dh]``, scales ``[S, Hkv]`` or None) that
         :meth:`decode_paged` commits for all layers at once."""
         from distributed_tensorflow_tpu.ops import paged_attention as paged
 
-        tables, lengths = cache.block_tables, cache.lengths
-
-        def view(pool, row):
-            got = paged.gather_block_view(pool, tables, layer)
-            with jax.named_scope(names.KV_GATHER):
-                # a pool carried with its rows flat: back to the row's axes
-                got = got.reshape(got.shape[:2] + row.shape[1:])
-                here = (jnp.arange(got.shape[1])[None, :]
-                        == lengths[:, None]) & act[:, None]  # [S, C]
-                here = here.reshape(here.shape + (1,) * (got.ndim - 2))
-                return jnp.where(here, row[:, None], got)
-
-        def cache_update(k, v):
+        def attend(q, k, v):
             if qd is None:
                 kq = k.astype(cache.k.dtype)[:, 0]
                 vq = v.astype(cache.v.dtype)[:, 0]
                 ksc = vsc = None
+                k_row, v_row = kq, vq
             else:
                 kq, ksc = quantize_kv(k[:, 0], qd)  # [S,Hkv,Dh] + [S,Hkv]
                 vq, vsc = quantize_kv(v[:, 0], qd)
-            ck, cv = view(cache.k, kq), view(cache.v, vq)  # [S, C, Hkv, Dh]
-            if qd is not None:
-                # compute_dtype view, not f32 (see _decode_block_slots).
-                ck = dequantize_kv(
-                    ck, view(cache.k_scale, ksc), self.compute_dtype
-                )
-                cv = dequantize_kv(
-                    cv, view(cache.v_scale, vsc), self.compute_dtype
-                )
-            idx = jnp.arange(ck.shape[1])[None, :]  # [1, C] absolute
-            valid = idx <= lengths[:, None]  # [S, C]
-            if self.window is not None:
-                valid &= idx > lengths[:, None] - self.window
-            return ck, cv, valid, (kq, vq, ksc, vsc)
+                # compute_dtype rows, not f32 (see _decode_block_slots).
+                k_row = dequantize_kv(kq, ksc, self.compute_dtype)
+                v_row = dequantize_kv(vq, vsc, self.compute_dtype)
+            attn = paged.paged_decode_attention(
+                q[:, 0], k_row, v_row, cache.k, cache.v, layer, live,
+                cache.lengths, window=self.window,
+                k_scale=cache.k_scale if qd else None,
+                v_scale=cache.v_scale if qd else None,
+            )
+            return attn, (kq, vq, ksc, vsc)
 
-        return self._decode_block_step(blk, h, lengths, cache_update)
+        return self._decode_block_step(blk, h, cache.lengths, attend)
 
     def decode_paged(
         self,
@@ -2237,6 +2219,7 @@ class GPTLM:
         active: jax.Array | None = None,
         *,
         engine: str | None = None,
+        live=None,
     ):
         """Append one token per slot through the block tables — the
         paged counterpart of :meth:`decode_slots` (same masking
@@ -2245,10 +2228,15 @@ class GPTLM:
         ``lengths[s]`` (the engine reserves ``prompt + max_new`` blocks
         at admission, so generation never outgrows the table).
 
-        The XLA engine never moves the pool: the layer loop (UNROLLED,
-        as in :meth:`decode_step`) reads each layer through
-        ``(layer, block_tables)`` out of the one stacked
-        ``[layers, blocks, block_size, Hkv, Dh]`` array, and ONE update
+        The XLA engine never moves the pool and reads of it only what is
+        resident: the layer loop (UNROLLED, as in :meth:`decode_step`)
+        walks the live-block list ``live``
+        (``ops/paged_attention.live_block_list``) through each layer of
+        the one stacked ``[layers, blocks, block_size, Hkv, Dh]`` array.
+        A caller that steps many times makes the list once, for that
+        many steps, and hands it in (the server's chunk scan); a call
+        that brings none gets one made here for its single step. ONE
+        update
         for K and one for V (and one per scale pool) commits every
         layer's fresh row after it
         (``ops/paged_attention.commit_token_rows``: the places and the
@@ -2283,10 +2271,15 @@ class GPTLM:
             return self._decode_paged_pallas(params, h, cache, act, qd)
         from distributed_tensorflow_tpu.ops import paged_attention as paged
 
+        if live is None:
+            live = paged.live_block_list(
+                cache.block_tables, cache.lengths, act, 1,
+                cache.k.shape[1], cache.k.shape[2],
+            )
         fresh = []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
-            h, rows = self._decode_block_paged(blk, h, cache, i, act, qd)
+            h, rows = self._decode_block_paged(blk, h, cache, i, live, qd)
             fresh.append(rows)
 
         def commit(pool, rows):
@@ -2308,8 +2301,7 @@ class GPTLM:
         """Fused-kernel half of :meth:`decode_paged`: one
         ``ops/pallas_decode.decode_block_paged`` launch per layer (the
         block tables ride as scalar-prefetch args — the pool is read
-        block-by-block in the grid, no contiguous ``gather_block_view``
-        copy), then the fresh row committed through
+        block-by-block in the grid), then the fresh row committed through
         :meth:`_commit_paged_rows` — the table arithmetic of the XLA
         engine's all-layer commit, so both engines write identical
         pools by construction."""

@@ -717,9 +717,10 @@ def decode_block_paged(
     ``k_scale``/``v_scale`` [NB, bs, Hkv] f32 or None, ``tables``
     [S, max_blocks] int32. The block tables ride as scalar-prefetch
     arguments and the pool gather happens in the grid index maps — the
-    kernel DMAs exactly the slot's blocks, no contiguous view is ever
-    materialized (the XLA engine's ``gather_block_view`` copy). Validity
-    is the absolute-position rule of ``models/gpt._decode_block_paged``
+    kernel DMAs the slot's table block by block, no contiguous view is
+    ever materialized (nor does the XLA engine build one: it walks the
+    resident blocks, ``ops/paged_attention.paged_decode_attention``).
+    Validity is that step's absolute-position rule
     (``idx < length``, windowed ``idx > length − W``); unused table
     entries gather garbage blocks the mask keeps out of the softmax.
     Return contract matches :func:`decode_block_slab` (the caller

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 import os
 import time
@@ -58,6 +59,7 @@ from distributed_tensorflow_tpu.observability import names, tracing
 from distributed_tensorflow_tpu.observability.exporter import MetricsExporter
 from distributed_tensorflow_tpu.observability.metrics import MetricsRegistry
 from distributed_tensorflow_tpu.observability.spans import SpanRecorder
+from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu import serve_pool
 from distributed_tensorflow_tpu.serve_pool import (
     BlockAllocator,
@@ -955,7 +957,15 @@ class TextServer:
         plus the [chunk, S] token block and its validity mask — the only
         per-chunk host traffic. One body for both cache layouts: the
         paged step differs only in how the cache row is addressed
-        (:meth:`GPTLM.decode_paged` vs :meth:`GPTLM.decode_slots`)."""
+        (:meth:`GPTLM.decode_paged` vs :meth:`GPTLM.decode_slots`).
+
+        The XLA engine's paged step reads the pool through a list of the
+        blocks that are resident, made here once for the whole chunk
+        (every block a slot active now can reach by the chunk's end);
+        the token block then carries two more rows, the list's real
+        entries and the entries a step walks (that count rounded up to
+        whole tiles), each repeated over the slots: they reach the host
+        in the fetch that brings the tokens (:meth:`_account_delivery`)."""
         max_len = self.model.max_len
         decode = (
             self.model.decode_paged if self.paged else self.model.decode_slots
@@ -971,9 +981,19 @@ class TextServer:
             self.model._resolve_decode_engine(self.decode_engine, params)
             == "xla"
         )
+        walked = None
         if rows_flat:
             flat = pool_shape[:3] + (-1,)
             st = st._replace(k=st.k.reshape(flat), v=st.v.reshape(flat))
+            live = paged_attention.live_block_list(
+                st.block_tables, st.lengths,
+                ~st.finished & (st.lengths < max_len), self.chunk,
+                pool_shape[1], pool_shape[2],
+            )
+            decode = functools.partial(decode, live=live)
+            walked = jnp.stack(
+                [live.live, paged_attention.blocks_walked(live, pool_shape[2])]
+            )
 
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
@@ -1012,6 +1032,9 @@ class TextServer:
         if rows_flat:
             st = st._replace(
                 k=st.k.reshape(pool_shape), v=st.v.reshape(pool_shape)
+            )
+            toks = jnp.concatenate(
+                [toks, jnp.broadcast_to(walked[:, None], (2, self.slots))]
             )
         return st, toks, valid
 
@@ -1974,7 +1997,9 @@ class TextServer:
                 ),
             )
 
-    def _account_delivery(self, sp, valid, occupied: int, steps: int) -> None:
+    def _account_delivery(
+        self, sp, valid, occupied: int, steps: int, walked=()
+    ) -> None:
         """What a decode dispatch delivered, written into its span after
         the fetch and before the span closes: ``emitted``, the tokens it
         delivered to each resident request (one count per entry of the
@@ -1982,7 +2007,16 @@ class TextServer:
         it ran (``active`` × the steps of the program). A slot that
         finishes inside a chunk rides masked to the chunk's end: 1 − Σ
         emitted ÷ Σ slot_steps is that tail (under speculation, the
-        rejected drafts' share)."""
+        rejected drafts' share). ``walked``, where the chunk read the
+        pool through a live-block list, is the two counts the program
+        sent along with its tokens: ``kv_blocks_live``, the list's real
+        entries, and ``kv_blocks_read``, the entries one step of one
+        layer walks (the first rounded up to whole tiles); the second ÷
+        (``slots`` × a table's blocks) is the share of a whole-table
+        read that is left."""
+        if len(walked):
+            sp.args["kv_blocks_live"] = int(walked[0])
+            sp.args["kv_blocks_read"] = int(walked[1])
         sp.args["emitted"] = [
             int(valid[:, slot].sum())
             for slot, req in enumerate(self._slot_req) if req is not None
@@ -2108,7 +2142,11 @@ class TextServer:
                     # D2H fetch = execution barrier (closes the span).
                     toks = sp.fetch(toks)
                     valid = np.asarray(valid)
-                    self._account_delivery(sp, valid, occupied, self.chunk)
+                    # rows past the chunk: the live-block list's counts
+                    toks, walked = toks[: self.chunk], toks[self.chunk:, 0]
+                    self._account_delivery(
+                        sp, valid, occupied, self.chunk, walked
+                    )
             fin = np.asarray(self._state.finished)
             emitted = 0
             for slot, req in enumerate(self._slot_req):

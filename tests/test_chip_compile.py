@@ -210,7 +210,11 @@ def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
     copy of the pool and no second pool, only the two scatters that
     update it. XLA:TPU lays an array out by its two minor axes, and a
     commit whose window spans the layer axis, or a carry that ends in
-    [20, 64], each brought two whole-pool copies a step back (PR 27)."""
+    [20, 64], each brought two whole-pool copies a step back (PR 27).
+    Nor does it build any array the size of every slot's whole table
+    (PR 29: a layer reads the pool a tile of the live-block list at a
+    time, in a loop whose trip count the device computes), and the chunk
+    is still one program whatever the residency."""
     import re
 
     import numpy as np
@@ -223,7 +227,7 @@ def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
     )
     srv = TextServer(
         model, None, slots=4, chunk=4, paged=True, block_size=16,
-        kv_blocks=64, kv_dtype=kv, decode_engine="xla",
+        kv_blocks=48, kv_dtype=kv, decode_engine="xla",
     )
     on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
         x.shape, x.dtype, sharding=chip)
@@ -232,18 +236,27 @@ def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
         jax.tree.map(on_chip, srv._state),
     ).compile().as_text()
     assert "input_output_alias" in text
+    assert text.count("HloModule ") == 1 and text.count("\nENTRY ") == 1
+    assert text.startswith("HloModule jit__chunk_graph")
     loop = text[: text.index("\nENTRY ")]  # every computation but the entry
-    size = srv._state.k.size  # no other array of the program has it
-    big = [
-        (op, name) for name, dims, op in re.findall(
+    made = [
+        (op, name, np.prod([int(d) for d in dims.split(",") if d]))
+        for name, dims, op in re.findall(
             r"%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", loop)
-        if np.prod([int(d) for d in dims.split(",") if d]) == size
-        and op not in ("parameter", "get-tuple-element", "bitcast", "tuple")
+        if op not in ("parameter", "get-tuple-element", "bitcast", "tuple")
     ]
+    size = srv._state.k.size  # no other array of the program has it
+    big = [(op, name) for op, name, n in made if n == size]
     moved = [b for b in big if b[0] in (
         "copy", "concatenate", "slice", "dynamic-slice", "dynamic-update-slice")]
     assert not moved, moved
     assert sum(op == "scatter" for op, _ in big) == 2, big
+    # every slot's whole table as one view: slots × max_blocks × block_size
+    # rows of Hkv·Dh (a tile of the list is half of it here)
+    view = srv.slots * model.max_len * model.model_dim
+    assert view != size and not [m for m in made if m[2] == view]
+    # one loop per layer, inside the chunk's own
+    assert len(re.findall(r" while\(", loop)) == model.num_layers
 
 
 # -- the hybrid stack's routed experts: the kernel a trace names ---------------
