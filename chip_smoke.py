@@ -12,8 +12,8 @@ points:
   layers, 16 heads, L=2048, vocabulary 8,192, batch 8, flash attention,
   remat — for a few steps, saving a checkpoint through its Supervisor;
 - the text server (``serve.TextServer.from_checkpoint``) on that
-  checkpoint, paged cache, continuous batching, and the Pallas decode
-  kernels against the XLA engine at d=512;
+  checkpoint, paged cache, continuous batching, its greedy streams
+  against ``GPTLM.greedy_decode`` token for token;
 - the hybrid stack (``models.hybrid.HybridLM``: a state-space, an expert
   and an attention layer) through the same ``LMTrainer``: its first
   step's loss against the plain reference
@@ -82,12 +82,6 @@ FULL_LM = dict(
     num_layers=4, attention_impl="flash", remat=True,
 )
 FULL_LM_BATCH = 8
-# The widest config the decode kernels' per-layer weight cap admits
-# (tools/lm_bench.py "gpt-m": d=512, 8 layers, L=1024). At its own 8
-# heads head_dim is 64; at 4 heads it is 128, a geometry the chip's
-# compiler accepts the megakernel at (GPTLM._megakernel_compiles).
-KERNEL_LM = dict(vocab_size=8192, max_len=1024, model_dim=512, num_layers=8)
-
 # One layer of each kind of the hybrid stack, heads and states at the
 # sizes the published models use (state-space heads of 64 with state 128,
 # attention heads of 128, chunk 128), the rest small: seconds on the chip.
@@ -590,31 +584,26 @@ def _requests(vocab: int, seed: int, greedy_lens, sampled_lens, max_new: int):
     return [prompts[i] for i in order], [configs[i] for i in order]
 
 
-def _logit_row(model: GPTLM, params, prefix: np.ndarray, engine: str | None):
-    """Next-token logits after ``prefix``: from the prompt pass alone
-    (``engine=None``), or from one decode step under ``engine`` on the
-    cache of ``prefix[:-1]``."""
+def _logit_rows(model: GPTLM, params, prefix: np.ndarray):
+    """Next-token logits after ``prefix`` two ways: from the prompt pass
+    alone, and from one decode step on the cache of ``prefix[:-1]``."""
     toks = jnp.asarray(prefix[None])
     prefill = jax.jit(model.prefill)
-    if engine is None:
-        return np.asarray(prefill(params, toks)[0][0], np.float32)
+    whole = prefill(params, toks)[0][0]
     _, cache = prefill(params, toks[:, :-1])
-    step = jax.jit(
-        lambda p, t, c: model.decode_step(p, t, c, engine=engine)[0]
-    )
-    return np.asarray(step(params, toks[:, -1], cache)[0], np.float32)
+    stepped = jax.jit(model.decode_step)(params, toks[:, -1], cache)[0][0]
+    return np.asarray(whole, np.float32), np.asarray(stepped, np.float32)
 
 
 def _top(row: np.ndarray, k: int = 8) -> list:
     return [(int(i), float(row[i])) for i in np.argsort(row)[::-1][:k]]
 
 
-def compare_streams(
-    model: GPTLM, params, prompt, got, want, engines: tuple
-) -> dict | None:
+def compare_streams(model: GPTLM, params, prompt, got, want) -> dict | None:
     """None when the two generated streams are equal. Otherwise the first
-    diverging position with both tokens and the two logit rows'
-    agreement; raises unless that position is a near-tie (NEAR_TIE)."""
+    diverging position with both tokens and the agreement of that
+    position's two logit rows (the prompt pass's and a decode step's);
+    raises unless that position is a near-tie (NEAR_TIE)."""
     got, want = np.asarray(got), np.asarray(want)
     check(got.shape == want.shape, f"stream shapes {got.shape} {want.shape}")
     diff = np.nonzero(got != want)[0]
@@ -622,7 +611,7 @@ def compare_streams(
         return None
     at = int(diff[0])
     prefix = np.concatenate([prompt, got[:at]])
-    row_a, row_b = (_logit_row(model, params, prefix, e) for e in engines)
+    row_a, row_b = _logit_rows(model, params, prefix)
     a, b = int(got[at]), int(want[at])
     scale = max(1.0, float(np.max(np.abs(row_a))))
     rows_apart = float(np.max(np.abs(row_a - row_b))) / scale
@@ -631,8 +620,8 @@ def compare_streams(
         position=at, tokens=[a, b], rows_apart=rows_apart, token_gap=float(gap),
     )
     print(
-        f"divergence {report}\n  top of row[{engines[0]}]: {_top(row_a)}\n"
-        f"  top of row[{engines[1]}]: {_top(row_b)}",
+        f"divergence {report}\n  top of row[prefill]: {_top(row_a)}\n"
+        f"  top of row[decode_step]: {_top(row_b)}",
         file=sys.stderr,
     )
     check(
@@ -670,12 +659,6 @@ def phase_serve(
                 bool(jnp.array_equal(a, b)),
                 "restored parameters differ from the trainer's",
             )
-        engine = model._resolve_decode_engine(None, server.params)
-        why = model._decode_unsupported_reason() or (
-            "supported" if model._megakernel_compiles() else
-            f"head_dim {model.head_dim} x {model.num_kv_heads} KV heads is "
-            "a geometry the chip's compiler refuses the megakernel at"
-        )
         prompts, configs = _requests(
             model.vocab_size, seed, greedy_lens, sampled_lens, max_new
         )
@@ -691,8 +674,7 @@ def phase_serve(
         near_ties = [
             r for i, want in refs.items()
             if (r := compare_streams(
-                model, server.params, prompts[i], outs[i], want,
-                (None, "xla"),
+                model, server.params, prompts[i], outs[i], want
             ))
         ]
         # Seeded sampling is reproducible: a second server restored from
@@ -712,7 +694,6 @@ def phase_serve(
             )
         out.update(
             checkpoint_step=server.checkpoint_step,
-            engine=engine, engine_reason=why,
             requests=len(prompts), slots=slots, chunk=chunk,
             greedy_equal_to_greedy_decode=len(refs) - len(near_ties),
             greedy_near_ties=near_ties,
@@ -721,122 +702,6 @@ def phase_serve(
                 compile_s=round(meter.compile_s - c0, 2),
                 cache_hits=meter.hits - h0,
             ),
-        )
-
-
-# -- decode_kernels --------------------------------------------------------
-
-
-def _kernel_model(num_heads: int, **model_kw) -> GPTLM:
-    return GPTLM(num_heads=num_heads, decode_engine="xla", **model_kw)
-
-
-def _serve_once(model, params, engine, spec_draft, prompts, configs, **kw):
-    """Serve the requests under ``engine``; returns the streams and
-    whether every decode program of that server carries a kernel."""
-    server = TextServer(
-        model, params, paged=True, decode_engine=engine,
-        spec_draft=spec_draft, **kw,
-    )
-    outs = server.generate(prompts, configs)
-    programs = [server._chunk_jit.lower(server.params, server._state)]
-    if spec_draft:
-        s = server.slots
-        programs.append(server._verify_jit.lower(
-            server.params, server._state,
-            jnp.zeros((s, spec_draft + 1), jnp.int32),
-            jnp.zeros((s,), jnp.int32),
-        ))
-    return outs, all(has_compiled_kernel(p.as_text()) for p in programs)
-
-
-def _refusal(model: GPTLM, params, engine: str) -> str | None:
-    """The compiler's own message when ``engine`` cannot compile this
-    model's paged decode step, None when it compiles."""
-    cache = model.empty_paged_cache(8, 64, 16)
-    tok = jnp.zeros((8,), jnp.int32)
-    try:
-        jax.jit(
-            lambda p, t, c: model.decode_paged(p, t, c, engine=engine)
-        ).lower(params, tok, cache).compile()
-    except Exception as exc:  # noqa: BLE001 — recorded verbatim, not handled
-        return str(exc).strip().splitlines()[0]
-    return None
-
-
-def phase_decode_kernels(
-    meter: Meter, *, compiled_kernels: bool, model_kw: dict = KERNEL_LM,
-    wide_heads: int = 4, narrow_heads: int = 8,
-    wide_cases=(("pallas", 0), ("pallas-layer", 0), ("pallas", 3)),
-    narrow_cases=(("pallas-layer", 0),), seed: int = 0,
-    slots: int = 8, chunk: int = 32, block_size: int = 16,
-    buckets=(32, 128), greedy_lens=(12, 12, 40, 40, 90),
-    sampled_lens=(7, 25, 60, 100, 33), max_new: int = 40,
-):
-    """The Pallas decode tiers against the XLA engine through the same
-    server, as (engine, spec_draft) cases. ``wide_heads`` gives head_dim
-    128, where the megakernel, the fused verify (``spec_draft > 0``) and
-    the per-layer kernel all compile; ``narrow_heads`` gives head_dim 64,
-    where only the per-layer kernel does and the megakernel tier is
-    listed ``not_compiled`` with the compiler's message."""
-    with meter.phase("decode_kernels") as out:
-        kw = dict(slots=slots, chunk=chunk, block_size=block_size,
-                  buckets=buckets)
-        prompts, configs = _requests(
-            model_kw["vocab_size"], seed, greedy_lens, sampled_lens, max_new
-        )
-        passed, not_compiled = [], []
-        for heads, cases in (
-            (wide_heads, wide_cases), (narrow_heads, narrow_cases)
-        ):
-            model = _kernel_model(heads, **model_kw)
-            params = model.init(seed=seed + 1)
-            base = {}
-            for engine, spec in cases:
-                if spec not in base:
-                    base[spec], _ = _serve_once(
-                        model, params, "xla", spec, prompts, configs, **kw
-                    )
-                outs, kernel = _serve_once(
-                    model, params, engine, spec, prompts, configs, **kw
-                )
-                name = (
-                    f"{engine}@head_dim={model.head_dim}"
-                    + (f"+spec_draft={spec}" if spec else "")
-                )
-                check(
-                    kernel == compiled_kernels,
-                    f"{name}: kernel in the decode program: {kernel}, "
-                    f"expected {compiled_kernels}",
-                )
-                ties = [
-                    r for p, got, want in zip(prompts, outs, base[spec])
-                    if (r := compare_streams(
-                        model, params, p, got, want, (engine, "xla")
-                    ))
-                ]
-                passed.append(dict(
-                    engine=name, kernel_compiled=kernel,
-                    streams_equal=len(prompts) - len(ties), near_ties=ties,
-                ))
-            if compiled_kernels and not model._megakernel_compiles():
-                refused = _refusal(model, params, "pallas")
-                check(
-                    refused is not None,
-                    f"pallas@head_dim={model.head_dim} compiles now: let "
-                    "GPTLM._resolve_decode_engine's auto rule admit it",
-                )
-                not_compiled.append(dict(
-                    engine=f"pallas@head_dim={model.head_dim}",
-                    kernels=["decode_token_slab", "decode_token_paged",
-                             "verify_tokens_paged"],
-                    refusal=refused,
-                ))
-        out.update(
-            model={k: model_kw[k] for k in ("model_dim", "num_layers",
-                                            "max_len")},
-            requests=len(prompts), passed_engines=passed,
-            not_compiled=not_compiled,
         )
 
 
@@ -1002,8 +867,6 @@ def run(chips: int, seed: int) -> dict:
                 )
                 phase_serve(meter, model, optimizer, params, ckpt, seed=seed)
             del params
-            gc.collect()
-            phase_decode_kernels(meter, compiled_kernels=True, seed=seed)
             gc.collect()
             phase_hybrid_train(meter, compiled_kernels=True, seed=seed)
     finally:

@@ -35,7 +35,6 @@ from jax import lax
 
 from distributed_tensorflow_tpu.models.base import layernorm as _layernorm
 from distributed_tensorflow_tpu.observability import names
-from distributed_tensorflow_tpu.ops import pallas_mode
 from distributed_tensorflow_tpu.ops.collectives import to_varying
 from distributed_tensorflow_tpu.ops.quantized import (
     QuantizedLinear,
@@ -45,24 +44,6 @@ from distributed_tensorflow_tpu.ops.quantized import (
     wo_dot,
 )
 from distributed_tensorflow_tpu.ops.ring_attention import dense_attention
-
-
-# Decode-path implementations (rounds 18+20): see GPTLM.__init__'s
-# decode_engine comment and ops/pallas_decode.py.
-DECODE_ENGINES = ("auto", "pallas", "pallas-layer", "xla")
-
-# Per-LAYER VMEM budget for the decode kernels' weights (~10·d² +
-# 2·d·Hkv·Dh elements at compute dtype). Under the round-20 megakernel
-# weights are STREAMED layer by layer, so this caps the one layer
-# resident at a time — the same per-layer arithmetic also bounds the
-# "pallas-layer" kernel, whose single launch holds exactly one block.
-# 8 MiB keeps serving widths (d ≤ ~512 bf16) fused and refuses widths
-# whose FFN pair alone would blow the ~16 MiB VMEM — "auto" silently
-# falls back to XLA there, an explicit pallas variant raises (the
-# message states this cap AND the config's actual per-layer bytes).
-# PROVISIONAL until the chip session measures where the fused win stops
-# (the _FUSED_DQ_CAP_BYTES convention, ops/pallas_attention.py).
-_DECODE_VMEM_WEIGHT_CAP = 8 << 20
 
 
 def _rope(x, positions, base: float = 10000.0):
@@ -231,7 +212,6 @@ class GPTLM:
         remat: bool | str = False,
         flash_min_len: int | None = None,
         matmul_dtype: str | None = None,
-        decode_engine: str = "auto",
     ):
         assert model_dim % num_heads == 0
         if attention_impl not in ("xla", "flash"):
@@ -366,49 +346,6 @@ class GPTLM:
                     f"of {MATMUL_DTYPES}"
                 )
         self.matmul_dtype = matmul_dtype
-        # Rounds 18-19: which implementation serves the single-token
-        # decode paths (decode_step / decode_slots / decode_paged) and,
-        # with spec_draft, the verify extend (verify_paged).
-        #   "xla"    — the unrolled per-op path (rounds 5-15, bitwise
-        #              unchanged; the default everywhere off-TPU).
-        #   "pallas" — the round-20 megakernel tier
-        #              (ops/pallas_decode.py decode_token_* /
-        #              verify_tokens_paged): ONE Pallas launch per
-        #              token across ALL layers, per-layer weights
-        #              streamed through index maps, the KV commit done
-        #              in-kernel via aliased cache operands, and the
-        #              speculation verify fused for paged decode.
-        #   "pallas-layer" — the round-18 per-layer kernel: one launch
-        #              per block per token, weights VMEM-resident,
-        #              commit via the external XLA scatter. The escape
-        #              hatch + parity oracle for "pallas" (the
-        #              round-13 fused-vs-split pattern); verify stays
-        #              on XLA.
-        #   Both pallas variants are refused LOUDLY at construction/
-        #   call time for unsupported configs (MoE FFNs, quantized
-        #   projection weights, layers too wide for VMEM) instead of
-        #   silently degrading.
-        #   "auto"   — the megakernel on TPU when the config is
-        #              supported AND the chip's compiler accepts its
-        #              geometry (_megakernel_compiles: head_dim a
-        #              multiple of 128, KV heads a multiple of 4), else
-        #              xla (off-TPU auto is ALWAYS xla: the interpreter
-        #              kernels are correctness tools, not serving paths).
-        # Per-call override: decode_*(..., engine=) — TextServer threads
-        # its own knob through the chunk scan this way.
-        if decode_engine not in DECODE_ENGINES:
-            raise ValueError(
-                f"unknown decode_engine {decode_engine!r}; one of "
-                f"{DECODE_ENGINES}"
-            )
-        self.decode_engine = decode_engine
-        if decode_engine in ("pallas", "pallas-layer"):
-            reason = self._decode_unsupported_reason()
-            if reason is not None:
-                raise ValueError(
-                    f"decode_engine={decode_engine!r} unsupported: "
-                    f"{reason}"
-                )
 
     # -- init --------------------------------------------------------------
 
@@ -1180,114 +1117,14 @@ class GPTLM:
 
     # -- KV-cache decoding -------------------------------------------------
 
-    def _decode_unsupported_reason(self) -> str | None:
-        """Why the fused Pallas decode kernel cannot serve this model
-        CONFIG, or None when it can. Static (config-only) half of the
-        support check; the params half (weight-only quantized trees) is
-        :meth:`_resolve_decode_engine`'s, because params arrive at call
-        time. Supported: dense FFN blocks, MHA/GQA, full or sliding
-        window (rolling slab and absolute paged layouts), learned or
-        rope positions, bf16/int8/fp8 KV caches."""
-        if self.moe_experts is not None:
-            return (
-                "MoE blocks route through ops/moe (expert dispatch is not "
-                "a single-launch shape); serve MoE models on the XLA "
-                "engine"
-            )
-        if self.matmul_dtype is not None:
-            return (
-                "matmul_dtype projections route through "
-                "ops/quantized.quantized_dot; the fused kernel runs "
-                "compute-dtype weights only"
-            )
-        d = self.model_dim
-        elem = jnp.dtype(self.compute_dtype).itemsize
-        attn_bytes = (
-            d * d + 2 * d * self.num_kv_heads * self.head_dim + d * d
-        ) * elem
-        ffn_bytes = 8 * d * d * elem
-        weight_bytes = attn_bytes + ffn_bytes
-        if weight_bytes > _DECODE_VMEM_WEIGHT_CAP:
-            return (
-                f"one layer's weights ({weight_bytes} B at compute dtype: "
-                f"attention {attn_bytes} B + FFN {ffn_bytes} B) exceed the "
-                f"fused kernels' per-layer VMEM cap "
-                f"({_DECODE_VMEM_WEIGHT_CAP} B = "
-                f"{_DECODE_VMEM_WEIGHT_CAP >> 20} MiB) — the megakernel "
-                "streams one layer at a time and the per-layer kernel "
-                "holds one block, so the bound is per LAYER either way; "
-                "the XLA engine streams weights from HBM instead"
-            )
-        return None
-
-    def _resolve_decode_engine(self, engine: str | None, params) -> str:
-        """Resolve the per-call ``engine`` override (None → the model's
-        ``decode_engine`` knob) to one of the three CONCRETE engines
-        "pallas" (megakernel tier) / "pallas-layer" (per-layer kernel)
-        / "xla". Either pallas variant with an unsupported config/params
-        RAISES (a serving deployment must not silently run a different
-        engine than it asked for); "auto" is the megakernel only on a
-        real TPU backend with a supported config the chip's compiler
-        accepts (:meth:`_megakernel_compiles`) — off-TPU auto always
-        resolves to xla (pinned in tests/test_pallas_decode.py). An
-        explicit "pallas" on a config the compiler refuses is passed
-        through and fails at compile time with the compiler's own
-        message."""
-        e = self.decode_engine if engine is None else engine
-        if e not in DECODE_ENGINES:
-            raise ValueError(
-                f"unknown decode engine {e!r}; one of {DECODE_ENGINES}"
-            )
-        if e == "xla":
-            return "xla"
-        reason = self._decode_unsupported_reason()
-        if reason is None and any(
-            isinstance(getattr(params.blocks, nm, None), QuantizedLinear)
-            for nm in ("wq", "wk", "wv", "wo", "w_up", "w_down")
-        ):
-            reason = (
-                "weight-only quantized decode params (QuantizedLinear "
-                "leaves from decode_weights) route through wo_dot; the "
-                "fused kernels run compute-dtype weights only"
-            )
-        if e in ("pallas", "pallas-layer"):
-            if reason is not None:
-                raise ValueError(
-                    f"decode_engine={e!r} unsupported: {reason}"
-                )
-            return e
-        # auto
-        if (
-            reason is not None
-            or pallas_mode.default_interpret()
-            or not self._megakernel_compiles()
-        ):
-            return "xla"
-        return "pallas"
-
-    def _megakernel_compiles(self) -> bool:
-        """Whether the chip's compiler accepts the megakernel's in-kernel
-        commit at this geometry. The rule is Mosaic's, not the kernel's
-        math: the commit DMAs each position's ``[Hkv, Dh]`` row group out
-        of VMEM scratch, and a row group can be sliced only at whole lane
-        tiles — ``head_dim % 128`` ("Slice shape along dimension 2 must be
-        aligned to tiling (128), but is 64") — and whole packed-sublane
-        tiles, four rows for a one-byte cache ("... aligned to tiling (4),
-        but is 2"), so ``num_kv_heads % 4`` covers every ``kv_dtype``.
-        tests/test_chip_compile.py pins both sides of both rules."""
-        return self.head_dim % 128 == 0 and self.num_kv_heads % 4 == 0
-
     def _commit_slot_rows(
         self, ck0, cv0, ks0, vs0, kq, vq, ksc, vsc, lengths, act
     ):
-        """The ONE slab fresh-row commit (per-row scatter at
-        ``lengths % C`` / ``lengths``; inactive rows write their old
-        value back — a no-op) — shared by the XLA engine
-        (``_decode_block_slots``) and the fused Pallas engine
-        (``_decode_slots_pallas``), so the two engines write identical
-        caches BY CONSTRUCTION, not by copy discipline. ``kq``/``vq``
-        [S, Hkv, Dh] storage-dtype rows, ``ksc``/``vsc`` [S, Hkv] f32
-        scales or None (bf16 layout). Returns (ck, cv, nks, nvs)."""
+        """The slab fresh-row commit of ``_decode_block_slots`` (per-row
+        scatter at ``lengths % C`` / ``lengths``; inactive rows write
+        their old value back — a no-op). ``kq``/``vq`` [S, Hkv, Dh]
+        storage-dtype rows, ``ksc``/``vsc`` [S, Hkv] f32 scales or None
+        (bf16 layout). Returns (ck, cv, nks, nvs)."""
         rows = jnp.arange(ck0.shape[0])
         c = self.cache_len
         slot = lengths % c if self.window is not None else lengths
@@ -1305,43 +1142,6 @@ class GPTLM:
                 jnp.where(act[:, None], vsc, vs0[rows, slot])
             )
         return ck, cv, nks, nvs
-
-    def _commit_paged_rows(
-        self, pk, pv, pks, pvs, kq, vq, ksc, vsc, tables, lengths, act
-    ):
-        """One layer's paged fresh-row commit (scatter through the block
-        tables at position ``lengths[s]``; inactive rows drop at the
-        sentinel) for the per-layer Pallas engine
-        (``_decode_paged_pallas``), which still takes each layer's pool
-        out of the stack and stacks them back. The XLA engine commits
-        all layers at once into the stacked pool
-        (``ops/paged_attention.commit_token_rows``); both reach the pool
-        through the one ``_table_index`` arithmetic there, so the two
-        engines write identical pools by construction. Row/scale shapes
-        as in :meth:`_commit_slot_rows`. Returns (nk, nv, nks, nvs)."""
-        from distributed_tensorflow_tpu.ops import paged_attention as paged
-
-        pos = lengths[:, None]
-        valid = act[:, None]
-        nk = paged.scatter_token_kv(pk, kq[:, None], tables, pos, valid)
-        nv = paged.scatter_token_kv(pv, vq[:, None], tables, pos, valid)
-        if pks is None:
-            return nk, nv, None, None
-        nks = paged.scatter_token_kv(pks, ksc[:, None], tables, pos, valid)
-        nvs = paged.scatter_token_kv(pvs, vsc[:, None], tables, pos, valid)
-        return nk, nv, nks, nvs
-
-    def _decode_kernel_weights(self, blk) -> dict:
-        """One layer's raw (f32) block weights as the plain dict
-        ops/pallas_decode consumes (cast + layout happen inside the
-        launch builder)."""
-        return {
-            nm: getattr(blk, nm)
-            for nm in (
-                "ln1_scale", "ln1_bias", "wq", "wk", "wv", "wo",
-                "ln2_scale", "ln2_bias", "w_up", "b_up", "w_down", "b_down",
-            )
-        }
 
     @property
     def cache_len(self) -> int:
@@ -1475,8 +1275,6 @@ class GPTLM:
         params: GPTLMParams,
         token: jax.Array,
         cache: KVCache,
-        *,
-        engine: str | None = None,
     ):
         """Append one token [B] int32; returns (logits [B, vocab], cache).
 
@@ -1486,21 +1284,12 @@ class GPTLM:
         abstract — loop drivers must bound their own trip count the way
         :meth:`greedy_decode` does.
 
-        The layer loop is UNROLLED, not a ``lax.scan`` (round-5 decode
-        fix): with the stacked cache as scan xs/ys, XLA double-buffers the
-        whole cache every token instead of updating one slot in place —
-        measured 939 µs/token vs 306 unrolled for an MHA cache at c=1024,
-        and 2311 vs 191 at c=4096 (tools/lm_bench.py decode table; the old
-        "15× decode-full cliff" was this, not physics — unrolled, config
-        gaps match their cache-traffic ratios). Decode graphs are tiny
-        (~20 ops/layer, forward-only), so unrolling costs no meaningful
-        compile time; :meth:`prefill` and training keep their scans.
-
-        ``engine`` (rounds 18+20, default: the model's ``decode_engine``
-        knob): "pallas" runs the WHOLE step as ONE megakernel launch
-        (weights streamed per layer, KV commit in-kernel);
-        "pallas-layer" runs each block as one fused launch with the
-        external scatter commit — same math either way."""
+        The layer loop is UNROLLED, not a ``lax.scan``: with the stacked
+        cache as scan xs/ys, XLA double-buffers the whole cache every
+        token instead of updating one slot in place. Decode graphs are
+        tiny (~20 ops/layer, forward-only), so unrolling costs no
+        meaningful compile time; :meth:`prefill` and training keep their
+        scans."""
         if not isinstance(cache.length, jax.core.Tracer):
             if int(cache.length) >= self.max_len:
                 raise ValueError(
@@ -1510,65 +1299,6 @@ class GPTLM:
         h = self._embed_tokens(
             params, token[:, None], jnp.reshape(cache.length, (1,))
         )
-        eng = self._resolve_decode_engine(engine, params)
-        if eng == "pallas":
-            from distributed_tensorflow_tpu.ops.pallas_decode import (
-                decode_token_slab,
-            )
-
-            b = token.shape[0]
-            lengths = jnp.broadcast_to(
-                jnp.asarray(cache.length, jnp.int32), (b,)
-            )
-            hr, nk, nv, _, _ = decode_token_slab(
-                h[:, 0], self._decode_kernel_weights(params.blocks),
-                cache.k, cache.v, None, None, lengths,
-                jnp.ones((b,), jnp.int32),
-                num_heads=self.num_heads, window=self.window,
-                kv_dtype="bf16", compute_dtype=self.compute_dtype,
-                rope=self.pos_embedding == "rope",
-            )
-            new_cache = KVCache(k=nk, v=nv, length=cache.length + 1)
-            return self._logits(params, hr[:, None])[:, 0], new_cache
-        if eng == "pallas-layer":
-            from distributed_tensorflow_tpu.ops.pallas_decode import (
-                decode_block_slab,
-            )
-
-            b = token.shape[0]
-            c = self.cache_len
-            lengths = jnp.broadcast_to(
-                jnp.asarray(cache.length, jnp.int32), (b,)
-            )
-            slot = cache.length % c if self.window is not None else cache.length
-            hr = h[:, 0]
-            nks, nvs = [], []
-            for i in range(self.num_layers):
-                blk = jax.tree.map(lambda x: x[i], params.blocks)
-                ck0, cv0, _, _ = _cache_layer(cache, i)
-                hr, kq, vq, _, _ = decode_block_slab(
-                    hr, self._decode_kernel_weights(blk),
-                    ck0, cv0, None, None, lengths,
-                    num_heads=self.num_heads, window=self.window,
-                    kv_dtype="bf16", compute_dtype=self.compute_dtype,
-                    rope=self.pos_embedding == "rope",
-                )
-                # Commit with the XLA engine's exact index math (the
-                # scalar-slot dynamic_update_slice of _decode_block).
-                with jax.named_scope(names.KV_WRITE):
-                    nks.append(
-                        lax.dynamic_update_slice(
-                            ck0, kq[:, None], (0, slot, 0, 0)
-                        )
-                    )
-                    nvs.append(
-                        lax.dynamic_update_slice(
-                            cv0, vq[:, None], (0, slot, 0, 0)
-                        )
-                    )
-            nk, nv, _, _ = _restack(nks, nvs)
-            new_cache = KVCache(k=nk, v=nv, length=cache.length + 1)
-            return self._logits(params, hr[:, None])[:, 0], new_cache
         nks, nvs = [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
@@ -1794,9 +1524,7 @@ class GPTLM:
             else:
                 kq, ksc = quantize_kv(k[:, 0], qd)  # [S,Hkv,Dh] + [S,Hkv]
                 vq, vsc = quantize_kv(v[:, 0], qd)
-            # The shared commit (round 18: also the Pallas engine's) —
-            # per-row scatter, inactive rows writing their old value
-            # back.
+            # Per-row scatter, inactive rows writing their old value back.
             ck, cv, nks, nvs = self._commit_slot_rows(
                 ck0, cv0, ks0, vs0, kq, vq, ksc, vsc, lengths, act
             )
@@ -1834,8 +1562,6 @@ class GPTLM:
         token: jax.Array,
         cache: SlotKVCache,
         active: jax.Array | None = None,
-        *,
-        engine: str | None = None,
     ):
         """Append one token per SLOT: token [S] int32 at each slot's own
         position. Returns (logits [S, vocab], cache with ``lengths``
@@ -1866,11 +1592,6 @@ class GPTLM:
             params, token[:, None], cache.lengths[:, None]
         )
         qd = self._kv_quant_dtype(cache)
-        eng = self._resolve_decode_engine(engine, params)
-        if eng == "pallas":
-            return self._decode_slots_mega(params, h, cache, act, qd)
-        if eng == "pallas-layer":
-            return self._decode_slots_pallas(params, h, cache, act, qd)
         nks, nvs, nksc, nvsc = [], [], [], []
         for i in range(self.num_layers):
             blk = jax.tree.map(lambda x: x[i], params.blocks)
@@ -1888,72 +1609,6 @@ class GPTLM:
             lengths=cache.lengths + act.astype(jnp.int32),
         )
         return self._logits(params, h)[:, 0], new_cache
-
-    def _decode_slots_pallas(self, params, h, cache, act, qd):
-        """Fused-kernel half of :meth:`decode_slots`: one
-        ``ops/pallas_decode.decode_block_slab`` launch per layer, then
-        the fresh row committed through :meth:`_commit_slot_rows` — the
-        SAME helper the XLA engine's ``cache_update`` calls, so the two
-        engines' caches (and therefore their token streams) stay in
-        step by construction."""
-        from distributed_tensorflow_tpu.ops.pallas_decode import (
-            decode_block_slab,
-        )
-
-        lengths = cache.lengths
-        hr = h[:, 0]  # [S, d]
-        nks, nvs, nksc, nvsc = [], [], [], []
-        for i in range(self.num_layers):
-            blk = jax.tree.map(lambda x: x[i], params.blocks)
-            ck0, cv0, ks0, vs0 = _cache_layer(cache, i)
-            hr, kq, vq, ksc, vsc = decode_block_slab(
-                hr, self._decode_kernel_weights(blk), ck0, cv0, ks0, vs0,
-                lengths,
-                num_heads=self.num_heads, window=self.window,
-                kv_dtype=qd or "bf16", compute_dtype=self.compute_dtype,
-                rope=self.pos_embedding == "rope",
-            )
-            ck, cv, ksn, vsn = self._commit_slot_rows(
-                ck0, cv0, ks0, vs0, kq, vq, ksc, vsc, lengths, act
-            )
-            nks.append(ck)
-            nvs.append(cv)
-            nksc.append(ksn)
-            nvsc.append(vsn)
-        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
-        new_cache = SlotKVCache(
-            k=nk, v=nv, k_scale=nks, v_scale=nvs,
-            lengths=lengths + act.astype(jnp.int32),
-        )
-        return self._logits(params, hr[:, None])[:, 0], new_cache
-
-    def _decode_slots_mega(self, params, h, cache, act, qd):
-        """Megakernel half of :meth:`decode_slots` (round 20): ONE
-        ``ops/pallas_decode.decode_token_slab`` launch covers every
-        layer AND the fresh-row commit — the cache arrays come back
-        written at the same indices :meth:`_commit_slot_rows` scatters
-        to (inactive rows skip in-kernel, the scatter's no-op,
-        bit-for-bit); only the logits head stays XLA (round-13 rule)."""
-        from distributed_tensorflow_tpu.ops.pallas_decode import (
-            decode_token_slab,
-        )
-
-        hr, nk, nv, nks, nvs = decode_token_slab(
-            h[:, 0], self._decode_kernel_weights(params.blocks),
-            cache.k, cache.v,
-            None if qd is None else cache.k_scale,
-            None if qd is None else cache.v_scale,
-            cache.lengths, act.astype(jnp.int32),
-            num_heads=self.num_heads, window=self.window,
-            kv_dtype=qd or "bf16", compute_dtype=self.compute_dtype,
-            rope=self.pos_embedding == "rope",
-        )
-        new_cache = SlotKVCache(
-            k=nk, v=nv,
-            lengths=cache.lengths + act.astype(jnp.int32),
-            k_scale=nks, v_scale=nvs,
-        )
-        return self._logits(params, hr[:, None])[:, 0], new_cache
 
     # -- paged decoding (block-table cache, serve.py paged=True) -----------
 
@@ -2119,58 +1774,6 @@ class GPTLM:
             k=nk, v=nv, k_scale=nksc, v_scale=nvsc
         )
 
-    def verify_paged(
-        self,
-        params: GPTLMParams,
-        cache: PagedKVCache,
-        tokens: jax.Array,
-        suffix_lens: jax.Array,
-        prefix_lens: jax.Array,
-        admit: jax.Array,
-        *,
-        engine: str | None = None,
-    ):
-        """The speculation-verify EXTEND (round 20): exactly
-        :meth:`extend_paged`'s contract — (per-position logits
-        [S, L, vocab], cache with K/V written, lengths/tables
-        caller-owned) — but engine-dispatched the way the decode paths
-        are. "pallas" runs ``ops/pallas_decode.verify_tokens_paged``:
-        ONE launch across all layers with the suffix causal block
-        folded into the online softmax and the valid rows committed
-        in-kernel (logits head stays XLA, round-13 rule). "xla" and
-        "pallas-layer" delegate to :meth:`extend_paged` verbatim (the
-        per-layer kernel has no multi-row step — XLA verify is its
-        pairing, and the parity oracle for the fused one). Greedy-exact
-        acceptance rides on the shared round-15 round-trip rule: both
-        engines attend exactly the values the cache stores."""
-        eng = self._resolve_decode_engine(engine, params)
-        if eng != "pallas":
-            return self.extend_paged(
-                params, cache, tokens, suffix_lens, prefix_lens, admit
-            )
-        from distributed_tensorflow_tpu.ops.pallas_decode import (
-            verify_tokens_paged,
-        )
-
-        s, l = tokens.shape
-        positions = prefix_lens[:, None] + jnp.arange(l)[None, :]
-        h = self._embed_tokens(params, tokens, positions)
-        qd = self._kv_quant_dtype(cache)
-        hr, nk, nv, nks, nvs = verify_tokens_paged(
-            h, self._decode_kernel_weights(params.blocks),
-            cache.k, cache.v,
-            None if qd is None else cache.k_scale,
-            None if qd is None else cache.v_scale,
-            cache.block_tables, prefix_lens, suffix_lens,
-            admit.astype(jnp.int32),
-            num_heads=self.num_heads, window=self.window,
-            kv_dtype=qd or "bf16", compute_dtype=self.compute_dtype,
-            rope=self.pos_embedding == "rope",
-        )
-        return self._logits(params, hr), cache._replace(
-            k=nk, v=nv, k_scale=nks, v_scale=nvs
-        )
-
     def _decode_block_paged(self, blk, h, cache, layer, live, qd=None):
         """Per-slot single-token block step against the BLOCK POOL. The
         layer-stacked pool is READ-ONLY here: attention walks the
@@ -2218,7 +1821,6 @@ class GPTLM:
         cache: PagedKVCache,
         active: jax.Array | None = None,
         *,
-        engine: str | None = None,
         live=None,
     ):
         """Append one token per slot through the block tables — the
@@ -2228,7 +1830,7 @@ class GPTLM:
         ``lengths[s]`` (the engine reserves ``prompt + max_new`` blocks
         at admission, so generation never outgrows the table).
 
-        The XLA engine never moves the pool and reads of it only what is
+        The step never moves the pool and reads of it only what is
         resident: the layer loop (UNROLLED, as in :meth:`decode_step`)
         walks the live-block list ``live``
         (``ops/paged_attention.live_block_list``) through each layer of
@@ -2236,9 +1838,8 @@ class GPTLM:
         A caller that steps many times makes the list once, for that
         many steps, and hands it in (the server's chunk scan); a call
         that brings none gets one made here for its single step. ONE
-        update
-        for K and one for V (and one per scale pool) commits every
-        layer's fresh row after it
+        update for K and one for V (and one per scale pool) commits
+        every layer's fresh row after it
         (``ops/paged_attention.commit_token_rows``: the places and the
         sentinel-drop of the commit :meth:`extend_paged` makes after its
         layer scan). The result is the argument changed at
@@ -2264,11 +1865,6 @@ class GPTLM:
             params, token[:, None], cache.lengths[:, None]
         )
         qd = self._kv_quant_dtype(cache)
-        eng = self._resolve_decode_engine(engine, params)
-        if eng == "pallas":
-            return self._decode_paged_mega(params, h, cache, act, qd)
-        if eng == "pallas-layer":
-            return self._decode_paged_pallas(params, h, cache, act, qd)
         from distributed_tensorflow_tpu.ops import paged_attention as paged
 
         if live is None:
@@ -2296,74 +1892,6 @@ class GPTLM:
             lengths=cache.lengths + act.astype(jnp.int32),
         )
         return self._logits(params, h)[:, 0], new_cache
-
-    def _decode_paged_pallas(self, params, h, cache, act, qd):
-        """Fused-kernel half of :meth:`decode_paged`: one
-        ``ops/pallas_decode.decode_block_paged`` launch per layer (the
-        block tables ride as scalar-prefetch args — the pool is read
-        block-by-block in the grid), then the fresh row committed through
-        :meth:`_commit_paged_rows` — the table arithmetic of the XLA
-        engine's all-layer commit, so both engines write identical
-        pools by construction."""
-        from distributed_tensorflow_tpu.ops.pallas_decode import (
-            decode_block_paged,
-        )
-
-        lengths = cache.lengths
-        tables = cache.block_tables
-        hr = h[:, 0]  # [S, d]
-        nks, nvs, nksc, nvsc = [], [], [], []
-        for i in range(self.num_layers):
-            blk = jax.tree.map(lambda x: x[i], params.blocks)
-            pk, pv, pks, pvs = _cache_layer(cache, i)
-            hr, kq, vq, ksc, vsc = decode_block_paged(
-                hr, self._decode_kernel_weights(blk), pk, pv, pks, pvs,
-                tables, lengths,
-                num_heads=self.num_heads, window=self.window,
-                kv_dtype=qd or "bf16", compute_dtype=self.compute_dtype,
-                rope=self.pos_embedding == "rope",
-            )
-            nk, nv, ksn, vsn = self._commit_paged_rows(
-                pk, pv, pks, pvs, kq, vq, ksc, vsc, tables, lengths, act
-            )
-            nks.append(nk)
-            nvs.append(nv)
-            nksc.append(ksn)
-            nvsc.append(vsn)
-        nk, nv, nks, nvs = _restack(nks, nvs, nksc, nvsc)
-        new_cache = cache._replace(
-            k=nk, v=nv, k_scale=nks, v_scale=nvs,
-            lengths=lengths + act.astype(jnp.int32),
-        )
-        return self._logits(params, hr[:, None])[:, 0], new_cache
-
-    def _decode_paged_mega(self, params, h, cache, act, qd):
-        """Megakernel half of :meth:`decode_paged` (round 20): ONE
-        ``ops/pallas_decode.decode_token_paged`` launch covers every
-        layer and commits the fresh rows through the block tables
-        in-kernel (inactive rows issue no DMA — the
-        ``scatter_token_kv`` sentinel-drop, bit-for-bit; the sentinel
-        itself never materializes)."""
-        from distributed_tensorflow_tpu.ops.pallas_decode import (
-            decode_token_paged,
-        )
-
-        hr, nk, nv, nks, nvs = decode_token_paged(
-            h[:, 0], self._decode_kernel_weights(params.blocks),
-            cache.k, cache.v,
-            None if qd is None else cache.k_scale,
-            None if qd is None else cache.v_scale,
-            cache.block_tables, cache.lengths, act.astype(jnp.int32),
-            num_heads=self.num_heads, window=self.window,
-            kv_dtype=qd or "bf16", compute_dtype=self.compute_dtype,
-            rope=self.pos_embedding == "rope",
-        )
-        new_cache = cache._replace(
-            k=nk, v=nv,
-            lengths=cache.lengths + act.astype(jnp.int32),
-            k_scale=nks, v_scale=nvs,
-        )
-        return self._logits(params, hr[:, None])[:, 0], new_cache
 
     def _check_decode_bounds(self, prompt, max_new):
         """Shared generation-length validation (every decode entry point:
@@ -2599,11 +2127,10 @@ def _cache_layer(cache, i: int):
     """Layer ``i``'s ``(k, v, k_scale, v_scale)`` out of a layer-stacked
     cache (scales None on a bf16 cache, and on a :class:`KVCache`, which
     has none). With :func:`_restack` this is the per-step restack of the
-    whole cache that the unrolled loops of ``decode_step``,
-    ``decode_slots`` and the two ``pallas-layer`` variants pay — scoped,
-    so a trace shows what it costs (``decode_paged``'s XLA engine reads
-    the stack in place instead: a third of its step at gpt2-large's
-    size, PERF.md §6, PR 27)."""
+    whole cache that the unrolled loops of ``decode_step`` and
+    ``decode_slots`` pay — scoped, so a trace shows what it costs
+    (``decode_paged`` reads the stack in place instead: the restack was
+    most of its step at gpt2-large's size, PERF.md §6, PR 27)."""
     with jax.named_scope(names.KV_RESTACK):
         ks = getattr(cache, "k_scale", None)
         vs = getattr(cache, "v_scale", None)

@@ -93,15 +93,11 @@ KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD_FUSED = "flash_bwd_fused"
 KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
 KERNEL_FLASH_BWD_DKV = "flash_bwd_dkv"
-KERNEL_DECODE_LAYER = "decode_layer"
-KERNEL_DECODE_TOKEN = "decode_token"
-KERNEL_VERIFY_TOKENS = "verify_tokens"
 KERNEL_MLP_TRAIN_STEP = "mlp_train_step"
 KERNEL_MLP_TRAIN_EPOCH = "mlp_train_epoch"
 KERNELS = (
     KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_FUSED, KERNEL_FLASH_BWD_DQ,
-    KERNEL_FLASH_BWD_DKV, KERNEL_DECODE_LAYER, KERNEL_DECODE_TOKEN,
-    KERNEL_VERIFY_TOKENS, KERNEL_MLP_TRAIN_STEP, KERNEL_MLP_TRAIN_EPOCH,
+    KERNEL_FLASH_BWD_DKV, KERNEL_MLP_TRAIN_STEP, KERNEL_MLP_TRAIN_EPOCH,
 )
 
 

@@ -64,28 +64,6 @@ def gather_block_view(pool: jax.Array, block_tables: jax.Array):
         return view.reshape(s, tabs * view.shape[2], *view.shape[3:])
 
 
-def scatter_token_kv(
-    pool_layer: jax.Array,
-    kv: jax.Array,
-    block_tables: jax.Array,
-    positions: jax.Array,
-    valid: jax.Array,
-):
-    """Write per-slot K (or V) rows into one layer's pool through the
-    block tables. ``kv`` [S, L, Hkv, Dh] holds the rows for logical
-    ``positions`` [S, L] (absolute per slot); ``valid`` [S, L] masks pad
-    positions and non-admitted slots — their writes drop at the sentinel
-    block. Distinct live slots never map the same WRITABLE block (the
-    allocator shares only immutable full prompt blocks, and writes land
-    past the prompt), so the scatter rows are disjoint by construction.
-
-    Delegates to :func:`scatter_token_kv_all_layers` with a 1-layer pool
-    so the sentinel/index arithmetic lives in exactly one place."""
-    return scatter_token_kv_all_layers(
-        pool_layer[None], kv[None], block_tables, positions, valid
-    )[0]
-
-
 def _table_index(block_tables, positions, valid, num_blocks, block_size):
     """``(block, offset)`` of logical ``positions`` [S, L] through the
     tables, masked writes routed to the sentinel block ``num_blocks``:
@@ -101,8 +79,15 @@ def scatter_token_kv_all_layers(
     positions: jax.Array,
     valid: jax.Array,
 ):
-    """All-layer variant (the extend path scatters once after its layer
-    scan): ``pool`` [n, NB, bs, Hkv, Dh], ``kvs`` [n, S, L, Hkv, Dh]."""
+    """Write per-slot K (or V) rows into every layer's pool through the
+    block tables (the extend path scatters once after its layer scan):
+    ``pool`` [n, NB, bs, Hkv, Dh]; ``kvs`` [n, S, L, Hkv, Dh] holds the
+    rows for logical ``positions`` [S, L] (absolute per slot); ``valid``
+    [S, L] masks pad positions and non-admitted slots — their writes
+    drop at the sentinel block. Distinct live slots never map the same
+    WRITABLE block (the allocator shares only immutable full prompt
+    blocks, and writes land past the prompt), so the scatter rows are
+    disjoint by construction."""
     n, nb, bs = pool.shape[0], pool.shape[1], pool.shape[2]
     s, l = positions.shape
     with jax.named_scope(names.KV_WRITE):

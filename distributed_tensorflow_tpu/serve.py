@@ -417,25 +417,16 @@ class TextServer:
         if decode_matmul_dtype is not None and params is not None:
             params = model.decode_weights(params, decode_matmul_dtype)
         self.params = params
-        # Decode-engine knob (rounds 18+20, docs/serving.md
-        # §decode-kernel): None defers to the model's own
-        # ``decode_engine``; "pallas" runs the k-token chunk scan's
-        # step as ONE megakernel launch per token AND — with
-        # spec_draft — the verify extend as the fused small-L kernel
-        # (ops/pallas_decode.py verify_tokens_paged, threaded through
-        # GPTLM.verify_paged); "pallas-layer" is the round-18
-        # per-layer kernel (verify falls back to XLA there). The
-        # EFFECTIVE engine (explicit knob OR the model's) is resolved
-        # ONCE here so an unsupported pairing (e.g.
-        # decode_matmul_dtype's QuantizedLinear tree + a pallas model
-        # knob) refuses at construction, not first dispatch. Prefill
-        # and the non-spec extend stay on XLA — they are batched-L
-        # graphs the flash/dense attention already serves; the
-        # kernels' domain is the L=1 chunk scan plus the
-        # L ≤ spec_draft+1 verify.
-        self.decode_engine = decode_engine
-        if params is not None:
-            model._resolve_decode_engine(decode_engine, params)
+        # ``decode_engine`` selected the fused Pallas decode tier, removed
+        # in PR 30; the keyword is still accepted because
+        # benchmark/traffic/*.json pass "auto" straight into this
+        # constructor (ROADMAP D14 drops it there, then it goes here).
+        if decode_engine not in (None, "auto", "xla"):
+            raise ValueError(
+                f"decode_engine={decode_engine!r}: the fused Pallas decode "
+                "tier was removed; there is one decode engine (leave the "
+                "keyword out)"
+            )
         self.tokenizer = tokenizer
         self.slots = slots
         self.chunk = chunk
@@ -540,7 +531,6 @@ class TextServer:
             "serving_cache_config",
             kv_dtype=self.kv_dtype,
             decode_matmul_dtype=self.decode_matmul_dtype,
-            decode_engine=self.decode_engine,
             paged=bool(paged),
             block_size=int(self.block_size) if paged else None,
             kv_blocks=int(self.kv_blocks) if paged else None,
@@ -903,9 +893,8 @@ class TextServer:
         graph's host contract, so the scheduler loop is shared."""
         max_len = self.model.max_len
         act = ~st.finished & (st.lengths < max_len)
-        logits, cache = self.model.verify_paged(
-            params, self._cache(st), suffix, suffix_lens, st.lengths, act,
-            engine=self.decode_engine,
+        logits, cache = self.model.extend_paged(
+            params, self._cache(st), suffix, suffix_lens, st.lengths, act
         )
         s, d1 = suffix.shape
         amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, D+1]
@@ -959,8 +948,8 @@ class TextServer:
         paged step differs only in how the cache row is addressed
         (:meth:`GPTLM.decode_paged` vs :meth:`GPTLM.decode_slots`).
 
-        The XLA engine's paged step reads the pool through a list of the
-        blocks that are resident, made here once for the whole chunk
+        The paged step reads the pool through a list of the blocks that
+        are resident, made here once for the whole chunk
         (every block a slot active now can reach by the chunk's end);
         the token block then carries two more rows, the list's real
         entries and the entries a step walks (that count rounded up to
@@ -970,19 +959,15 @@ class TextServer:
         decode = (
             self.model.decode_paged if self.paged else self.model.decode_slots
         )
-        # The XLA engine's paged step updates the pool where it lies, and
-        # the scan carries it with each position's [Hkv, Dh] row FLAT:
+        # The paged step updates the pool where it lies, and the scan
+        # carries it with each position's [Hkv, Dh] row FLAT:
         # the chip tiles an array's two minor axes (8 × 128 words), so a
         # carry ending in [20, 64] is padded 3.2 times over, where
         # [.., 1280] is not and a block is one contiguous tile to gather.
         # Two reshapes a chunk, none a step.
         pool_shape = st.k.shape
-        rows_flat = self.paged and (
-            self.model._resolve_decode_engine(self.decode_engine, params)
-            == "xla"
-        )
         walked = None
-        if rows_flat:
+        if self.paged:
             flat = pool_shape[:3] + (-1,)
             st = st._replace(k=st.k.reshape(flat), v=st.v.reshape(flat))
             live = paged_attention.live_block_list(
@@ -998,8 +983,7 @@ class TextServer:
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
             logits, cache = decode(
-                params, st.last_tok, self._cache(st), active=act,
-                engine=self.decode_engine,
+                params, st.last_tok, self._cache(st), active=act
             )
             carried, sub = self._split_keys(st.key)
             nxt = self._pick(logits, sub, st.greedy, st.temp, st.top_p)
@@ -1029,7 +1013,7 @@ class TextServer:
         st, (toks, valid) = jax.lax.scan(
             body, st, None, length=self.chunk
         )
-        if rows_flat:
+        if self.paged:
             st = st._replace(
                 k=st.k.reshape(pool_shape), v=st.v.reshape(pool_shape)
             )

@@ -12,23 +12,7 @@ on, and emits one JSON line with per-case max errors and pass/fail.
 Round 13 adds a ``fused-vs-split:*`` row per case: the one-pass fused
 dq+dk+dv backward (the new default) against the two-kernel split on the
 same forward, so the on-chip record covers the fused kernel explicitly.
-Round 18 adds ``decode-fused-vs-xla:*`` rows: the fused Pallas
-decode-step kernel (ops/pallas_decode.py) against the unrolled XLA
-decode engine over a short greedy decode — max logit error across
-steps plus the greedy-token agreement fraction, per serving-config
-feature (dense / GQA / rolling window / paged / int8 / fp8 KV). The
-round-3 lesson applies to these too: the CPU interpreter tolerates
-Mosaic-only bugs, so the rows only count as a kernel proof when the
-row says Mosaic.
-Round 20: the round-18 engine is now ``decode_engine="pallas-layer"``
-(the case names keep their committed round-18 ids); the new
-``decode-mega-vs-xla:*`` rows run the multi-layer megakernel
-(``decode_engine="pallas"``, one launch per token, in-kernel aliased
-cache commit) over the same matrix, and ``verify-fused-vs-xla:*`` rows
-prove the fused speculation-verify kernel (``GPTLM.verify_paged``)
-against the XLA extend path — logit error + argmax agreement on the
-valid suffix rows AND a bitwise cache/pool check (the commit contract).
-Rows now carry per-row ``device``/``mode`` provenance and
+Rows carry per-row ``device``/``mode`` provenance and
 ``--write-docs`` MERGES into the committed record: a Mosaic row is
 never overwritten by an interpreter rerun, so the round-2 on-chip
 record survives off-chip regenerations while new cases land beside it
@@ -77,194 +61,6 @@ CASES = [
     _case("kv-lens-gqa", h=8, hkv=2, kv_lens=(301, 444)),
     _case("offset-shifted-band", window=96, offset=256, l=512),
 ]
-
-
-def _decode_case(name, *, engine="pallas", kv_dtype="bf16", heads=4,
-                 kv_heads=None, window=None, paged=False):
-    return dict(
-        name=name, engine=engine, kv_dtype=kv_dtype, heads=heads,
-        kv_heads=kv_heads or heads, window=window, paged=paged,
-    )
-
-
-DECODE_CASES = [
-    # Round-18 rows: the per-layer kernel (its engine id became
-    # "pallas-layer" in round 20; the committed case names stay).
-    _decode_case("decode-fused-vs-xla:dense-bf16", engine="pallas-layer"),
-    _decode_case(
-        "decode-fused-vs-xla:dense-int8", engine="pallas-layer",
-        kv_dtype="int8",
-    ),
-    _decode_case(
-        "decode-fused-vs-xla:dense-fp8", engine="pallas-layer",
-        kv_dtype="fp8",
-    ),
-    _decode_case(
-        "decode-fused-vs-xla:gqa", engine="pallas-layer", heads=8,
-        kv_heads=2,
-    ),
-    _decode_case(
-        "decode-fused-vs-xla:window-rolling", engine="pallas-layer",
-        window=16,
-    ),
-    _decode_case(
-        "decode-fused-vs-xla:paged-int8", engine="pallas-layer",
-        kv_dtype="int8", paged=True,
-    ),
-    # Round-20 rows: the multi-layer megakernel over the same matrix.
-    _decode_case("decode-mega-vs-xla:dense-bf16"),
-    _decode_case("decode-mega-vs-xla:dense-int8", kv_dtype="int8"),
-    _decode_case("decode-mega-vs-xla:dense-fp8", kv_dtype="fp8"),
-    _decode_case("decode-mega-vs-xla:gqa", heads=8, kv_heads=2),
-    _decode_case("decode-mega-vs-xla:window-rolling", window=16),
-    _decode_case(
-        "decode-mega-vs-xla:paged-int8", kv_dtype="int8", paged=True
-    ),
-]
-
-
-VERIFY_CASES = [
-    _decode_case("verify-fused-vs-xla:bf16", paged=True),
-    _decode_case("verify-fused-vs-xla:int8", kv_dtype="int8", paged=True),
-    _decode_case("verify-fused-vs-xla:fp8", kv_dtype="fp8", paged=True),
-    _decode_case(
-        "verify-fused-vs-xla:gqa-int8", kv_dtype="int8", heads=8,
-        kv_heads=2, paged=True,
-    ),
-    _decode_case(
-        "verify-fused-vs-xla:window-int8", kv_dtype="int8", window=16,
-        paged=True,
-    ),
-]
-
-
-def _decode_model_and_cache(c: dict):
-    import numpy as np
-
-    from distributed_tensorflow_tpu.models.gpt import GPTLM
-
-    m = GPTLM(
-        vocab_size=97, max_len=64, model_dim=32, num_heads=c["heads"],
-        num_kv_heads=c["kv_heads"], num_layers=2, pos_embedding="rope",
-        window=c["window"],
-    )
-    params = m.init(seed=1)
-    rng = np.random.default_rng(0)
-    toks = jnp.asarray(rng.integers(0, 97, (3, 8)), jnp.int32)
-    lens = jnp.asarray([8, 5, 3], jnp.int32)
-    admit = jnp.ones((3,), bool)
-    if c["paged"]:
-        cache = m.empty_paged_cache(3, 24, block_size=8, kv_dtype=c["kv_dtype"])
-        tables = np.zeros((3, m.paged_blocks_per_slot(8)), np.int32)
-        nb = m.paged_blocks_per_slot(8)
-        for s in range(3):
-            tables[s] = np.arange(1 + s * nb, 1 + (s + 1) * nb) % 24
-        cache = cache._replace(block_tables=jnp.asarray(tables))
-        _, cache = m.extend_paged(
-            params, cache, toks, lens, jnp.zeros((3,), jnp.int32), admit
-        )
-        cache = cache._replace(lengths=lens)
-    else:
-        cache = m.empty_slot_cache(3, c["kv_dtype"])
-        _, cache = m.prefill_slots(params, cache, toks, lens, admit)
-    return m, params, cache
-
-
-def run_decode_case(c: dict) -> dict:
-    """One serving config's Pallas-vs-XLA decode parity: prefill three
-    ragged prompts into slots, then 8 greedy decode steps with BOTH
-    engines fed the XLA engine's token stream (teacher-forced) — so
-    every step scores the same prefix and the max logit error stays a
-    kernel-parity measurement even after a budgeted argmax flip (self-
-    fed streams would diverge at the first flip and the error metric
-    would measure different prefixes, not the kernel). Token agreement
-    is the per-step argmax match under those identical prefixes; ``ok``
-    needs logit error under the shared tolerance bar and ≥ 90% token
-    agreement (bf16 compute — flips at near-ties are the budgeted
-    residual; tests/test_pallas_decode.py pins the tight f32
-    contract). ``c["engine"]`` selects the kernel tier: "pallas-layer"
-    (round 18, one launch per block) or "pallas" (round 20 megakernel,
-    one launch per token)."""
-    import numpy as np
-
-    m, params, cache = _decode_model_and_cache(c)
-    decode = m.decode_paged if c["paged"] else m.decode_slots
-    tok = jnp.asarray([1, 2, 3], jnp.int32)
-    cx = cp = cache
-    tx = tok
-    steps, agree, err = 8, 0, 0.0
-    for _ in range(steps):
-        lx, cx = decode(params, tx, cx, engine="xla")
-        lp, cp = decode(params, tx, cp, engine=c["engine"])
-        err = max(err, float(jnp.max(jnp.abs(
-            lx.astype(jnp.float32) - lp.astype(jnp.float32)
-        ))))
-        nx = jnp.argmax(lx, -1).astype(jnp.int32)
-        npal = jnp.argmax(lp, -1).astype(jnp.int32)
-        agree += int((np.asarray(nx) == np.asarray(npal)).sum())
-        tx = nx  # teacher-force the XLA stream into BOTH engines
-    tok_match = agree / (steps * 3)
-    tol = ATOL + RTOL
-    return {
-        "case": c["name"],
-        "fwd_max_err": round(err, 5),
-        "tok_match": round(tok_match, 4),
-        "ok": bool(err < tol and tok_match >= 0.9),
-    }
-
-
-def run_verify_case(c: dict) -> dict:
-    """Fused speculation-verify parity (round 20): score a 4-token
-    draft suffix per slot with ``GPTLM.verify_paged`` under both
-    engines ("xla" delegates to the extend path; "pallas" launches the
-    fused verify kernel). Logit error and argmax agreement are measured
-    on the VALID suffix rows of admitted slots only; the committed
-    cache — payload AND quantization scales — must match the XLA
-    extend's scatter bit-for-bit on the payload (scales compare at f32
-    reassociation tolerance), because greedy-exact acceptance rides on
-    the verified suffix being the one the cache remembers."""
-    import numpy as np
-
-    m, params, cache = _decode_model_and_cache(c)
-    rng = np.random.default_rng(3)
-    suffix = jnp.asarray(rng.integers(0, 97, (3, 4)), jnp.int32)
-    slens = jnp.asarray([4, 3, 4], jnp.int32)
-    admit = jnp.asarray([True, True, False])
-    lx, cvx = m.verify_paged(
-        params, cache, suffix, slens, cache.lengths, admit, engine="xla"
-    )
-    lp, cvp = m.verify_paged(
-        params, cache, suffix, slens, cache.lengths, admit,
-        engine="pallas",
-    )
-    valid = (
-        (jnp.arange(suffix.shape[1])[None, :] < slens[:, None])
-        & admit[:, None]
-    )
-    err = float(jnp.max(jnp.where(
-        valid[..., None],
-        jnp.abs(lx.astype(jnp.float32) - lp.astype(jnp.float32)),
-        0.0,
-    )))
-    nx = np.asarray(jnp.argmax(lx, -1))
-    npal = np.asarray(jnp.argmax(lp, -1))
-    vmask = np.asarray(valid)
-    tok_match = float((nx == npal)[vmask].mean())
-    cache_ok = bool(jnp.all(cvx.k == cvp.k)) and bool(
-        jnp.all(cvx.v == cvp.v)
-    )
-    if cvx.k_scale is not None:
-        cache_ok = cache_ok and bool(
-            jnp.allclose(cvx.k_scale, cvp.k_scale, atol=1e-6)
-        ) and bool(jnp.allclose(cvx.v_scale, cvp.v_scale, atol=1e-6))
-    tol = ATOL + RTOL
-    return {
-        "case": c["name"],
-        "fwd_max_err": round(err, 5),
-        "tok_match": round(tok_match, 4),
-        "cache_bitwise": cache_ok,
-        "ok": bool(err < tol and tok_match >= 0.9 and cache_ok),
-    }
 
 
 def run_case(c: dict) -> dict:
@@ -414,8 +210,6 @@ def _case_order() -> list[str]:
     order = []
     for c in CASES:
         order += [c["name"], f"fused-vs-split:{c['name']}"]
-    order += [c["name"] for c in DECODE_CASES]
-    order += [c["name"] for c in VERIFY_CASES]
     return order
 
 
@@ -459,11 +253,7 @@ def main(argv=None) -> int:
     ap.add_argument("--write-docs", action="store_true")
     ap.add_argument("--cases", nargs="+", default=None)
     args = ap.parse_args(argv)
-    known = (
-        {c["name"] for c in CASES}
-        | {c["name"] for c in DECODE_CASES}
-        | {c["name"] for c in VERIFY_CASES}
-    )
+    known = {c["name"] for c in CASES}
     if args.cases:
         unknown = set(args.cases) - known
         if unknown:
@@ -487,18 +277,6 @@ def main(argv=None) -> int:
                     {"case": label, "ok": False,
                      "error": f"{type(exc).__name__}: {exc}"[:200]}
                 )
-    for cases, runner in ((DECODE_CASES, run_decode_case),
-                          (VERIFY_CASES, run_verify_case)):
-        for c in cases:
-            if args.cases and c["name"] not in args.cases:
-                continue
-            try:
-                rows.append(runner(c))
-            except Exception as exc:  # noqa: BLE001
-                rows.append(
-                    {"case": c["name"], "ok": False,
-                     "error": f"{type(exc).__name__}: {exc}"[:200]}
-                )
     for r in rows:
         r["device"] = device
         r["mode"] = mode
@@ -506,21 +284,20 @@ def main(argv=None) -> int:
     print(header)
 
     def _table(rs):
-        cols = ["case", "fwd", "dq", "dk", "dv", "tok", "device", "ok"]
+        cols = ["case", "fwd", "dq", "dk", "dv", "device", "ok"]
         lines = ["| " + " | ".join(cols) + " |", "|" + "---|" * len(cols)]
         for r in rs:
             dev = f"{r.get('device', '?')} ({r.get('mode', '?')})"
             if "error" in r:
                 lines.append(
-                    f"| {r['case']} | error: {r['error']} |" + " |" * 4
+                    f"| {r['case']} | error: {r['error']} |" + " |" * 3
                     + f" {dev} | FAIL |"
                 )
                 continue
             lines.append(
                 f"| {r['case']} | {r['fwd_max_err']} "
-                f"| {r.get('dq_max_err', '-')} | {r.get('dk_max_err', '-')} "
-                f"| {r.get('dv_max_err', '-')} | {r.get('tok_match', '-')} "
-                f"| {dev} "
+                f"| {r['dq_max_err']} | {r['dk_max_err']} "
+                f"| {r['dv_max_err']} | {dev} "
                 f"| {'PASS' if r['ok'] else 'FAIL'} |"
             )
         return "\n".join(lines)
@@ -566,19 +343,7 @@ def main(argv=None) -> int:
                 "only; interpreter rows are correctness previews awaiting"
                 "\nthe chip rerun). Forward and q/k/v gradient max-abs "
                 "errors vs the dense\noracle, bf16 inputs, per feature "
-                "(causal/window/banding/GQA/kv_lens/offset).\n"
-                "`decode-fused-vs-xla:*` rows (round 18): the per-layer "
-                "Pallas decode-step\nkernel (`decode_engine="
-                '"pallas-layer"`) vs the unrolled XLA decode engine —\n'
-                "max logit error over an 8-step greedy decode plus the "
-                "token-agreement\nfraction (`tok`). "
-                "`decode-mega-vs-xla:*` rows (round 20): the multi-layer"
-                "\nmegakernel (`decode_engine=\"pallas\"`, one launch per "
-                "token, in-kernel\naliased cache commit) over the same "
-                "matrix. `verify-fused-vs-xla:*` rows\n(round 20): the "
-                "fused speculation-verify kernel vs the XLA extend path "
-                "—\nlogit/argmax parity on valid suffix rows plus the "
-                "bitwise cache-commit\ncheck (`ok` includes it).\n\n"
+                "(causal/window/banding/GQA/kv_lens/offset).\n\n"
                 + _table(rows) + "\n"
             )
         print(f"wrote {root}/attention_parity.md")

@@ -55,15 +55,14 @@ import sys
 # point of that series is catching the count going UP from 0. ``bytes``/
 # ``bytes/token`` are comm payloads (diloco_bench's comm_bytes_per_token,
 # round 17): traffic creeping back UP past the compressed record is the
-# regression. ``us``/``µs`` variants (round 18): the decode-latency
-# series (serve_bench's decode_us_per_token) are microsecond-scale —
-# before this entry a us-unit latency series silently gated FAIL-LOW,
-# i.e. it would have flagged an IMPROVEMENT and waved regressions
-# through (direction pinned in tests/test_fleet_observability.py).
-# ``dispatches/token`` (round 20): the decode megakernel's structural
-# launch count — more launches per token is the regression (the whole
-# point of the tier is O(1)); fails HIGH, direction pinned alongside
-# the us variants. ``shed_rate`` (round 21): the per-class load-shed
+# regression. ``us``/``µs`` variants: a microsecond-scale latency
+# series must not silently gate FAIL-LOW, i.e. flag an IMPROVEMENT and
+# wave regressions through (direction pinned in
+# tests/test_fleet_observability.py). ``dispatches/token``: a launch
+# count per token — more launches is the regression; fails HIGH,
+# direction pinned alongside the us variants (no committed series
+# carries either unit since PR 30 took the decode-engine A/B out of
+# serve_bench). ``shed_rate`` (round 21): the per-class load-shed
 # fraction under the fixed overload scenario — MORE shedding at the
 # same offered load is a scheduling/capacity regression; fails HIGH.
 LOWER_IS_BETTER_UNITS = (

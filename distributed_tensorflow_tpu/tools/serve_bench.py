@@ -389,235 +389,6 @@ def bench_speculation(
     }
 
 
-def _decode_two_point(model, params, cache0, tok0, engine, *, k=16, reps=3):
-    """Per-decode-step seconds via the TWO-POINT method
-    (utils/sync.two_point_seconds): time a warm k-step and a 4k-step
-    compiled ``decode_slots`` chain and divide the DIFFERENCE by 3k, so
-    the per-dispatch fixed cost cancels instead of diluting into every
-    step. Each measurement ends in a D2H token fetch BEFORE the clock
-    read."""
-    import jax
-    from jax import lax
-
-    def chain(steps):
-        @jax.jit
-        def run(params, cache, tok):
-            def body(carry, _):
-                tok, cache = carry
-                logits, cache = model.decode_slots(
-                    params, tok, cache, engine=engine
-                )
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                return (nxt, cache), ()
-
-            (tok, cache), _ = lax.scan(
-                body, (tok, cache), None, length=steps
-            )
-            return tok
-
-        return run
-
-    run_k, run_4k = chain(k), chain(4 * k)
-    int(run_k(params, cache0, tok0)[0])  # compile + warm
-    int(run_4k(params, cache0, tok0)[0])
-
-    def timed(fn):
-        t0 = time.perf_counter()
-        out = fn(params, cache0, tok0)
-        _ = int(out[0])  # the fetch happens BEFORE perf_counter below
-        return time.perf_counter() - t0
-
-    vals = []
-    for _ in range(reps):
-        tk = timed(run_k)
-        t4k = timed(run_4k)
-        vals.append((t4k - tk) / (3 * k))
-    return float(np.median(vals))
-
-
-def bench_decode_engine(
-    *,
-    cache_lens: tuple[int, ...] = (256, 1024),
-    kv_dtypes: tuple[str, ...] = ("bf16", "int8"),
-    two_point_k: int = 16,
-    model_kw=None,
-) -> dict:
-    """Fused-Pallas vs unrolled-XLA decode engine A/B (round 18): per
-    (engine, kv_dtype, cache_len) config, µs/token over a slots=1
-    ``decode_slots`` chain measured with the two-point method, the cache
-    prefilled to half its length so attention spans a real resident
-    cache. The PALLAS rows are measured ONLY on a real TPU backend —
-    off-chip the kernel runs the Pallas *interpreter*, whose wall time
-    is a correctness artifact, not a latency record (worse than
-    meaningless: it would seed the gate band with garbage); skipped
-    engines land in ``pending`` with that provenance, and the chip
-    session's rerun (``--decode-engine``) fills them as a fresh
-    device-keyed series."""
-    import jax
-
-    rows, pending = [], []
-    device = jax.devices()[0].device_kind
-    on_tpu = jax.default_backend() == "tpu"
-    engines = ("xla", "pallas") if on_tpu else ("xla",)
-    if not on_tpu:
-        pending.append(
-            {
-                "engine": "pallas",
-                "note": "interpreter-only off-TPU; rerun "
-                "serve_bench --decode-engine on the chip",
-            }
-        )
-    for c in cache_lens:
-        mk = dict(
-            vocab_size=512, max_len=c, model_dim=128, num_heads=4,
-            num_layers=2,
-        )
-        mk.update(model_kw or {})
-        model, params = _build(mk)
-        rng = np.random.default_rng(5)
-        prompt = rng.integers(0, model.vocab_size, (c // 2,)).astype(
-            np.int32
-        )
-        for kv in kv_dtypes:
-            cache = model.empty_slot_cache(1, kv)
-            _, cache = model.prefill_slots(
-                params,
-                cache,
-                jnp.asarray(prompt[None, :]),
-                jnp.asarray([prompt.size], jnp.int32),
-                jnp.ones((1,), bool),
-            )
-            tok0 = jnp.zeros((1,), jnp.int32)
-            for engine in engines:
-                per_step = _decode_two_point(
-                    model, params, cache, tok0, engine, k=two_point_k
-                )
-                rows.append(
-                    {
-                        "engine": engine,
-                        "kv_dtype": kv,
-                        "cache_len": int(c),
-                        "us_per_token": round(per_step * 1e6, 2),
-                        "tokens_per_s": round(1.0 / per_step, 1),
-                    }
-                )
-    # Fused speedup per (kv, cache) pair when both engines measured.
-    speedups = []
-    for c in cache_lens:
-        for kv in kv_dtypes:
-            pair = {
-                r["engine"]: r
-                for r in rows
-                if r["kv_dtype"] == kv and r["cache_len"] == c
-            }
-            if "xla" in pair and "pallas" in pair:
-                speedups.append(
-                    {
-                        "kv_dtype": kv,
-                        "cache_len": int(c),
-                        "fused_speedup": round(
-                            pair["xla"]["us_per_token"]
-                            / pair["pallas"]["us_per_token"],
-                            2,
-                        ),
-                    }
-                )
-    return {
-        "device": device,
-        "slots": 1,
-        "two_point_steps": [two_point_k, 4 * two_point_k],
-        "model": {"model_dim": 128, "num_layers": 2, "num_heads": 4},
-        "rows": rows,
-        "speedups": speedups,
-        "pending": pending,
-    }
-
-
-def _count_dispatch_eqns(jaxpr) -> tuple[int, int]:
-    """(kernel launches, cache-commit ops) in a traced jaxpr: Pallas
-    launches are ``pallas_call`` eqns (counted whole — their interior
-    kernel jaxpr is one launch, never recursed into); commit ops are
-    the scatter family plus ``dynamic_update_slice``, the shapes XLA
-    emits for the per-layer cache/scale writes the fused kernels fold
-    into their aliased in-kernel DMA. Recurses through sub-jaxprs
-    (pjit/scan/cond bodies) so engine-internal structure can't hide
-    eqns from the count."""
-    kernels = commits = 0
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name == "pallas_call":
-            kernels += 1
-            continue
-        if name.startswith("scatter") or name == "dynamic_update_slice":
-            commits += 1
-        for v in eqn.params.values():
-            for x in v if isinstance(v, (tuple, list)) else (v,):
-                sub = getattr(x, "jaxpr", x)
-                if hasattr(sub, "eqns"):
-                    k, s = _count_dispatch_eqns(sub)
-                    kernels += k
-                    commits += s
-    return kernels, commits
-
-
-def bench_decode_dispatches(
-    *, cache_len: int = 256, kv_dtype: str = "int8", model_kw=None
-) -> dict:
-    """The CPU-deterministic half of the decode A/B (round 20):
-    dispatches per decoded token, counted on the TRACED ``decode_slots``
-    jaxpr rather than timed — launch counts are structural, identical on
-    every device, so this half commits a gate-stable series off-chip
-    while the µs/token rows stay pending for the v5e (the round-15
-    slot-density precedent). Convention: dispatches/token = pallas_call
-    eqns + cache-commit eqns (scatter family + dynamic_update_slice)
-    + 1 for the sampling tail (same one XLA dispatch for every engine).
-    The count is a structural proxy — XLA may fuse neighbouring commit
-    ops — but the ordering it certifies is the tentpole claim: the
-    unrolled XLA engine and the per-layer kernel both scale with
-    num_layers (~S kernel/commit pairs), the megakernel is O(1) (ONE
-    launch; the commit rides the kernel's input/output aliasing)."""
-    import jax
-
-    mk = dict(
-        vocab_size=512, max_len=cache_len, model_dim=128, num_heads=4,
-        num_layers=2,
-    )
-    mk.update(model_kw or {})
-    model, params = _build(mk)
-    cache = model.empty_slot_cache(1, kv_dtype)
-    tok0 = jnp.zeros((1,), jnp.int32)
-    act = jnp.ones((1,), bool)
-    rows = []
-    for engine in ("xla", "pallas-layer", "pallas"):
-
-        def step(p, t, c, a, engine=engine):
-            return model.decode_slots(p, t, c, a, engine=engine)
-
-        jaxpr = jax.make_jaxpr(step)(params, tok0, cache, act)
-        kernels, commits = _count_dispatch_eqns(jaxpr.jaxpr)
-        rows.append(
-            {
-                "engine": engine,
-                "kernel_launches": kernels,
-                "commit_ops": commits,
-                "dispatches_per_token": kernels + commits + 1,
-            }
-        )
-    return {
-        "device": "trace",
-        "cache_len": int(cache_len),
-        "kv_dtype": kv_dtype,
-        "model": {
-            "model_dim": mk["model_dim"],
-            "num_layers": mk["num_layers"],
-            "num_heads": mk["num_heads"],
-        },
-        "convention": "pallas_call + scatter-family/dynamic_update_slice "
-        "eqns in one traced decode_slots step, +1 sampling tail",
-        "rows": rows,
-    }
-
-
 def bench_fleet(
     *,
     replicas: int = 3,
@@ -1354,74 +1125,6 @@ def emit_bench_events(payload: dict, events_path: str) -> list[dict]:
         j.close()
 
 
-def emit_decode_events(payload: dict, events_path: str) -> list[dict]:
-    """The decode-engine A/B's gate-covered series: one
-    ``decode_us_per_token`` bench_point per measured (engine, kv_dtype,
-    cache_len) config, unit ``us/token`` — lower-is-better after the
-    round-18 unit-direction fix, so the gate fails HIGH on a latency
-    regression. Config is encoded in the series NAME (the gate bands by
-    (tool, name, device) — attrs alone would collapse every config into
-    one band); pending (unmeasured) engines emit nothing, so the chip
-    rerun starts those series fresh under its own device key."""
-    from distributed_tensorflow_tpu.observability.journal import EventJournal
-
-    de = payload["decode_engine"]
-    j = EventJournal(events_path, run_id="serve_bench")
-    try:
-        common = dict(tool="serve_bench", device=de["device"])
-        return [
-            j.emit(
-                "bench_point",
-                name=(
-                    f"decode_us_per_token_{r['engine']}_{r['kv_dtype']}"
-                    f"_c{r['cache_len']}"
-                ),
-                value=r["us_per_token"],
-                unit="us/token",
-                engine=r["engine"],
-                kv_dtype=r["kv_dtype"],
-                cache_len=r["cache_len"],
-                **common,
-            )
-            for r in de["rows"]
-        ]
-    finally:
-        j.close()
-
-
-def emit_dispatch_events(payload: dict, events_path: str) -> list[dict]:
-    """The dispatch-count half's gate series: one
-    ``decode_dispatches_per_token_{engine}`` bench_point per engine,
-    unit ``dispatches/token`` (LOWER_IS_BETTER — the gate fails HIGH if
-    an engine ever regresses to more launches per token). Device key is
-    the section's literal ``trace``: the count is structural, so its
-    band must never collide with a cpu- or chip-keyed timing series.
-    Emitted ONLY by ``--decode-dispatches`` — the µs/token series each
-    carry exactly one committed point and a dispatch refresh must not
-    append to them."""
-    from distributed_tensorflow_tpu.observability.journal import EventJournal
-
-    disp = payload["decode_engine"]["dispatches"]
-    j = EventJournal(events_path, run_id="serve_bench")
-    try:
-        common = dict(tool="serve_bench", device=disp["device"])
-        return [
-            j.emit(
-                "bench_point",
-                name=f"decode_dispatches_per_token_{r['engine']}",
-                value=r["dispatches_per_token"],
-                unit="dispatches/token",
-                engine=r["engine"],
-                kv_dtype=disp["kv_dtype"],
-                cache_len=disp["cache_len"],
-                **common,
-            )
-            for r in disp["rows"]
-        ]
-    finally:
-        j.close()
-
-
 def emit_fleet_events(payload: dict, events_path: str) -> list[dict]:
     """The fleet row's gate-covered bench_point series (round-12 gate:
     tokens/s fails LOW, the ttft ``s`` unit fails HIGH). The
@@ -1656,75 +1359,6 @@ def render(payload: dict) -> str:
             "which is not measured, like the round-13 int8 training "
             "row.",
         ]
-    de = payload.get("decode_engine")
-    if de:
-        dev = de.get("device", "?")
-        lines += [
-            "",
-            "## Fused decode-step engine A/B (`decode_engine`, "
-            "ops/pallas_decode.py)",
-            "",
-            "| engine | KV dtype | cache len | µs/token | tokens/s |",
-            "|---|---|---|---|---|",
-        ]
-        for r in de["rows"]:
-            lines.append(
-                f"| {r['engine']} | {r['kv_dtype']} | {r['cache_len']} "
-                f"| {r['us_per_token']} ({dev}) | {r['tokens_per_s']} |"
-            )
-        for s in de.get("speedups", []):
-            lines += [
-                "",
-                f"**Fused speedup ({s['kv_dtype']}, C={s['cache_len']}): "
-                f"{s['fused_speedup']}x** µs/token vs the unrolled XLA "
-                "engine.",
-            ]
-        lines += [
-            "",
-            f"Two-point method (k = {de['two_point_steps'][0]} vs "
-            f"{de['two_point_steps'][1]} warm compiled decode steps, "
-            "slots=1, cache prefilled to half its length; Δ/(3k) with a "
-            "D2H token fetch before every clock read), so the "
-            "per-dispatch fixed cost cancels out of the per-token "
-            "number.",
-        ]
-        for p in de.get("pending", []):
-            lines.append(
-                f"PENDING `{p['engine']}` rows: {p['note']} — the fused "
-                "kernel's latency claim (one launch per block at L=1, "
-                "int8/fp8 KV dequantized in-kernel) is measurable only "
-                "where Mosaic compiles it."
-            )
-        disp = de.get("dispatches")
-        if disp:
-            m = disp["model"]
-            lines += [
-                "",
-                "### Dispatches per token (traced — device-independent)",
-                "",
-                "| engine | kernel launches | commit ops "
-                "| dispatches/token |",
-                "|---|---|---|---|",
-            ]
-            for r in disp["rows"]:
-                lines.append(
-                    f"| {r['engine']} | {r['kernel_launches']} "
-                    f"| {r['commit_ops']} "
-                    f"| {r['dispatches_per_token']} |"
-                )
-            lines += [
-                "",
-                f"Counted on the traced `decode_slots` jaxpr "
-                f"({m['num_layers']} layers, d={m['model_dim']}, "
-                f"{disp['kv_dtype']} KV, C={disp['cache_len']}): "
-                f"{disp['convention']}. The XLA engine and the "
-                "per-layer kernel both scale with the layer count "
-                "(a kernel/commit pair per layer); the megakernel is "
-                "O(1) — one launch per token, the cache commit rides "
-                "its input/output aliasing. Structural counts, not "
-                "wall time: the gate series is committable off-chip "
-                "(round-15 slot-density precedent).",
-            ]
     sp = payload.get("speculation")
     if sp:
         lines += [
@@ -2016,62 +1650,10 @@ def main(argv=None) -> int:
         "serving.json (the --fleet merge pattern) — TTFT/tokens-per-s/"
         "migration-bytes series feed the gate",
     )
-    ap.add_argument(
-        "--decode-engine",
-        action="store_true",
-        help="run ONLY the fused-vs-XLA decode engine A/B and merge its "
-        "section into the committed serving.json (the --fleet merge "
-        "pattern); on the chip this fills the pallas rows, off-chip it "
-        "measures the xla rows and records the pallas ones as pending",
-    )
-    ap.add_argument(
-        "--decode-dispatches",
-        action="store_true",
-        help="re-count ONLY the dispatches-per-token half of the decode "
-        "A/B (traced jaxpr, device-independent) and merge it under the "
-        "committed decode_engine section — the timing rows (each a "
-        "single committed point per series) are untouched",
-    )
     args = ap.parse_args(argv)
     events_path = args.events
     if events_path is None and args.write_docs:
         events_path = os.path.join(_docs_root(), "events.jsonl")
-    if args.decode_dispatches:
-        disp = bench_decode_dispatches()
-        with open(os.path.join(_docs_root(), "serving.json")) as f:
-            payload = json.load(f)
-        payload.setdefault("decode_engine", {})["dispatches"] = disp
-        print(json.dumps(disp))
-        if args.write_docs:
-            write_docs(payload)
-            print(f"wrote {_docs_root()}/serving.md and serving.json")
-        else:
-            print(render(payload))
-        if events_path:
-            n = len(emit_dispatch_events(payload, events_path))
-            print(f"appended {n} bench_point events to {events_path}")
-        return 0
-    if args.decode_engine:
-        de = bench_decode_engine()
-        with open(os.path.join(_docs_root(), "serving.json")) as f:
-            payload = json.load(f)
-        # A timing rerun (chip or cpu) never re-traces the dispatch
-        # half — carry the committed counts forward (the --fleet merge
-        # pattern, one level down).
-        prev = payload.get("decode_engine") or {}
-        if "dispatches" in prev:
-            de.setdefault("dispatches", prev["dispatches"])
-        payload["decode_engine"] = de
-        print(json.dumps(de))
-        if args.write_docs:
-            write_docs(payload)
-            print(f"wrote {_docs_root()}/serving.md and serving.json")
-        else:
-            print(render(payload))
-        if events_path:
-            n = len(emit_decode_events(payload, events_path))
-            print(f"appended {n} bench_point events to {events_path}")
-        return 0
     if args.load_gen:
         lg = bench_load_gen()
         with open(os.path.join(_docs_root(), "serving.json")) as f:
@@ -2123,14 +1705,14 @@ def main(argv=None) -> int:
         slots=args.slots,
         chunk=args.chunk,
     )
-    # A full rerun re-measures every engine row but not the fleet row
-    # (subprocess bench, its own --fleet entry point) or the decode
-    # engine A/B (its own --decode-engine entry point): carry the
-    # committed sections forward instead of silently dropping them.
+    # A full rerun re-measures every engine row but not the sections
+    # with an entry point of their own (--fleet, --load-gen, --disagg):
+    # carry the committed sections forward instead of silently dropping
+    # them.
     try:
         with open(os.path.join(_docs_root(), "serving.json")) as f:
             old = json.load(f)
-        for key in ("fleet", "decode_engine", "load_gen", "disagg"):
+        for key in ("fleet", "load_gen", "disagg"):
             if key in old:
                 payload.setdefault(key, old[key])
     except (OSError, ValueError):

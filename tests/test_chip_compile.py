@@ -27,7 +27,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from distributed_tensorflow_tpu.ops import pallas_decode, pallas_mlp  # noqa: E402
+from distributed_tensorflow_tpu.ops import pallas_mlp  # noqa: E402
 from distributed_tensorflow_tpu.ops.pallas_attention import flash_attention  # noqa: E402
 from distributed_tensorflow_tpu.ops.pallas_mode import has_compiled_kernel  # noqa: E402
 
@@ -103,104 +103,7 @@ def test_mlp_kernels_compile(chip, which):
     assert has_compiled_kernel(_compile(fn, state, xs, ys))
 
 
-# -- serving side: the decode kernels at d=512 (gpt-m) -----------------------
-
-D, LAYERS, CACHE, SLOTS, POOL, BS = 512, 8, 1024, 8, 256, 16
-
-
-def _decode_call(entry: str, heads: int, kv: str, chip, kv_heads=None):
-    """(fn, args) for one ops/pallas_decode entry point at d=512 with
-    ``heads`` query heads (``kv_heads`` KV heads, default MHA) and ``kv``
-    cache dtype; abstract arguments only."""
-    dh = D // heads
-    kv_heads = kv_heads or heads
-    token = entry.startswith(("decode_token", "verify"))
-    paged = entry.endswith("paged")
-    lead = (LAYERS,) if token else ()
-    arr = lambda dt, *s: jax.ShapeDtypeStruct(lead + s, dt, sharding=chip)  # noqa: E731
-    flat = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=chip)  # noqa: E731
-    f32, i32 = jnp.float32, jnp.int32
-    weights = dict(
-        wq=arr(f32, D, D), wk=arr(f32, D, kv_heads * dh),
-        wv=arr(f32, D, kv_heads * dh),
-        wo=arr(f32, D, D), ln1_scale=arr(f32, D), ln1_bias=arr(f32, D),
-        ln2_scale=arr(f32, D), ln2_bias=arr(f32, D),
-        w_up=arr(f32, D, 4 * D), b_up=arr(f32, 4 * D),
-        w_down=arr(f32, 4 * D, D), b_down=arr(f32, D),
-    )
-    rows = (POOL, BS) if paged else (SLOTS, CACHE)
-    storage = jnp.int8 if kv == "int8" else jnp.bfloat16
-    cache = arr(storage, *rows, kv_heads, dh)
-    scale = arr(f32, *rows, kv_heads) if kv == "int8" else None
-    lens, act = flat(i32, SLOTS), flat(jnp.bool_, SLOTS)
-    tables = flat(i32, SLOTS, CACHE // BS)
-    kw = dict(num_heads=heads, kv_dtype=kv, interpret=False)
-    fn = getattr(pallas_decode, entry)
-    if entry == "verify_tokens_paged":
-        h = flat(f32, SLOTS, 4, D)  # spec_draft=3 → 4 rows per slot
-        args = (h, weights, cache, cache, scale, scale, tables, lens, lens, act)
-    else:
-        h = flat(f32, SLOTS, D)
-        args = (h, weights, cache, cache, scale, scale)
-        args += (tables,) if paged else ()
-        args += (lens, act) if token else (lens,)
-    return (lambda *a: fn(*a, **kw)), args
-
-
-_ENTRIES = (
-    "decode_block_slab", "decode_block_paged", "decode_token_slab",
-    "decode_token_paged", "verify_tokens_paged",
-)
-
-
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("entry", _ENTRIES)
-def test_decode_kernels_compile_at_head_dim_128(chip, entry, kv):
-    """All five entry points at 4 heads — the width `decode_engine="auto"`
-    resolves to the megakernel at (GPTLM._resolve_decode_engine)."""
-    fn, args = _decode_call(entry, 4, kv, chip)
-    assert has_compiled_kernel(_compile(fn, *args))
-
-
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("entry", _ENTRIES[:2])
-def test_per_layer_kernels_compile_at_head_dim_64(chip, entry, kv):
-    """gpt-m's own 8 heads: the per-layer kernel (`"pallas-layer"`)."""
-    fn, args = _decode_call(entry, 8, kv, chip)
-    assert has_compiled_kernel(_compile(fn, *args))
-
-
-@pytest.mark.parametrize("entry", _ENTRIES[2:])
-def test_megakernel_is_refused_at_head_dim_64(chip, entry):
-    """The other side of the auto rule (GPTLM._megakernel_compiles): at
-    head_dim 64 the in-kernel commit is refused by the compiler, in its
-    own words — which is what an explicit `decode_engine="pallas"` raises
-    there. When this stops failing, let `auto` admit the width."""
-    fn, args = _decode_call(entry, 8, "bf16", chip)
-    with pytest.raises(Exception, match="aligned to tiling|shape cast"):
-        _compile(fn, *args)
-
-
-def test_megakernel_is_refused_at_two_kv_heads_int8(chip):
-    """The rule's second half: a one-byte cache packs four rows to a
-    sublane tile, so a [2, Dh] commit row group cannot be sliced."""
-    fn, args = _decode_call("decode_token_paged", 4, "int8", chip, kv_heads=2)
-    with pytest.raises(Exception, match=r"aligned to tiling \(4\)"):
-        _compile(fn, *args)
-
-
-def test_auto_rule_matches_what_compiles():
-    from distributed_tensorflow_tpu.models.gpt import GPTLM
-
-    def admits(**kw):
-        return GPTLM(model_dim=512, **kw)._megakernel_compiles()
-
-    assert admits(num_heads=4)
-    assert not admits(num_heads=8)                   # head_dim 64
-    assert not admits(num_heads=4, num_kv_heads=2)   # two-row commit group
-
-
-# -- serving side: the XLA engine's paged chunk program ----------------------
+# -- serving side: the paged chunk program ------------------------------------
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -227,7 +130,7 @@ def test_paged_chunk_loop_leaves_the_pool_in_place(chip, kv):
     )
     srv = TextServer(
         model, None, slots=4, chunk=4, paged=True, block_size=16,
-        kv_blocks=48, kv_dtype=kv, decode_engine="xla",
+        kv_blocks=48, kv_dtype=kv,
     )
     on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
         x.shape, x.dtype, sharding=chip)

@@ -495,12 +495,23 @@ def test_launch_local_metrics_port_scrapes_live_gang(tmp_path):
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
-    worker = "import time; time.sleep(4)"
+    # The gang lives until the scrape has succeeded: each worker waits for
+    # a file this test writes (60 s at most), and the scrape goes on for
+    # as long as the launcher runs. A gang alive for a fixed 4 s scraped
+    # by 80 fixed tries raced the machine: under load the driver bound
+    # its port after the last try.
+    release = tmp_path / "scraped"
+    worker = (
+        "import os, sys, time\n"
+        "end = time.time() + 60\n"
+        "while not os.path.exists(sys.argv[1]) and time.time() < end:\n"
+        "    time.sleep(0.05)\n"
+    )
     result = {}
 
     def _run():
         result["rc"] = launch(
-            [sys.executable, "-c", worker],
+            [sys.executable, "-c", worker, str(release)],
             num_workers=2,
             logdir=str(tmp_path),
             max_restarts=1,
@@ -513,7 +524,7 @@ def test_launch_local_metrics_port_scrapes_live_gang(tmp_path):
     t.start()
     try:
         text, hz = None, None
-        for _ in range(80):  # the gang is live for ~4 s
+        while t.is_alive():
             try:
                 text = urlopen(
                     f"http://127.0.0.1:{port}/metrics", timeout=1
@@ -527,7 +538,8 @@ def test_launch_local_metrics_port_scrapes_live_gang(tmp_path):
             except Exception:  # noqa: BLE001 — not bound yet
                 time.sleep(0.05)
     finally:
-        t.join(timeout=60)
+        release.write_text("")
+        t.join(timeout=90)
     assert result["rc"] == 0
     assert text is not None, "never scraped the live driver"
     assert "# TYPE world_size gauge" in text and "world_size 2" in text
@@ -845,17 +857,16 @@ def test_gate_microsecond_units_fail_high():
 
 
 def test_gate_dispatch_unit_fails_high():
-    # Round 20: the megakernel's structural launch count
-    # ("dispatches/token", serve_bench's decode_dispatches_per_token
-    # series) is lower-is-better — the tier's whole claim is O(1)
-    # launches per token, so MORE launches is the regression and a
-    # fusion improvement must never trip the gate.
+    # A launch count per token ("dispatches/token"; the gate keeps the
+    # unit, no committed series carries it since PR 30) is
+    # lower-is-better: MORE launches is the regression and a fusion
+    # improvement must never trip the gate.
     mk = lambda vals, unit: [  # noqa: E731
         (i, v, unit) for i, v in enumerate(vals)
     ]
     assert "dispatches/token" in regression_gate.LOWER_IS_BETTER_UNITS
     res = regression_gate.check_series(
-        {("serve_bench", "decode_dispatches_per_token_pallas"): mk(
+        {("serve_bench", "decode_dispatches_per_token_a"): mk(
             [2.0, 2.0, 11.0], "dispatches/token"
         )},
         tolerance=0.5,
@@ -863,7 +874,7 @@ def test_gate_dispatch_unit_fails_high():
     [f] = res["failures"]
     assert f["direction"] == "above" and f["unit"] == "dispatches/token"
     assert not regression_gate.check_series(
-        {("serve_bench", "decode_dispatches_per_token_xla"): mk(
+        {("serve_bench", "decode_dispatches_per_token_b"): mk(
             [9.0, 9.0, 2.0], "dispatches/token"
         )},
         tolerance=0.5,

@@ -128,58 +128,6 @@ def test_diloco_docs_match_committed_artifact():
     )
 
 
-def test_serving_decode_engine_record():
-    """The round-18 decode-engine A/B is part of the committed serving
-    record: serving.json carries the ``decode_engine`` section (≥1
-    measured row with the gate-unit fields) and the committed serving.md
-    renders it (the byte-level staleness pin is
-    tests/test_serve.py::test_serving_record_docs_match_committed_artifact;
-    this guards the SECTION's presence so a full serve_bench rerun that
-    dropped the --decode-engine merge key would fail loudly)."""
-    from distributed_tensorflow_tpu.tools import serve_bench
-
-    root = serve_bench._docs_root()
-    with open(os.path.join(root, "serving.json")) as f:
-        payload = json.load(f)
-    de = payload.get("decode_engine")
-    assert de, (
-        "serving.json lost its decode_engine section; run python -m "
-        "distributed_tensorflow_tpu.tools.serve_bench --decode-engine "
-        "--write-docs"
-    )
-    assert de["rows"], "decode_engine section carries no measured rows"
-    for r in de["rows"]:
-        for key in ("engine", "kv_dtype", "cache_len", "us_per_token",
-                    "tokens_per_s"):
-            assert key in r
-    # Off-chip records must name the pallas rows as pending — the fused
-    # kernel's latency claim is chip-only until the Mosaic rerun.
-    if not any(r["engine"] == "pallas" for r in de["rows"]):
-        assert any(p["engine"] == "pallas" for p in de.get("pending", []))
-    # Round 20: the dispatch-count half (traced, device-independent) is
-    # committed beside the timing rows — every engine tier present, the
-    # megakernel at its O(1) count (one launch + the sampling tail),
-    # and the layer-scaling engines strictly above it.
-    disp = de.get("dispatches")
-    assert disp, (
-        "decode_engine section lost its dispatches half; run python -m "
-        "distributed_tensorflow_tpu.tools.serve_bench "
-        "--decode-dispatches --write-docs"
-    )
-    assert disp["device"] == "trace"
-    counts = {
-        r["engine"]: r["dispatches_per_token"] for r in disp["rows"]
-    }
-    assert set(counts) == {"xla", "pallas-layer", "pallas"}
-    assert counts["pallas"] == 2
-    assert counts["xla"] > counts["pallas"]
-    assert counts["pallas-layer"] > counts["pallas"]
-    with open(os.path.join(root, "serving.md")) as f:
-        committed = f.read()
-    assert "Fused decode-step engine A/B" in committed
-    assert "Dispatches per token" in committed
-
-
 def test_serving_load_gen_record():
     """Round 21: the overload-robustness row is part of the committed
     serving record — serving.json carries the ``load_gen`` section with

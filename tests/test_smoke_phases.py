@@ -123,7 +123,6 @@ def test_lm_train_then_serve_phases(smoke, meter, tmp_path, capsys):
     assert train["checkpoint_step"] == 9
     assert train["last_loss"] < train["first_loss"]
     assert serve["phase"] == "serve" and serve["passed"]
-    assert serve["engine"] == "xla"
     assert serve["checkpoint_step"] == 9
     assert serve["requests"] == 5 > serve["slots"]
     # f32-exact on the CPU: no near-tie is ever needed here.
@@ -153,29 +152,6 @@ def test_hybrid_train_phase(smoke, meter, capsys):
     assert 0 < line["gauges"]["moe_rows_per_step"] <= 4 * 32 * 2
 
 
-def test_decode_kernels_phase(smoke, meter, capsys):
-    smoke.phase_decode_kernels(
-        meter, compiled_kernels=False,
-        model_kw=dict(
-            vocab_size=64, max_len=32, model_dim=32, num_layers=2,
-            compute_dtype=jnp.float32,
-        ),
-        wide_heads=2, narrow_heads=4, wide_cases=(("pallas", 2),),
-        narrow_cases=(("pallas-layer", 0),), slots=2, chunk=4, block_size=4,
-        buckets=(8, 16), greedy_lens=(3, 6), sampled_lens=(4,), max_new=5,
-    )
-    (line,) = _lines(capsys)
-    assert line["phase"] == "decode_kernels" and line["passed"]
-    assert [p["engine"] for p in line["passed_engines"]] == [
-        "pallas@head_dim=16+spec_draft=2", "pallas-layer@head_dim=8",
-    ]
-    assert all(
-        p["streams_equal"] == 3 and not p["near_ties"]
-        for p in line["passed_engines"]
-    )
-    assert line["not_compiled"] == []  # listed from the chip's compiler only
-
-
 def test_compare_streams_names_the_first_divergence(smoke):
     """A stream that differs from its reference beyond a near-tie fails
     with the position and both tokens; equal streams pass silently."""
@@ -188,14 +164,10 @@ def test_compare_streams_names_the_first_divergence(smoke):
     params = model.init(seed=1)
     prompt = jnp.arange(5, dtype=jnp.int32)
     want = model.greedy_decode(params, prompt[None], 4)[0, 5:]
-    assert smoke.compare_streams(
-        model, params, prompt, want, want, (None, "xla")
-    ) is None
+    assert smoke.compare_streams(model, params, prompt, want, want) is None
     got = want.at[2].set((want[2] + 1) % 64)
     with pytest.raises(smoke.SmokeFailure, match="'position': 2"):
-        smoke.compare_streams(
-            model, params, prompt, got, want, (None, "xla")
-        )
+        smoke.compare_streams(model, params, prompt, got, want)
 
 
 def test_parallel_phase_on_virtual_devices(smoke, meter, small_datasets, capsys):

@@ -372,16 +372,6 @@ def test_flash_kernels_are_named_in_the_jaxpr(fused, kernels):
             or f"name={n} " in jaxpr} == kernels
 
 
-def test_decode_kernels_are_named_in_the_jaxpr():
-    m = _tiny_model(decode_engine="pallas-layer")
-    p = m.init(1)
-    cache = m.empty_slot_cache(2)
-    jaxpr = str(jax.make_jaxpr(
-        lambda tok: m.decode_slots(p, tok, cache)[0]
-    )(jnp.zeros((2,), jnp.int32)))
-    assert f"name={names.KERNEL_DECODE_LAYER}" in jaxpr
-
-
 # -- the benchmark names only what the program promises -------------------------
 
 
