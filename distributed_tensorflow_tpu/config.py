@@ -138,17 +138,20 @@ class TrainConfig:
     # Global-norm gradient clipping; 0 disables (reference parity — the
     # reference's naive loss has no gradient guard and can diverge).
     grad_clip_norm: float = 0.0
-    # Rematerialization (jax.checkpoint on the model forward): recompute
-    # activations in the backward pass instead of storing them — trades MXU
-    # FLOPs for HBM activation memory. Gradients unchanged. The LM family
-    # additionally accepts "selective" (round 13): a Pallas-aware
-    # jax.checkpoint policy that SAVES the flash-attention out+lse
-    # (cheap, O(B·L·d)) and recomputes only the layernorm/QKV/MLP half of
-    # each block — grad-identical to True, reaches every dp_mode through
-    # LMTrainer. Wins on MXU-sized rows where the recompute third is
-    # mostly attention (docs/benchmarks/lm_phases.md); keep plain True at
-    # toy widths. The classifier path treats any truthy value as plain
-    # remat (its models have no selective policy surface).
+    # Rematerialization (jax.checkpoint on the model forward): replay
+    # activations in the backward pass instead of storing them — trades
+    # FLOPs for HBM activation memory. Gradients unchanged. For the LM
+    # family True (and its older spelling "selective") is a checkpoint a
+    # layer that keeps what costs more to replay than to hold: the flash
+    # kernel's output and log-sum-exp where the kernel is engaged and,
+    # under dp_mode="tp", the residual stream after the attention's
+    # output was summed across the `model` axis (GPTLM.__init__ has the
+    # list and this chip's numbers: +4% tokens/s on one chip, +8% on
+    # four under tp, PERF.md section 6, PR 31). It reaches every
+    # dp_mode through LMTrainer. A model built with
+    # GPTLM(remat=jax.checkpoint_policies.nothing_saveable) holds the
+    # least (one [B, L, d] a layer). The classifier path treats any
+    # truthy value as the plain checkpoint (its models name no value).
     remat: bool | str = False
     # Opt-in low-precision projection matmuls for the LM family
     # (models/gpt.GPTLM(matmul_dtype=), ops/quantized.py): None | "int8"

@@ -119,7 +119,7 @@ def config_from_env(base: TrainConfig | None = None) -> TrainConfig:
         raw = os.environ["DTF_REMAT"]
         # Empty/0/1 keep the boolean surface (empty = off, matching the
         # sibling knob's unset-style contract); "selective" is the
-        # round-13 policy; anything else fails in
+        # older spelling of 1 for the LM family; anything else fails in
         # TrainConfig.__post_init__.
         kw["remat"] = raw == "1" if raw in ("", "0", "1") else raw
     if "DTF_MATMUL_DTYPE" in os.environ:

@@ -190,7 +190,18 @@ class KVCache(NamedTuple):
 
 
 class GPTLM:
-    """tokens [B, L] int32 → next-token logits [B, L, vocab]."""
+    """tokens [B, L] int32 → next-token logits [B, L, vocab].
+
+    ``remat=True`` (``"selective"`` means the same) checkpoints each
+    block and keeps what costs more to replay than to hold: the flash
+    kernel's output and log-sum-exp where the kernel is engaged and,
+    under tensor parallelism, the residual stream after the attention's
+    output was summed across chips. That is two ``[B, L, d]`` values a
+    layer, three under ``tp``, where a checkpoint that keeps nothing
+    holds one: a run at long context that wants the least memory passes
+    ``remat=jax.checkpoint_policies.nothing_saveable`` (any callable is
+    handed to ``jax.checkpoint`` as its policy). The constructor's
+    comment has the list and the measured gain."""
 
     def __init__(
         self,
@@ -284,37 +295,49 @@ class GPTLM:
         self.flash_min_len = flash_min_len
         # (mesh, batch axis | None, head axis | None) when this model's
         # forward runs inside a GSPMD-sharded program: the flash kernel
-        # is then mapped per device (_flash_attend). Not a constructor
-        # knob — the trainer that owns the mesh sets it on its own copy.
+        # is then mapped per device (_flash_attend), and a head axis
+        # says the block's row-split products are summed across chips
+        # (_tensor_parallel). Not a constructor knob — the trainer that
+        # owns the mesh sets it on its own copy.
         self.attention_shard = None
-        # jax.checkpoint around each scanned block: activation memory drops
-        # from O(num_layers · L · d) to O(L · d) + one block's recompute per
-        # layer in the backward — the standard long-context memory/FLOPs
-        # trade (the reference never needed it: 784-feature MLP).
-        #
-        # Round 13 widens the knob into a POLICY surface:
-        #   True        — plain jax.checkpoint (recompute everything);
-        #   "selective" — jax.checkpoint with save_only_these_names over
-        #                 the flash-attention out+lse (O(B·L·d) to store
-        #                 vs the O(L²)-work kernel recompute); only the
-        #                 layernorm/QKV/MLP half of each block replays.
-        #                 Grad-identical to True (pinned in test_gpt.py).
-        #                 WHEN IT WINS: MXU-sized rows with the flash
-        #                 kernel engaged (d≈2048, L ≥ flash_min_len),
-        #                 where the measured backward is three near-equal
-        #                 forwards and the recompute third is mostly
-        #                 attention (docs/benchmarks/lm_phases.md). Toy
-        #                 widths — and any config on the dense-attention
-        #                 fallback — should keep remat=True: there the
-        #                 saved tensors cost more HBM than the recompute
-        #                 costs FLOPs (the round-4 dots-saveable probe
-        #                 lost to plain remat the same way).
-        #   callable    — passed straight to jax.checkpoint(policy=...).
+        # jax.checkpoint around each scanned block: the backward replays a
+        # block from its input instead of holding every activation of
+        # every layer. What a layer's checkpoint KEEPS is what costs more
+        # to replay than to hold (_remat_policy):
+        #   True, "selective" — the flash kernel's output and its
+        #                 log-sum-exp ([B, L, d] + [B, L, H] a layer),
+        #                 tagged only where the kernel is engaged, so the
+        #                 dense fallback and lengths under flash_min_len
+        #                 keep nothing; and, where the trainer runs this
+        #                 model under a tensor-parallel axis
+        #                 (attention_shard names a head axis), the
+        #                 residual stream after the attention's row-split
+        #                 product was summed over that axis ([B, L, d]),
+        #                 so the replay is ln1, q/k/v, ln2, the
+        #                 up-projection and the GELU: no flash forward,
+        #                 no `wo` product, no second all-reduce. On one
+        #                 chip nothing is exchanged and the `wo` product
+        #                 replays at the MXU's rate, so that value is
+        #                 not kept there. Grad-identical to remat=False
+        #                 (tests/test_gpt.py, tests/test_lm_trainer.py).
+        #   callable    — passed straight to jax.checkpoint(policy=...);
+        #                 jax.checkpoint_policies.nothing_saveable holds
+        #                 the least a checkpoint can (one [B, L, d] a
+        #                 layer: the meaning of True before PR 31) and
+        #                 replays the kernel and the exchange.
+        # MEASURED (TPU v5e, 1,024-token rows, flash engaged; PERF.md
+        # section 6, PR 31), against nothing_saveable: gpt2-medium on one
+        # chip at batch 8, +4% tokens/s for +0.8 GB (9.05 GB of 16: the
+        # replayed kernel was 24 ms of a 283 ms step, and copying what is
+        # kept into and out of the layers' stack gives 12 of them back);
+        # gpt2-large under a 2x2 data x model mesh at batch 16, +8%
+        # tokens/s for +2.3 GB a chip (five exposed all-reduces a layer,
+        # not six). At toy widths or on the dense fallback nothing is
+        # tagged, so nothing is kept and nothing is lost.
         # Every forward path (scanned stack, sp/ep bodies, pipeline
         # stages) routes through _remat_wrap, so the policy reaches every
-        # dp_mode. The shard_map sp ring does not thread the save names —
-        # "selective" there degrades to plain remat semantics (correct,
-        # no savings).
+        # dp_mode. The shard_map sp ring does not thread the save names:
+        # there every mode is nothing_saveable (correct, no savings).
         if not (
             isinstance(remat, bool)
             or remat == "selective"
@@ -535,26 +558,30 @@ class GPTLM:
         return "int8" if cache.k.dtype == jnp.int8 else "fp8"
 
     @property
-    def _policy_remat(self) -> bool:
-        """Whether ``remat`` is a POLICY mode ("selective" or a callable)
-        rather than the plain boolean — the modes under which ``_attend``
-        tags the flash forward with checkpoint names."""
-        return bool(self.remat) and self.remat is not True
+    def _tensor_parallel(self) -> bool:
+        """Whether this copy's forward runs under a tensor-parallel mesh
+        axis (the trainer said so through ``attention_shard``)."""
+        return (
+            self.attention_shard is not None
+            and self.attention_shard[2] is not None
+        )
 
     def _remat_policy(self):
-        """The jax.checkpoint policy for the current ``remat`` value, or
-        None for the plain (save-nothing) checkpoint."""
-        if self.remat == "selective":
-            from distributed_tensorflow_tpu.ops.pallas_attention import (
-                REMAT_SAVE_NAMES,
-            )
-
-            return jax.checkpoint_policies.save_only_these_names(
-                *REMAT_SAVE_NAMES
-            )
+        """The jax.checkpoint policy for the current ``remat`` value: a
+        callable as given, else keep the values this model names — the
+        flash kernel's output and log-sum-exp (``_flash_attend``) and,
+        under tensor parallelism, the attention's summed output
+        (``_block``). A name no one tagged keeps nothing."""
         if callable(self.remat):
             return self.remat
-        return None
+        from distributed_tensorflow_tpu.ops.pallas_attention import (
+            REMAT_SAVE_NAMES,
+            REMAT_SAVE_TP_SUM,
+        )
+
+        return jax.checkpoint_policies.save_only_these_names(
+            *REMAT_SAVE_NAMES, REMAT_SAVE_TP_SUM
+        )
 
     def _remat_wrap(self, body):
         """``jax.checkpoint`` around a scanned-block (or pipeline-stage)
@@ -562,10 +589,7 @@ class GPTLM:
         uses, so a policy mode reaches dense/sp/ep/pp identically."""
         if not self.remat:
             return body
-        policy = self._remat_policy()
-        if policy is None:
-            return jax.checkpoint(body)
-        return jax.checkpoint(body, policy=policy)
+        return jax.checkpoint(body, policy=self._remat_policy())
 
     def _attend(self, q, k, v, kv_lens=None):
         from distributed_tensorflow_tpu.models.base import (
@@ -593,12 +617,11 @@ class GPTLM:
             flash_attention,
         )
 
-        # Selective remat: name out+lse so the enclosing checkpoint
-        # policy saves them and the backward recompute skips the
-        # O(L²)-work forward kernel (the rebuild composition — see
-        # flash_attention_with_lse). Inert without an enclosing policy
-        # (eval/prefill paths).
-        names = REMAT_SAVE_NAMES if self._policy_remat else None
+        # Under remat, name out+lse so the layer's checkpoint keeps them
+        # and the backward's replay skips the O(L²)-work forward kernel
+        # (the rebuild composition — see flash_attention_with_lse). A
+        # policy that does not list the names replays it as before.
+        names = REMAT_SAVE_NAMES if self.remat else None
 
         def kernel(q, k, v, *lens):
             return flash_attention(
@@ -761,6 +784,17 @@ class GPTLM:
             attn = (attend or self._attend)(q, k, v)
         with jax.named_scope(names.ATTN_OUT):
             h = h + self._dot(attn.reshape(b, l, d), blk.wo)
+            if self.remat and self._tensor_parallel:
+                # `wo` is row-split: the product above ends in an
+                # all-reduce over the model axis. Name its result, so the
+                # layer's checkpoint keeps it and the replay pays neither.
+                from jax.ad_checkpoint import checkpoint_name
+
+                from distributed_tensorflow_tpu.ops.pallas_attention import (
+                    REMAT_SAVE_TP_SUM,
+                )
+
+                h = checkpoint_name(h, REMAT_SAVE_TP_SUM)
         with jax.named_scope(names.MLP):
             hn2 = _layernorm(h, blk.ln2_scale, blk.ln2_bias)
             if ffn is not None:

@@ -368,10 +368,18 @@ class HybridLM:
         with jax.named_scope(names.ATTN_OUT):
             return self._dot(a.reshape(b, l, -1), p.wo)
 
-    # remat: GPTLM's policy surface, by its own code (False | True |
-    # "selective" | a jax.checkpoint policy), applied per layer.
-    _policy_remat = GPTLM._policy_remat
-    _remat_policy = GPTLM._remat_policy
+    # remat, applied per layer: GPTLM's values (False | True | "selective"
+    # | a jax.checkpoint policy), and its code for all but True. Here True
+    # keeps nothing: this stack's cell trains at 94.7% of its chip's
+    # memory (PERF.md section 4), where one attention layer's kept output
+    # buys under 1% of the step and may not load.
+    @property
+    def _policy_remat(self) -> bool:
+        return bool(self.remat) and self.remat is not True
+
+    def _remat_policy(self):
+        return GPTLM._remat_policy(self) if self._policy_remat else None
+
     _remat_wrap = GPTLM._remat_wrap
 
     # -- forward -------------------------------------------------------------

@@ -750,10 +750,16 @@ def _flash_bwd(
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# The checkpoint_name labels under which the selective-remat policy saves
-# the attention forward (models/gpt.py remat="selective" builds
-# jax.checkpoint_policies.save_only_these_names(*REMAT_SAVE_NAMES)).
+# The checkpoint_name labels under which a layer's checkpoint keeps the
+# attention forward (models/gpt.py: remat=True or "selective" builds
+# jax.checkpoint_policies.save_only_these_names over them).
 REMAT_SAVE_NAMES = ("flash_out", "flash_lse")
+# Kept beside them, and tagged under tensor parallelism only
+# (GPTLM._block): the residual stream once the attention's row-split
+# product has been summed over the model axis, so that the replay pays
+# neither the product nor its all-reduce a second time.
+REMAT_SAVE_TP_SUM = "attn_out_sum"
+_LANES = 128  # the minor dimension of the chip's (8, 128) float32 tile
 
 # Auto-fusion cap: the fused backward's dq-partial buffer is
 # nk · (B·H·L·D) f32 in HBM — (L/bk) full gradient copies. The default
@@ -984,7 +990,15 @@ def flash_attention_with_lse(
             lax.stop_gradient(qb), lax.stop_gradient(kb),
             lax.stop_gradient(vb), kv_lens,
         )
-        o = checkpoint_name(o, save_names[0])
+        # The output is named in a shape whose rows fill the chip's 128
+        # lanes: held as the kernel writes it, a head_dim of 64 is padded
+        # to twice its bytes in every layer's kept copy.
+        fold = _LANES // d if _LANES % d == 0 else 1
+        if l % fold:
+            fold = 1
+        o = checkpoint_name(
+            o.reshape(-1, d * fold), save_names[0]
+        ).reshape(o.shape)
         lse0 = checkpoint_name(lse0, save_names[1])
         out, lse = _flash_rebuild(*statics, qb, kb, vb, kv_lens, o, lse0)
     return _from_bh(out, b, h), jnp.transpose(lse.reshape(b, h, l), (0, 2, 1))

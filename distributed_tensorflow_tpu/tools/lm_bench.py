@@ -584,7 +584,12 @@ def main(argv=None) -> None:
         return
     overrides = {}
     if args.remat:
-        overrides["remat"] = True if args.remat == "plain" else "selective"
+        # "plain" is the checkpoint that keeps nothing: GPTLM reads True
+        # as "selective" (PR 31), which would make this A/B one program.
+        overrides["remat"] = (
+            jax.checkpoint_policies.nothing_saveable
+            if args.remat == "plain" else "selective"
+        )
     if args.matmul_dtype:
         overrides["matmul_dtype"] = args.matmul_dtype
     rows = run(

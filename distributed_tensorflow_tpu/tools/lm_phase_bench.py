@@ -133,6 +133,12 @@ def bench_phases(
     mkw, b = CONFIGS[name]
     if matmul_dtype:
         mkw = dict(mkw, matmul_dtype=matmul_dtype)
+    if mkw.get("remat") is True:
+        # The rows' own backward stays the checkpoint that keeps nothing
+        # (what remat=True meant when they were first measured), so that
+        # "backward" against "backward-selective" is still an A/B:
+        # since PR 31 GPTLM reads True as "selective".
+        mkw = dict(mkw, remat=jax.checkpoint_policies.nothing_saveable)
     model = GPTLM(vocab_size=_VOCAB, **mkw)
     params = model.init(seed=1)
     opt = optax.adam(1e-3)

@@ -211,14 +211,17 @@ class LMTrainer:
             if getattr(delta_exchange, "metrics", None) is None:
                 delta_exchange.metrics = self.metrics
         self.mode = self._resolve_mode()
-        if self.mode in ("dp", "zero", "tp") and (
-            model.attention_impl == "flash"
+        if self.mode == "tp" or (
+            self.mode in ("dp", "zero") and model.attention_impl == "flash"
         ):
             # These modes run the model's forward as ONE GSPMD program,
             # and the chip's compiler cannot partition a Pallas kernel:
             # tell this trainer's copy of the model how its batch and
             # heads are laid out, so the flash call maps itself per
-            # device (GPTLM._flash_attend).
+            # device (GPTLM._flash_attend). Under tp, whatever the
+            # attention, the head axis also tells the layer's checkpoint
+            # that the attention's output is a sum across chips, worth
+            # keeping (GPTLM._block).
             model = self.model = copy.copy(model)
             model.attention_shard = (
                 mesh, self._dp_axis(),

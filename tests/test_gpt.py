@@ -1346,6 +1346,7 @@ def test_remat_gradients_match_exactly():
         )
 
 
+@pytest.mark.parametrize("remat", [True, "selective"])
 @pytest.mark.parametrize(
     "mkw",
     [
@@ -1356,16 +1357,16 @@ def test_remat_gradients_match_exactly():
     ],
     ids=["dense", "gqa", "window", "moe"],
 )
-def test_selective_remat_gradients_match_plain(mkw):
-    # remat="selective" (save the flash out+lse, recompute only the
-    # layernorm/QKV/MLP half — the rebuild composition in
-    # ops/pallas_attention) must be grad-identical to remat=True for
-    # every block flavor; flash_min_len=0 forces the kernel (and
-    # therefore the named-save path) at toy L.
+def test_selective_remat_gradients_match_plain(mkw, remat):
+    # remat=True and its older spelling "selective" (keep the flash
+    # out+lse, replay only the layernorm/QKV/MLP half — the rebuild
+    # composition in ops/pallas_attention) must give the gradients of
+    # remat=False for every block flavor; flash_min_len=0 forces the
+    # kernel (and therefore the named-save path) at toy L.
     toks = _tokens(np.random.default_rng(52), 2, 16)
     common = dict(attention_impl="flash", flash_min_len=0, **mkw)
-    plain = _model(remat=True, **common)
-    sel = _model(remat="selective", **common)
+    plain = _model(remat=False, **common)
+    sel = _model(remat=remat, **common)
     params = plain.init(seed=52)
     l0, g0 = jax.value_and_grad(plain.loss)(params, toks)
     l1, g1 = jax.value_and_grad(sel.loss)(params, toks)
@@ -1376,12 +1377,14 @@ def test_selective_remat_gradients_match_plain(mkw):
         )
 
 
-def test_selective_remat_skips_flash_forward_recompute():
-    # The policy must actually SAVE work, not just match gradients:
-    # compiled backward FLOPs strictly below plain remat's (the flash
-    # forward is DCE'd from the recompute) and above no-remat's. This is
-    # the pin on the rebuild mechanism — naming the custom-vjp outputs
-    # alone leaves the FLOPs at plain-remat level (measured in round 13).
+@pytest.mark.parametrize("remat", [True, "selective"])
+def test_selective_remat_skips_flash_forward_recompute(remat):
+    # The checkpoint must actually SAVE work, not just match gradients:
+    # compiled backward FLOPs strictly below those of a checkpoint that
+    # keeps nothing (the flash forward is DCE'd from the replay) and
+    # above no-remat's. This is the pin on the rebuild mechanism —
+    # naming the custom-vjp outputs alone leaves the FLOPs at the
+    # keep-nothing level (measured in round 13).
     toks = _tokens(np.random.default_rng(53), 2, 32)
     common = dict(attention_impl="flash", flash_min_len=0, num_layers=2)
 
@@ -1396,8 +1399,10 @@ def test_selective_remat_skips_flash_forward_recompute():
         return ca.get("flops")
 
     f_none = flops(_model(remat=False, **common))
-    f_plain = flops(_model(remat=True, **common))
-    f_sel = flops(_model(remat="selective", **common))
+    f_plain = flops(
+        _model(remat=jax.checkpoint_policies.nothing_saveable, **common)
+    )
+    f_sel = flops(_model(remat=remat, **common))
     if not all(isinstance(f, float) for f in (f_none, f_plain, f_sel)):
         pytest.skip("backend reports no flops")
     assert f_none < f_sel < f_plain, (f_none, f_sel, f_plain)

@@ -104,6 +104,25 @@ def test_remat_changes_no_gradient(remat):
         close(a, b, 1e-5)
 
 
+@pytest.mark.parametrize("remat", [True, "selective"])
+def test_remat_true_keeps_no_named_value(remat, capsys):
+    """This stack's ``remat=True`` is the checkpoint that keeps nothing
+    (its cell fills its chip); GPTLM's keeps the flash kernel's output.
+    "selective" keeps it here too, and shows the probe sees a name."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    cfg = tiny("*")
+    model, tree = build(cfg, attention_impl="flash", remat=remat)
+    model.flash_min_len = 0
+    toks = tokens_for(cfg, length=32)
+    print_saved_residuals(
+        lambda t: model.loss(cell.to_program_params(t), toks), tree)
+    # One line a residual, each with the function that made it; the
+    # kernel's two outputs are made (and named) in this one.
+    kept = capsys.readouterr().out.count("(flash_attention_with_lse)")
+    assert kept == (0 if remat is True else 2)
+
+
 def _scan_inputs(length, seed=0):
     k = jax.random.split(jax.random.key(seed), 6)
     b, h, p, g, n = 2, 4, 8, 2, 16
