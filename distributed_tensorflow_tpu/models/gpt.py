@@ -611,11 +611,19 @@ class GPTLM:
         (``attention_shard`` set by the trainer that owns the mesh) the
         call is mapped per device over the batch and head axes; inside
         an enclosing ``shard_map`` the outputs are typed with the
-        inputs' varying axes."""
+        inputs' varying axes.
+
+        The kernel runs its products in its operands' type, so q, k, v
+        are handed over in ``compute_dtype`` like every other product of
+        the step (they leave ``_dot`` as float32 sums); the output and
+        dq, dk, dv come back in it, the log-sum-exp in float32."""
         from distributed_tensorflow_tpu.ops.pallas_attention import (
             REMAT_SAVE_NAMES,
             flash_attention,
         )
+
+        cd = self.compute_dtype
+        q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
 
         # Under remat, name out+lse so the layer's checkpoint keeps them
         # and the backward's replay skips the O(L²)-work forward kernel

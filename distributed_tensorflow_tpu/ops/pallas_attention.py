@@ -45,9 +45,14 @@ statistics (m, l, lse, delta) are carried as [rows, 1] 2-D columns — 1-D
 vectors trip Mosaic relayout bugs (CLAUDE.md).
 
 Layout: public API takes [B, L, H, D] (matching ``dense_attention`` /
-``ring_attention``); kernels run on [B·H, L, D] with f32 math regardless of
-input dtype. ``interpret=None`` auto-selects the Pallas interpreter
-off-TPU, the Mosaic compiler on TPU (same convention as ops/pallas_mlp.py).
+``ring_attention``); kernels run on [B·H, L, D]. Precision: every product
+runs in its operands' type with float32 accumulation (bfloat16 q, k, v:
+one MXU pass, and o, dq, dk, dv leave in bfloat16; float32 operands:
+float32 products), p and ds are cast to that type for their products, and
+the softmax's statistics (m, l, lse, delta), ``exp``, the accumulators and
+the dq partials are float32 whatever comes in. ``interpret=None``
+auto-selects the Pallas interpreter off-TPU, the Mosaic compiler on TPU
+(same convention as ops/pallas_mlp.py).
 """
 
 from __future__ import annotations

@@ -84,6 +84,32 @@ def test_flash_attention_compiles_at_full_width(chip, which):
     assert has_compiled_kernel(_compile(fn, q, q, q))
 
 
+def test_flash_attention_compiles_at_gpt2_medium_call(chip):
+    """``train-gpt2m``'s call as ``GPTLM`` makes it: bfloat16 operands,
+    8 rows x 16 heads of 1,024 tokens at head_dim 64, causal, blocks of
+    512, forward and fused backward in one program. The full-width cases
+    above prove head_dim 128 only; a 64-wide bfloat16 block is tiled
+    (16, 128) and half-fills its lanes."""
+    q = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16, sharding=chip)
+    text = _compile(
+        jax.value_and_grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=False
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        q, q, q,
+    )
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2, calls
+    fwd, bwd = (ln.split(" custom-call(")[0] for ln in calls)
+    assert "flash_fwd" in fwd and "flash_bwd_fused" in bwd
+    # o in bfloat16 beside a float32 log-sum-exp; float32 dq partials (two
+    # k blocks) beside bfloat16 dk, dv.
+    assert fwd.count("bf16[128,1024,64]") == 1 and "f32[128,1024,1]" in fwd
+    assert bwd.count("bf16[128,1024,64]") == 2 and "f32[2,128,1024,64]" in bwd
+
+
 @pytest.mark.parametrize("which", ["epoch", "per-step"])
 def test_mlp_kernels_compile(chip, which):
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)  # noqa: E731

@@ -1408,6 +1408,229 @@ def test_selective_remat_skips_flash_forward_recompute(remat):
     assert f_none < f_sel < f_plain, (f_none, f_sel, f_plain)
 
 
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (scan bodies, checkpoints, custom-vjp calls, shard_map bodies)."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def _flash_calls(fn, *args):
+    """The flash kernels' launches in ``fn``'s jaxpr by kernel name, each
+    as (operand dtypes, result dtypes), plus for every float32 dq-partial
+    result the dtypes its sum passes through on the way out."""
+    calls, dq_chain = {}, []
+    for jaxpr, eqn in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        name = eqn.params["name"]
+        calls.setdefault(name, []).append((
+            [v.aval.dtype for v in eqn.invars],
+            [v.aval.dtype for v in eqn.outvars],
+        ))
+        if name == "flash_bwd_fused":
+            # the partials' sum over k blocks, then what reads the sum
+            (total,) = [e for e in jaxpr.eqns if eqn.outvars[0] in e.invars]
+            users = [e for e in jaxpr.eqns if total.outvars[0] in e.invars]
+            dq_chain.append([
+                (e.primitive.name, e.outvars[0].aval.dtype)
+                for e in (total, *users)
+            ])
+    return calls, dq_chain
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize(
+    "cd", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"]
+)
+def test_flash_kernels_get_compute_dtype_operands(cd, remat, monkeypatch):
+    # The kernels run their products in their operands' type: GPTLM hands
+    # them q, k, v (and so do) in compute_dtype and gets o, dq, dk, dv
+    # back in it; the log-sum-exp, delta and the dq partials (summed
+    # before the cast) stay float32. The dense branch still gets what
+    # _dot returns: float32.
+    from distributed_tensorflow_tpu.models import gpt as gpt_mod
+
+    f32 = jnp.dtype(jnp.float32)
+    cd = jnp.dtype(cd)
+    toks = _tokens(np.random.default_rng(54), 2, 16)
+    model = _model(
+        compute_dtype=cd, remat=remat, attention_impl="flash", flash_min_len=0
+    )
+    params = model.init(seed=54)
+    calls, dq_chain = _flash_calls(jax.value_and_grad(model.loss), params, toks)
+    assert sorted(calls) == ["flash_bwd_fused", "flash_fwd"], sorted(calls)
+    # remat=True keeps the output: no replayed forward either way.
+    assert calls["flash_fwd"] == [([cd] * 3, [cd, f32])]
+    assert calls["flash_bwd_fused"] == [
+        ([cd] * 4 + [f32, f32], [f32, cd, cd])
+    ]
+    (chain,) = dq_chain
+    assert chain[0] == ("reduce_sum", f32)
+    if cd != f32:  # summed in float32, then cast once
+        assert chain[1:] == [("convert_element_type", cd)]
+
+    seen = []
+    dense = gpt_mod.dense_attention
+
+    def spy(q, k, v, **kw):
+        seen.append((q.dtype, k.dtype, v.dtype))
+        return dense(q, k, v, **kw)
+
+    monkeypatch.setattr(gpt_mod, "dense_attention", spy)
+    jax.make_jaxpr(_model(compute_dtype=cd, remat=remat).loss)(params, toks)
+    assert seen and set(seen) == {(f32, f32, f32)}, seen
+
+
+@pytest.mark.parametrize("who", ["sp_ring", "hybrid"])
+def test_other_flash_callers_pass_what_they_passed(who):
+    # Only GPTLM._flash_attend changed what it hands the kernel: the
+    # sequence-parallel ring passes its own attend (float32 from _dot),
+    # HybridLM casts at its own call.
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    if who == "sp_ring":
+        from jax.sharding import PartitionSpec as P
+
+        from distributed_tensorflow_tpu.parallel import make_mesh
+
+        model = _model(compute_dtype=bf16)
+        params = model.init(seed=55)
+        toks = _tokens(np.random.default_rng(55), 2, 32)
+        mesh = make_mesh((4,), ("seq",), devices=jax.devices()[:4])
+        fn = jax.shard_map(
+            lambda p, t: model.apply_sequence_parallel(
+                p, t, "seq", attention="ring_flash"
+            ),
+            mesh=mesh, in_specs=(P(), P(None, "seq")),
+            out_specs=P(None, "seq"), check_vma=False,
+        )
+        want = f32
+    else:
+        from distributed_tensorflow_tpu.models.hybrid import HybridLM
+
+        model = HybridLM(
+            64, 32, "*", num_heads=4, num_kv_heads=2, head_dim=16,
+            attention_impl="flash", flash_min_len=0,
+        )
+        assert jnp.dtype(model.compute_dtype) == bf16
+        params = model.init(seed=55)
+        toks = _tokens(np.random.default_rng(55), 2, 16)
+        fn = model.loss
+        want = bf16
+    calls, _ = _flash_calls(fn, params, toks)
+    assert calls["flash_fwd"], calls
+    for operands, results in calls["flash_fwd"]:
+        assert operands[:3] == [want] * 3 and results == [want, f32]
+
+
+def _head64_case(seed):
+    """A bfloat16 GPTLM at head_dim 64 with every projection random (init
+    zeroes wo and w_down, which would keep attention out of the loss),
+    and the same model with the kernel swapped for dense_attention on the
+    same bfloat16-rounded q, k, v."""
+    import copy
+
+    from distributed_tensorflow_tpu.ops.ring_attention import dense_attention
+
+    model = _model(
+        model_dim=128, num_heads=2, compute_dtype=jnp.bfloat16,
+        attention_impl="flash", flash_min_len=0,
+    )
+    assert model.head_dim == 64
+    params = model.init(seed=seed)
+    k1, k2 = jax.random.split(jax.random.key(seed))
+    blocks = params.blocks
+    params = params._replace(blocks=blocks._replace(
+        wo=0.05 * jax.random.normal(k1, blocks.wo.shape),
+        w_down=0.05 * jax.random.normal(k2, blocks.w_down.shape),
+    ))
+    oracle = copy.copy(model)
+    cd = model.compute_dtype
+    oracle._flash_attend = lambda q, k, v, kv_lens: dense_attention(
+        q.astype(cd), k.astype(cd), v.astype(cd), causal=True, kv_lens=kv_lens
+    )
+    return model, oracle, params
+
+
+def _assert_bf16_close(got, want):
+    # tests/test_pallas_attention.py's bfloat16 tolerances (2e-2 on
+    # outputs, 5e-2 on gradients, of values of order one), relative to
+    # each leaf's largest value: a model's gradients are not of order one.
+    (l_got, g_got), (l_want, g_want) = got, want
+    np.testing.assert_allclose(float(l_got), float(l_want), rtol=2e-2)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 5e-2 * max(np.abs(b).max(), 1e-30)
+
+
+def _assert_remat_equal(got, want):
+    (l_got, g_got), (l_want, g_want) = got, want
+    np.testing.assert_allclose(float(l_got), float(l_want), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+        )
+
+
+def test_flash_bf16_head64_matches_dense_on_rounded_operands():
+    import copy
+
+    toks = _tokens(np.random.default_rng(56), 2, 32)
+    model, oracle, params = _head64_case(56)
+    kept = copy.copy(model)
+    kept.remat = True
+    want = jax.value_and_grad(oracle.loss)(params, toks)
+    plain = jax.value_and_grad(model.loss)(params, toks)
+    _assert_bf16_close(plain, want)
+    # The attention does reach the loss: its operands' gradients are there.
+    assert float(jnp.abs(plain[1].blocks.wq).max()) > 0
+    _assert_remat_equal(jax.value_and_grad(kept.loss)(params, toks), plain)
+
+
+def test_flash_bf16_head64_under_tensor_parallel_mesh():
+    # What LMTrainer(dp_mode="tp") builds on a 2x2 data x model mesh: the
+    # model told of the mesh through attention_shard, parameters in the
+    # Megatron layout, rows split over `data`. The kernel runs per device
+    # on its own rows and heads, on bfloat16 operands there too.
+    import copy
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_tensorflow_tpu.parallel import make_mesh
+
+    mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+    toks = _tokens(np.random.default_rng(57), 4, 32)
+    model, oracle, params = _head64_case(57)
+    want = jax.value_and_grad(oracle.loss)(params, toks)
+
+    tp = copy.copy(model)
+    tp.attention_shard = (mesh, "data", "model")
+    sharded = jax.device_put(
+        params,
+        jax.tree.map(lambda s: NamedSharding(mesh, s), tp.partition_specs()),
+    )
+    rows = jax.device_put(toks, NamedSharding(mesh, P("data")))
+    kept = copy.copy(tp)
+    kept.remat = True
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    for m in (tp, kept):
+        calls, _ = _flash_calls(jax.value_and_grad(m.loss), sharded, rows)
+        assert calls["flash_fwd"] == [([bf16] * 3, [bf16, f32])]
+        assert calls["flash_bwd_fused"] == [
+            ([bf16] * 4 + [f32, f32], [f32, bf16, bf16])
+        ]
+    plain = jax.jit(jax.value_and_grad(tp.loss))(sharded, rows)
+    _assert_bf16_close(plain, want)
+    _assert_remat_equal(
+        jax.jit(jax.value_and_grad(kept.loss))(sharded, rows), plain
+    )
+
+
 def test_remat_value_validated():
     with pytest.raises(ValueError, match="remat must be"):
         _model(remat="sometimes")
