@@ -17,7 +17,11 @@ points:
 - the hybrid stack (``models.hybrid.HybridLM``: a state-space, an expert
   and an attention layer) through the same ``LMTrainer``: its first
   step's loss against the plain reference
-  (``benchmark/lib/reference_nemotron_h.py``), then a few steps.
+  (``benchmark/lib/reference_nemotron_h.py``), then a few steps;
+- the same stack's later kinds (a gated delta-rule layer, a dense gated
+  feed-forward, latent attention at 192-wide keys and 128-wide values,
+  SiLU-gated experts: one layer of each at d = 2304), the same way against
+  ``benchmark/lib/reference_kimi_linear.py``.
 
 Each phase prints one JSON line: its name, seconds (compile apart from
 run, from ``jax.monitoring``), the compile-cache traffic, and what it
@@ -94,6 +98,20 @@ HYBRID_LM = dict(
     attention_impl="flash", remat=True, balance_rounds=2,
 )
 HYBRID_LEN = 1024
+# One layer of each of the later kinds at the widths the benchmark's
+# delta-rule configuration publishes (d = 2304; 32 delta-rule heads of 128;
+# latent attention at 128 + 64 wide keys and 128 wide values over a latent
+# of 512; a feed-forward of 9216; experts of 1024), few experts, a small
+# vocabulary: 0.2 G parameters.
+DELTA_LM = dict(
+    vocab_size=4096, model_dim=2304, pattern="KDLE",
+    kda_heads=32, kda_head_dim=128, num_heads=32, kv_lora_rank=512,
+    qk_nope_dim=128, qk_shared_dim=64, v_head_dim=128, dense_dim=9216,
+    expert_form="silu_gated", num_experts=32, experts_per_token=8,
+    expert_dim=1024, shared_dim=1024, routed_scale=2.446,
+    experts_held=(8, 8), depth_for_init=54,
+    attention_impl="flash", remat=True, balance_rounds=2,
+)
 # The hybrid step's first loss against the float32 reference: bfloat16
 # matmul operands move a loss near ln(vocabulary) by a few parts in 1e4.
 HYBRID_LOSS_RTOL = 2e-3
@@ -468,18 +486,49 @@ def _reference_dims(model: HybridLM) -> dict:
     }
 
 
+def _delta_reference_dims(model: HybridLM) -> dict:
+    """The sizes benchmark/lib/reference_kimi_linear.py reads, from the
+    model's own attributes."""
+    return {
+        "pattern": model.pattern, "eps": model.norm_eps,
+        "kda_heads": model.kda_heads, "kda_head_dim": model.kda_head_dim,
+        "kda_inner": model.kda_inner, "gate_rank": model.kda_gate_rank,
+        "conv_kernel": model.conv_kernel, "heads": model.num_heads,
+        "kv_rank": model.kv_lora_rank, "nope": model.qk_nope_dim,
+        "shared_k": model.qk_shared_dim, "v_dim": model.v_head_dim,
+        "experts": model.num_experts, "held": model.experts_held,
+        "top_k": model.experts_per_token, "routed_scale": model.routed_scale,
+    }
+
+
+def phase_delta_train(meter: Meter, **kw):
+    """:func:`phase_hybrid_train` over the stack's later kinds (one
+    delta-rule, dense, latent-attention and gated-expert layer) against
+    their own plain reference."""
+    from benchmark.lib import reference_kimi_linear
+
+    kw.setdefault("model_kw", DELTA_LM)
+    return phase_hybrid_train(
+        meter, name="delta_train", reference=reference_kimi_linear,
+        dims_of=_delta_reference_dims, **kw)
+
+
 def phase_hybrid_train(
     meter: Meter, *, compiled_kernels: bool, model_kw: dict = HYBRID_LM,
     seq_len: int = HYBRID_LEN, batch: int = 2, steps: int = 3, seed: int = 0,
     loss_rtol: float = HYBRID_LOSS_RTOL, grad_rtol: float = HYBRID_GRAD_RTOL,
+    name: str = "hybrid_train", reference=None, dims_of=_reference_dims,
 ):
     """The hybrid stack through ``LMTrainer``'s scanned epoch: the loss
     falls, the flash kernel is in the compiled step and the expert layer's
     counters came back with the costs; then one step's loss and gradient
-    on the trainer's own initial weights against the plain reference."""
-    from benchmark.lib import reference_nemotron_h
+    on the trainer's own initial weights against the plain reference
+    (``reference``: ``benchmark/lib/reference_nemotron_h`` unless given,
+    its sizes from ``dims_of``)."""
+    if reference is None:
+        from benchmark.lib import reference_nemotron_h as reference
 
-    with meter.phase("hybrid_train") as out:
+    with meter.phase(name) as out:
         model = HybridLM(**model_kw)
         corpus = copy_corpus(
             num=(steps + 2) * batch, half_len=seq_len // 2,
@@ -515,9 +564,9 @@ def phase_hybrid_train(
         tree = as_dict(start)
         toks = jnp.asarray(corpus.train.tokens[:batch])
         got_loss, got = jax.jit(jax.value_and_grad(model.loss))(start, toks)
-        dims = _reference_dims(model)
+        dims = dims_of(model)
         want_loss, want = jax.jit(jax.value_and_grad(
-            lambda t, x: reference_nemotron_h.loss(
+            lambda t, x: reference.loss(
                 t, x, dims, balance=model.balance_rounds)))(tree, toks)
         gap = abs(float(got_loss) - float(want_loss)) / abs(float(want_loss))
         check(
@@ -869,6 +918,8 @@ def run(chips: int, seed: int) -> dict:
             del params
             gc.collect()
             phase_hybrid_train(meter, compiled_kernels=True, seed=seed)
+            gc.collect()
+            phase_delta_train(meter, compiled_kernels=True, seed=seed)
     finally:
         meter.close()
     return dict(

@@ -80,13 +80,23 @@ MOE_ROUTE = "moe_route"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"
+# A gated delta-rule layer ("K"): its projections (in, out, both low-rank
+# gates and the write strength), its causal depthwise convolutions, its
+# decay and normalisation arithmetic (L2 norms, log-decay, the gated output
+# norm), the chunked scan. Latent attention ("L") runs under the attention
+# scopes, the dense gated feed-forward ("D") under MLP.
+KDA_PROJ = "kda_proj"
+KDA_CONV = "kda_conv"
+KDA_GATE = "kda_gate"
+KDA_SCAN = "kda_scan"
 HYBRID_SCOPES = (
     SSM_PROJ, SSM_CONV, SSM_SCAN, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
     MOE_SHARED,
 )
+KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN)
 SCOPES = MODEL_SCOPES + (
     LOSS, KV_WRITE, KV_GATHER, KV_RESTACK, PICK, OPTIMIZER,
-) + HYBRID_SCOPES
+) + HYBRID_SCOPES + KDA_SCOPES
 
 # -- Pallas kernels -----------------------------------------------------------
 KERNEL_FLASH_FWD = "flash_fwd"

@@ -410,6 +410,21 @@ def relu2_experts(rows, w_up, w_down, group_sizes, compute_dtype):
                           preferred_element_type=jnp.float32)
 
 
+def silu_gated_experts(rows, w_gate, w_up, w_down, group_sizes, compute_dtype):
+    """Each expert's rows through its own ``w_down (silu(w_gate x) *
+    w_up x)`` (the gated form, three matrices): arguments and undefined
+    rows as :func:`relu2_experts`."""
+    cd = compute_dtype
+    rows = rows.astype(cd)
+    gate, up = (
+        lax.ragged_dot(rows, w.astype(cd), group_sizes,
+                       preferred_element_type=jnp.float32)
+        for w in (w_gate, w_up))
+    act = jax.nn.silu(gate) * up
+    return lax.ragged_dot(act.astype(cd), w_down.astype(cd), group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
 def held_block_rows(pairs: int, held: int, experts: int) -> int:
     """Rows of one block of the held experts' row buffer: twice what an
     even routing sends to ``held`` of ``experts``, in whole tiles of 512,
@@ -420,7 +435,7 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
 
 
 def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
-                 scale: float, compute_dtype=jnp.bfloat16,
+                 scale: float, w_gate=None, compute_dtype=jnp.bfloat16,
                  balance: tuple[int, int] | None = None,
                  block_rows: int | None = None):
     """The routed part of a sigmoid-scored, top-``k`` expert layer that
@@ -431,7 +446,9 @@ def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
     int32: the (token, choice) pairs that chose each expert, held or not —
     ``load[first:first + E_held]`` landed here). ``balance`` = (tokens a
     sequence, rounds): the choice is by each sequence's own
-    :func:`balancing_bias`.
+    :func:`balancing_bias`. The expert's form: ``w_down relu(w_up x)^2``
+    (:func:`relu2_experts`), or with ``w_gate`` [E_held, D, F] the gated
+    ``w_down (silu(w_gate x) * w_up x)`` (:func:`silu_gated_experts`).
 
     Dropless, with no capacity: the pairs that landed here, sorted by
     expert, are computed ``block_rows`` at a time (default
@@ -473,7 +490,9 @@ def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
             sizes = (jnp.clip(ends - start, 0, r)
                      - jnp.clip(ends - rows - start, 0, r))
         with jax.named_scope(names.MOE_EXPERTS):
-            y = relu2_experts(taken, w_up, w_down, sizes, compute_dtype)
+            y = (relu2_experts(taken, w_up, w_down, sizes, compute_dtype)
+                 if w_gate is None else silu_gated_experts(
+                     taken, w_gate, w_up, w_down, sizes, compute_dtype))
         with jax.named_scope(names.MOE_DISPATCH):
             y = jnp.where(here[:, None], y, 0.0) * w_flat[ids][:, None]
             return jnp.zeros((t, d), jnp.float32).at[tok].add(y)

@@ -277,13 +277,15 @@ def _fwd_call(
     q, k, v, kv_lens, *, causal, window, offset, bq, bk, scale, interpret,
     vma, hq, hkv
 ):
-    """q [B·Hq, L, D], k/v [B·Hkv, L, D] → (out [B·Hq, L, D], lse
-    [B·Hq, L, 1]). ``kv_lens`` is None or [B] int32 (right-padded
-    key-padding; expanded per query head here). ``vma`` marks the outputs
-    as varying over those mesh axes — required under a ``check_vma=True``
-    shard_map (the ring composition)."""
+    """q [B·Hq, L, D], k [B·Hkv, L, D], v [B·Hkv, L, Dv] → (out [B·Hq, L,
+    Dv], lse [B·Hq, L, 1]); the values' head size is free of the keys'.
+    ``kv_lens`` is None or [B] int32 (right-padded key-padding; expanded
+    per query head here). ``vma`` marks the outputs as varying over those
+    mesh axes — required under a ``check_vma=True`` shard_map (the ring
+    composition)."""
     sds = partial(jax.ShapeDtypeStruct, vma=vma) if vma else jax.ShapeDtypeStruct
     bh, l, d = q.shape
+    dv = v.shape[-1]
     nq, nk = l // bq, l // bk
     row = _kv_row(hq, hkv)
     kmap = (
@@ -296,7 +298,7 @@ def _fwd_call(
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
         pl.BlockSpec((1, bk, d), kmap),
-        pl.BlockSpec((1, bk, d), kmap),
+        pl.BlockSpec((1, bk, dv), kmap),
     ]
     inputs = [q, k, v]
     if has_lens:
@@ -311,17 +313,17 @@ def _fwd_call(
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, iq, ik: (b, iq, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, iq, ik: (b, iq, 0)),
         ),
         out_shape=(
-            sds((bh, l, d), q.dtype),
+            sds((bh, l, dv), q.dtype),
             sds((bh, l, 1), jnp.float32),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
         name=names.KERNEL_FLASH_FWD,
@@ -550,6 +552,7 @@ def _bwd_call(
 ):
     sds = partial(jax.ShapeDtypeStruct, vma=vma) if vma else jax.ShapeDtypeStruct
     bh, l, d = q.shape
+    dv = v.shape[-1]  # do, o and dv share the values' head size
     bhkv = k.shape[0]
     g = hq // hkv
     nq, nk = l // bq, l // bk
@@ -578,9 +581,11 @@ def _bwd_call(
             return (qrow(b, j), j % nq, 0)
 
     qspec2, rowspec2 = _qrow_specs(bq, d, qmap2)
+    dospec2, _ = _qrow_specs(bq, dv, qmap2)
     kspec2 = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0))
+    vspec2 = pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, i, 0))
     kv_inputs = [q, k, v, do, lse, delta]
-    kv_specs = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
+    kv_specs = [qspec2, kspec2, vspec2, dospec2, rowspec2, rowspec2]
     if has_lens:
         # k-major grid: b indexes B·Hkv rows.
         kv_inputs.append(jnp.repeat(kv_lens.astype(jnp.int32), hkv)[:, None])
@@ -603,15 +608,15 @@ def _bwd_call(
             ),
             grid=(bhkv, nk, nq * g),
             in_specs=kv_specs,
-            out_specs=(dqp_spec, kspec2, kspec2),
+            out_specs=(dqp_spec, kspec2, vspec2),
             out_shape=(
                 sds((nk, bh, l, d), jnp.float32),
                 sds((bhkv, l, d), k.dtype),
-                sds((bhkv, l, d), v.dtype),
+                sds((bhkv, l, dv), v.dtype),
             ),
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dv), jnp.float32),
             ],
             interpret=interpret,
             name=names.KERNEL_FLASH_BWD_FUSED,
@@ -627,9 +632,11 @@ def _bwd_call(
         else (lambda b, i, j: (row(b), j, 0))
     )
     qspec, rowspec = _qrow_specs(bq, d, lambda b, i, j: (b, i, 0))
+    dospec, _ = _qrow_specs(bq, dv, lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, bk, d), kmap)
+    vspec = pl.BlockSpec((1, bk, dv), kmap)
     dq_inputs = [q, k, v, do, lse, delta]
-    dq_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    dq_specs = [qspec, kspec, vspec, dospec, rowspec, rowspec]
     if has_lens:
         dq_inputs.append(jnp.repeat(kv_lens.astype(jnp.int32), hq)[:, None])
         dq_specs.append(lens_spec)
@@ -656,14 +663,14 @@ def _bwd_call(
         ),
         grid=(bhkv, nk, nq * g),
         in_specs=kv_specs,
-        out_specs=(kspec2, kspec2),
+        out_specs=(kspec2, vspec2),
         out_shape=(
             sds((bhkv, l, d), k.dtype),
-            sds((bhkv, l, d), v.dtype),
+            sds((bhkv, l, dv), v.dtype),
         ),
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
         name=names.KERNEL_FLASH_BWD_DKV,
@@ -859,6 +866,12 @@ def flash_attention(
     query sees only its last W keys (self included), and block pairs wholly
     outside the band are skipped — compute scales O(L·W) instead of O(L²).
 
+    ``v`` may have a head size of its own (latent attention trains with
+    q and k at 192 and v at 128): o, and dv in the backward, take it; the
+    scale is q's and k's. A head size that is no multiple of the 128 lanes
+    is never padded in HBM: a block spans the array's whole last
+    dimension and the compiler pads its tile in VMEM.
+
     Grouped-query attention: k/v may carry fewer heads than q (``Hq`` a
     multiple of ``Hkv``); each group of ``Hq/Hkv`` query heads reads one KV
     head via the grid index maps (no materialized repeat), and dk/dv
@@ -940,8 +953,9 @@ def flash_attention_with_lse(
     forward kernel entirely. Without an enclosing policy the naming is
     inert and the math/gradients are unchanged (pinned in
     tests/test_gpt.py selective-remat grad-identity tests)."""
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes must match: {k.shape} {v.shape}")
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"k/v must match in batch, length and heads: {k.shape} {v.shape}")
     if (
         q.shape[0] != k.shape[0]
         or q.shape[1] != k.shape[1]
@@ -998,11 +1012,12 @@ def flash_attention_with_lse(
         # The output is named in a shape whose rows fill the chip's 128
         # lanes: held as the kernel writes it, a head_dim of 64 is padded
         # to twice its bytes in every layer's kept copy.
-        fold = _LANES // d if _LANES % d == 0 else 1
+        dv = o.shape[-1]
+        fold = _LANES // dv if _LANES % dv == 0 else 1
         if l % fold:
             fold = 1
         o = checkpoint_name(
-            o.reshape(-1, d * fold), save_names[0]
+            o.reshape(-1, dv * fold), save_names[0]
         ).reshape(o.shape)
         lse0 = checkpoint_name(lse0, save_names[1])
         out, lse = _flash_rebuild(*statics, qb, kb, vb, kv_lens, o, lse0)

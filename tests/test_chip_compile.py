@@ -110,6 +110,36 @@ def test_flash_attention_compiles_at_gpt2_medium_call(chip):
     assert bwd.count("bf16[128,1024,64]") == 2 and "f32[2,128,1024,64]" in bwd
 
 
+def test_flash_attention_compiles_at_two_head_sizes(chip):
+    """Latent attention's call as ``HybridLM`` makes it in the 8k cell:
+    bfloat16, 2 rows x 32 heads of 8,192 tokens, q and k 192 wide (not a
+    multiple of the 128 lanes: a block spans the whole last dimension and
+    is padded in VMEM only), v 128 wide, blocks of 1,024. The dq partials
+    of a fused backward would be 3.2 GB here, so the backward is the
+    two-kernel split; o and dv are 128 wide, dq and dk 192."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=chip)
+    text = _compile(
+        jax.value_and_grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=False
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        q, q, v,
+    )
+    lines = [ln.split(" custom-call(")[0] for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(lines) == 3, lines
+    calls = {name: ln for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+             for ln in lines if name in ln.split(" = ")[0]}
+    assert len(calls) == 3, lines
+    assert "bf16[64,8192,128]" in calls["flash_fwd"]
+    assert "bf16[64,8192,192]" in calls["flash_bwd_dq"]
+    assert ("bf16[64,8192,192]" in calls["flash_bwd_dkv"]
+            and "bf16[64,8192,128]" in calls["flash_bwd_dkv"])
+
+
 @pytest.mark.parametrize("which", ["epoch", "per-step"])
 def test_mlp_kernels_compile(chip, which):
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)  # noqa: E731

@@ -152,6 +152,28 @@ def test_hybrid_train_phase(smoke, meter, capsys):
     assert 0 < line["gauges"]["moe_rows_per_step"] <= 4 * 32 * 2
 
 
+def test_delta_train_phase(smoke, meter, capsys):
+    smoke.phase_delta_train(
+        meter, compiled_kernels=False,
+        model_kw=dict(
+            vocab_size=64, model_dim=32, pattern="KDLE", kda_heads=4,
+            kda_head_dim=8, kda_gate_rank=4, num_heads=4,
+            kv_lora_rank=12, qk_nope_dim=8, qk_shared_dim=4, v_head_dim=8,
+            dense_dim=48, expert_form="silu_gated", num_experts=8,
+            experts_per_token=2, expert_dim=16, shared_dim=16,
+            routed_scale=2.446, experts_held=(2, 4), attention_impl="flash",
+            flash_min_len=0, remat=True, compute_dtype=jnp.float32,
+        ),
+        seq_len=32, batch=4, steps=3, loss_rtol=1e-5, grad_rtol=1e-3,
+    )
+    (line,) = _lines(capsys)
+    assert line["phase"] == "delta_train" and line["passed"]
+    assert line["last_loss"] < line["first_loss"]
+    assert line["loss_gap"] <= 1e-5 and line["gradient_rel_err"] <= 1e-3
+    assert line["model"]["pattern"] == "KDLE"
+    assert 0 < line["gauges"]["moe_rows_per_step"] <= 4 * 32 * 2
+
+
 def test_compare_streams_names_the_first_divergence(smoke):
     """A stream that differs from its reference beyond a near-tie fails
     with the position and both tokens; equal streams pass silently."""

@@ -265,6 +265,59 @@ def test_hybrid_train_step_program_carries_every_scope():
     assert names.MLP not in got["forward"]  # no attention + FFN pair here
 
 
+KDA_SCOPES = set(names.KDA_SCOPES)
+
+
+def test_delta_rule_train_step_program_carries_every_scope():
+    """The later kinds of models/hybrid.py through the same trainer: a
+    delta-rule layer's four sections, latent attention under the attention
+    scopes, the dense gated feed-forward under ``mlp``, the gated experts
+    under the ``moe_*`` scopes, in the forward pass, the backward pass and
+    remat's replay of each layer."""
+    from distributed_tensorflow_tpu.config import TrainConfig
+    from distributed_tensorflow_tpu.data.tokens import copy_corpus
+    from distributed_tensorflow_tpu.models.hybrid import HybridLM
+    from distributed_tensorflow_tpu.train.lm_trainer import LMTrainer
+
+    model = HybridLM(
+        61, 32, "KDLE", kda_heads=4, kda_head_dim=8, kda_gate_rank=4,
+        num_heads=4, kv_lora_rank=12, qk_nope_dim=8,
+        qk_shared_dim=4, v_head_dim=8, dense_dim=48, expert_form="silu_gated",
+        num_experts=8, experts_per_token=2, expert_dim=16, shared_dim=16,
+        routed_scale=2.446, experts_held=(2, 4), compute_dtype=jnp.float32,
+        attention_impl="flash", flash_min_len=128, remat=True,
+    )
+    trainer = LMTrainer(
+        model,
+        copy_corpus(num=12, half_len=64, vocab=61, n_val=4, n_test=4, seed=0),
+        TrainConfig(epochs=1, batch_size=2, optimizer="adam", scan_epoch=True,
+                    logs_path=""),
+        print_fn=lambda *a: None,
+    )
+    train = trainer.datasets.train
+    steps = train.num_examples // trainer.config.batch_size
+    compiled = trainer._build_scanned_fn().lower(
+        trainer.state,
+        trainer._stage("train_tokens", train.tokens),
+        trainer._train_lens(),
+        trainer._replicated(
+            trainer._epoch_indices(steps, trainer.config.batch_size)
+        ),
+    ).compile()
+    got = _scopes_by_phase(compiled)
+    experts = {names.MOE_ROUTE, names.MOE_DISPATCH, names.MOE_EXPERTS,
+               names.MOE_SHARED}
+    attention = {names.ATTN_QKV, names.ATTN_CORE, names.ATTN_OUT}
+    every = KDA_SCOPES | experts | attention | {
+        names.MLP, names.EMBED, names.LM_HEAD, names.LOSS}
+    assert got["forward"] >= every | {names.OPTIMIZER}, every - got["forward"]
+    assert got["backward"] >= every, every - got["backward"]
+    replayed = KDA_SCOPES | experts | {names.ATTN_QKV, names.ATTN_CORE,
+                                       names.MLP}
+    assert got["recompute"] >= replayed, replayed - got["recompute"]
+    assert not {names.SSM_PROJ, names.SSM_SCAN} & got["forward"]
+
+
 def _server(paged: bool, **kw):
     m = _tiny_model()
     kw.setdefault("slots", 2)
@@ -335,6 +388,7 @@ def test_serving_program_carries_every_scope(case):
 def test_every_scope_and_program_is_held_by_some_case():
     held = MODEL_SCOPES | {names.LOSS, names.OPTIMIZER} | SERVING_SCOPES
     held |= HYBRID_SCOPES  # test_hybrid_train_step_program_carries_every_scope
+    held |= KDA_SCOPES  # test_delta_rule_train_step_program_carries_every_scope
     for _, _, extra in PROGRAM_CASES.values():
         held |= extra
     assert held == set(names.SCOPES)
