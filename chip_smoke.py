@@ -65,6 +65,7 @@ from distributed_tensorflow_tpu.launch import build_trainer
 from distributed_tensorflow_tpu.models import MLP
 from distributed_tensorflow_tpu.models.gpt import GPTLM
 from distributed_tensorflow_tpu.models.hybrid import HybridLM
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops import cross_entropy, sgd
 from distributed_tensorflow_tpu.ops.pallas_mlp import (
     make_fused_epoch_fn,
@@ -510,7 +511,9 @@ def phase_delta_train(meter: Meter, **kw):
     kw.setdefault("model_kw", DELTA_LM)
     return phase_hybrid_train(
         meter, name="delta_train", reference=reference_kimi_linear,
-        dims_of=_delta_reference_dims, **kw)
+        dims_of=_delta_reference_dims,
+        kernels=(names.KERNEL_KDA_SCORES_FWD, names.KERNEL_KDA_SCORES_BWD),
+        **kw)
 
 
 def phase_hybrid_train(
@@ -518,13 +521,15 @@ def phase_hybrid_train(
     seq_len: int = HYBRID_LEN, batch: int = 2, steps: int = 3, seed: int = 0,
     loss_rtol: float = HYBRID_LOSS_RTOL, grad_rtol: float = HYBRID_GRAD_RTOL,
     name: str = "hybrid_train", reference=None, dims_of=_reference_dims,
+    kernels: tuple = (),
 ):
     """The hybrid stack through ``LMTrainer``'s scanned epoch: the loss
-    falls, the flash kernel is in the compiled step and the expert layer's
-    counters came back with the costs; then one step's loss and gradient
-    on the trainer's own initial weights against the plain reference
-    (``reference``: ``benchmark/lib/reference_nemotron_h`` unless given,
-    its sizes from ``dims_of``)."""
+    falls, the flash kernel and the ``kernels`` named are in the compiled
+    step and the expert layer's counters came back with the costs; then
+    one step's loss and gradient on the trainer's own initial weights
+    against the plain reference (``reference``:
+    ``benchmark/lib/reference_nemotron_h`` unless given, its sizes from
+    ``dims_of``)."""
     if reference is None:
         from benchmark.lib import reference_nemotron_h as reference
 
@@ -584,12 +589,17 @@ def phase_hybrid_train(
             f"hybrid gradient {grad_err:.2e} from the reference's over the "
             f"whole tree, allowed {grad_rtol:.0e}",
         )
-        flash = has_compiled_kernel(
-            _scanned_epoch_program(trainer, steps).as_text())
+        text = _scanned_epoch_program(trainer, steps).as_text()
+        flash = has_compiled_kernel(text)
         check(
             flash == compiled_kernels,
             f"flash kernel in the compiled hybrid step: {flash}, expected "
             f"{compiled_kernels}",
+        )
+        named = sorted(k for k in kernels if f'kernel_name = "{k}"' in text)
+        check(
+            named == (sorted(kernels) if compiled_kernels else []),
+            f"of the kernels {kernels} the compiled step calls {named}",
         )
         gauges = {g.name: g.value for g in trainer.metrics
                   if g.name.startswith("moe_")}
@@ -607,7 +617,8 @@ def phase_hybrid_train(
             first_loss=costs[0], last_loss=costs[-1],
             loss=float(got_loss), reference_loss=float(want_loss),
             loss_gap=gap, gradient_rel_err=grad_err,
-            flash_kernel_compiled=flash, gauges=gauges,
+            flash_kernel_compiled=flash, kernels_compiled=named,
+            gauges=gauges,
         )
 
 

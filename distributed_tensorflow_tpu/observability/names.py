@@ -105,9 +105,13 @@ KERNEL_FLASH_BWD_DQ = "flash_bwd_dq"
 KERNEL_FLASH_BWD_DKV = "flash_bwd_dkv"
 KERNEL_MLP_TRAIN_STEP = "mlp_train_step"
 KERNEL_MLP_TRAIN_EPOCH = "mlp_train_epoch"
+# The delta rule's decayed scores inside a chunk (ops/kda.py).
+KERNEL_KDA_SCORES_FWD = "kda_scores_fwd"
+KERNEL_KDA_SCORES_BWD = "kda_scores_bwd"
 KERNELS = (
     KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_FUSED, KERNEL_FLASH_BWD_DQ,
     KERNEL_FLASH_BWD_DKV, KERNEL_MLP_TRAIN_STEP, KERNEL_MLP_TRAIN_EPOCH,
+    KERNEL_KDA_SCORES_FWD, KERNEL_KDA_SCORES_BWD,
 )
 
 

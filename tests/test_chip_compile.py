@@ -140,6 +140,39 @@ def test_flash_attention_compiles_at_two_head_sizes(chip):
             and "bf16[64,8192,128]" in calls["flash_bwd_dkv"])
 
 
+def test_delta_rule_scores_compile_as_the_kernel_pair(chip, monkeypatch):
+    """``train-kimilinear-share32``'s call of the chunked delta rule as
+    ``HybridLM._kda`` makes it (2 rows x 8,192 tokens x 32 heads x 128
+    channels, float32, products at the highest precision), value and
+    gradient: the decayed scores of a scan step's 512 (chunk, row, head)
+    units are the two Mosaic kernels, and the sub-block decay tensor that
+    autodiff used to write out and read back is nowhere in the program.
+    ``kda_chunked`` takes no ``interpret``; the kernels follow the backend,
+    which is the CPU here, so the test says what the chip would."""
+    from distributed_tensorflow_tpu.observability import names
+    from distributed_tensorflow_tpu.ops import pallas_mode
+    from distributed_tensorflow_tpu.ops.kda import kda_chunked
+
+    monkeypatch.setattr(pallas_mode, "default_interpret", lambda: False)
+    t = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.float32, sharding=chip)
+    beta = jax.ShapeDtypeStruct((2, 8192, 32), jnp.float32, sharding=chip)
+    text = _compile(
+        jax.value_and_grad(
+            lambda *a: kda_chunked(
+                *a, precision=jax.lax.Precision.HIGHEST).sum(),
+            argnums=(0, 1, 2, 3, 4),
+        ),
+        t, t, t, t, beta,
+    )
+    assert has_compiled_kernel(text)
+    calls = [ln.split(" custom-call(")[0].split(" = ")[0]
+             for ln in text.splitlines() if "tpu_custom_call" in ln]
+    # the forward scan's, the replay's inside the backward scan, the backward
+    assert sum(names.KERNEL_KDA_SCORES_FWD in c for c in calls) == 2, calls
+    assert sum(names.KERNEL_KDA_SCORES_BWD in c for c in calls) == 1, calls
+    assert "f32[8,2,32,4,16,16,128]" not in text
+
+
 @pytest.mark.parametrize("which", ["epoch", "per-step"])
 def test_mlp_kernels_compile(chip, which):
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)  # noqa: E731

@@ -1,12 +1,15 @@
 """The gated delta rule with a per-channel decay (ops/kda.py): the chunked
 form against the recurrence token by token, values and the gradients to
-all five inputs, at float32 ``highest``."""
+all five inputs, at float32 ``highest``; the kernel pair that computes a
+chunk's decayed scores (interpreted here) against the ``jax.numpy`` form
+it stands in for."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops import kda
 from distributed_tensorflow_tpu.ops.kda import kda_chunked, kda_sequential
 
@@ -140,3 +143,88 @@ def test_the_unit_lower_inverse_is_the_inverse():
 def test_a_sub_block_must_divide_the_chunk():
     with pytest.raises(ValueError, match="sub-block"):
         kda_chunked(*inputs(24, 1.0), chunk=20)  # SUB is 8 here
+
+
+# -- the decayed scores as a kernel pair ----------------------------------------
+# The cases above stay on the jax.numpy path (16-wide heads); these run the
+# kernels, interpreted, at the widths they tile and the module's own
+# constants.
+
+CHUNK, WIDTH = 64, 128
+
+
+@pytest.fixture
+def real_blocks(monkeypatch):
+    monkeypatch.setattr(kda, "SUB", 16)
+    monkeypatch.setattr(kda, "CHUNKS_PER_STEP", 8)
+
+
+def score_inputs(strength, per=2, b=1, h=3):
+    """x = (k, q), k and the cumulative log-decay as ``_within_chunks``
+    hands them over: [2, per, B, H, 64, 128] beside two [per, B, H, 64,
+    128]."""
+    q, k, _, g, _ = inputs(CHUNK, strength, b=per * b, h=h, width=WIDTH)
+    by_unit = lambda t: t.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        per, b, h, CHUNK, WIDTH)
+    q, k, g = by_unit(q), by_unit(k), by_unit(g)
+    return jnp.stack([k, q]), k, jnp.cumsum(g, axis=-2)
+
+
+@pytest.mark.parametrize("strength", [0.05, 16.0], ids=["mild", "strongest"])
+def test_the_kernel_pair_equals_the_jax_numpy_scores(strength, real_blocks):
+    x, k, cum = score_inputs(strength)
+    if strength == 16.0:
+        assert float(cum.min()) < -88.0
+    oracle = lambda *a: kda._decayed_scores(*a, kda.SUB, HI)  # noqa: E731
+    kernels = lambda *a: kda._decayed_scores_kernels(*a, kda.SUB)  # noqa: E731
+    want = oracle(x, k, cum)
+    close(jax.jit(kernels)(x, k, cum), want, 1e-6)
+    weight = jax.random.normal(jax.random.key(9), want.shape)
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * weight), argnums=(0, 1, 2)))(x, k, cum)
+    for name, got, ref in zip(("x", "k", "cum"), grad(kernels), grad(oracle)):
+        try:
+            close(got, ref, 2e-6)
+        except AssertionError as e:
+            raise AssertionError(f"gradient to {name}: {e}") from None
+
+
+def kernel_names(fn, *args):
+    """The Pallas kernels a function's jaxpr calls, by name."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    return {n for n in (names.KERNEL_KDA_SCORES_FWD, names.KERNEL_KDA_SCORES_BWD)
+            if f"name={n}" in text}
+
+
+def test_chunked_with_the_kernels_equals_the_recurrence(real_blocks):
+    """128-wide heads, the module's own chunk, sub-block and step: the
+    kernels are in the program, forward and backward, and values and all
+    five gradients are the token-by-token recurrence's."""
+    args = inputs(200, 16.0, b=1, h=2, width=WIDTH, v_width=WIDTH)
+    assert float(jnp.cumsum(args[3][:, :CHUNK], axis=1).min()) < -88.0
+    run = lambda *a: kda_chunked(*a, precision=HI)  # noqa: E731
+    want = kda_sequential(*args, precision=HI)
+    weight = jax.random.normal(jax.random.key(9), want.shape)
+    loss = lambda f: (lambda *a: jnp.sum(f(*a) * weight))  # noqa: E731
+    grad = lambda f: jax.grad(loss(f), argnums=(0, 1, 2, 3, 4))  # noqa: E731
+    assert kernel_names(run, *args) == {names.KERNEL_KDA_SCORES_FWD}
+    assert kernel_names(grad(run), *args) == {
+        names.KERNEL_KDA_SCORES_FWD, names.KERNEL_KDA_SCORES_BWD}
+    close(jax.jit(run)(*args), want, 2e-5)
+    for name, got, ref in zip(NAMES, jax.jit(grad(run))(*args), grad(
+            lambda *a: kda_sequential(*a, precision=HI))(*args)):
+        try:
+            close(got, ref, 1e-4)
+        except AssertionError as e:
+            raise AssertionError(f"gradient to {name}: {e}") from None
+
+
+@pytest.mark.parametrize("width,dtype", [(64, "float32"), (128, "bfloat16")],
+                         ids=["narrow", "not-float32"])
+def test_what_the_kernels_do_not_tile_takes_the_jax_numpy_path(width, dtype):
+    x, k, cum = (jnp.ones(shape, dtype) for shape in (
+        (2, 1, 1, 2, 32, width), (1, 1, 2, 32, width), (1, 1, 2, 32, width)))
+    assert not kernel_names(
+        lambda *a: kda._scores(*a, kda.SUB, HI), x, k, cum)
+    assert kernel_names(lambda *a: kda._scores(*a, kda.SUB, HI), *(
+        jnp.ones((*t.shape[:-1], WIDTH), "float32") for t in (x, k, cum)))
