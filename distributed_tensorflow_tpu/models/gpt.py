@@ -163,7 +163,14 @@ class PagedKVCache(NamedTuple):
     them unchanged, and COW prefix sharing shares a block's scales with
     the block (one refcount covers both; scales are never packed into
     the payload). ``kv_dtype="bf16"`` keeps scales ``None``: the
-    round-11 bitwise path."""
+    round-11 bitwise path.
+
+    A model with window layers (:class:`~.hybrid.HybridLM`) keeps their
+    K/V out of the pool, in a ring of blocks per slot (``ring_k`` /
+    ``ring_v``, ``ops/paged_attention`` says how it is addressed); the
+    pool and its tables then hold the full-attention layers alone. An
+    extend over fewer rows than there are slots names each row's slot in
+    ``slot_of_row`` (None: row ``s`` is slot ``s``)."""
 
     k: jax.Array  # [num_layers, num_blocks, block_size, Hkv, Dh]
     v: jax.Array  # [num_layers, num_blocks, block_size, Hkv, Dh]
@@ -171,6 +178,9 @@ class PagedKVCache(NamedTuple):
     lengths: jax.Array  # [S] int32 — tokens written for each slot
     k_scale: jax.Array | None = None  # [num_layers, num_blocks, bs, Hkv] f32
     v_scale: jax.Array | None = None
+    ring_k: jax.Array | None = None  # [window layers, S*ring, bs, Hkv, Dh]
+    ring_v: jax.Array | None = None
+    slot_of_row: jax.Array | None = None  # [rows] int32
 
 
 class KVCache(NamedTuple):

@@ -6,7 +6,7 @@ The residual stream ``h`` runs through ``len(pattern)`` layers, each ONE
 mixer behind one pre-norm — ``h <- h + mixer(RMSNorm(h))``; a block that
 pairs a token mixer with a feed-forward is two letters, there is no pair
 in the code — then a final RMSNorm and an untied head.
-The pattern is a string over six letters:
+The pattern is a string over eight letters:
 
 - ``M`` — a Mamba-2 mixer: ``[z | xBC | dt] = in_proj(u)``; ``xBC`` through
   a causal depthwise convolution (with bias) and silu; split into ``x``
@@ -47,14 +47,22 @@ The pattern is a string over six letters:
   (the flash kernels take the two head sizes as they are); ``wo``.
 - ``D`` — a dense gated feed-forward: ``w_down (silu(w_gate u) * w_up u)``,
   ``dense_dim`` wide.
+- ``G`` and ``W`` — gated attention over positions, full (``G``) or in a
+  window of the last ``window`` keys (``W``): ``full_heads`` or
+  ``window_heads`` query heads over the shared ``num_kv_heads`` x
+  ``head_dim``, q and k turned by the kind's :class:`~..ops.rotary.Rotary`
+  (``full_rotary``, ``window_rotary``: a base, the leading dims that turn,
+  YaRN where given), scores scaled by ``head_dim^-1/2``, causal; each
+  head's output times ``sigmoid(u W_gate)`` of that head (``u`` the
+  layer's normed input) before ``wo``. No bias, no norm on q or k.
 
 Parameters are stacked per kind (``mamba[n_M]``, ``moe[n_E]``,
-``attn[n_*]``, ``kda[n_K]``, ``mla[n_L]``, ``dense[n_D]``) and the layers
-run unrolled in pattern order, each taking its kind's next slice. A
-pattern over ``M``, ``E``, ``*`` alone keeps the tree it always had
-(:class:`HybridLMParams`); one that uses ``K``, ``L`` or ``D`` has
-:class:`StackLMParams`. Matmul operands are ``compute_dtype`` with float32
-accumulation; norms, the router, the convolutions, both recurrences (their
+``attn[n_*]``, ``kda[n_K]``, ``mla[n_L]``, ``dense[n_D]``, ``full[n_G]``,
+``window[n_W]``) and the layers run unrolled in pattern order, each taking
+its kind's next slice. A pattern over ``M``, ``E``, ``*`` alone keeps the
+tree it always had (:class:`HybridLMParams`); one that uses ``K``, ``L``,
+``D``, ``G`` or ``W`` has :class:`StackLMParams`. Matmul operands are
+``compute_dtype`` with float32 accumulation; norms, the router, the convolutions, both recurrences (their
 decays, states and solves) and the loss are float32. ``remat`` checkpoints
 each layer through the same policy surface as
 :class:`~distributed_tensorflow_tpu.models.gpt.GPTLM`.
@@ -71,6 +79,16 @@ that many rounds of an auction
 (:func:`~distributed_tensorflow_tpu.ops.moe.balancing_bias`): the balancing
 a training recipe holds the load even with. Without it (None, the
 default) the buffer is used as it stands.
+
+A pattern over ``G``, ``W``, ``D`` and ``E`` is also served by
+:class:`~distributed_tensorflow_tpu.serve.TextServer` through the paged
+interface :class:`~..gpt.GPTLM` gives it (``empty_paged_cache``,
+``extend_paged``, ``decode_paged``, ``paged_blocks_per_slot``,
+``max_len``, ``num_layers``, ``num_kv_heads``, ``head_dim``): the ``G``
+layers' K/V in the server's block pool, the ``W`` layers' in a ring of
+``ring_blocks`` blocks per slot (``ops/paged_attention``). The prefill
+reads no cached prefix and returns the logits of each row's last
+position only; the experts hold every expert and drop nothing.
 """
 
 from __future__ import annotations
@@ -81,18 +99,23 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_tpu.models.gpt import GPTLM, _ce_from_logits
+from distributed_tensorflow_tpu.models.gpt import (
+    GPTLM, PagedKVCache, _ce_from_logits)
 from distributed_tensorflow_tpu.observability import names
+from distributed_tensorflow_tpu.ops import paged_attention as paged
+from distributed_tensorflow_tpu.ops import rotary
 from distributed_tensorflow_tpu.ops.kda import kda_chunked
 from distributed_tensorflow_tpu.ops.moe import moe_ffn_held
 from distributed_tensorflow_tpu.ops.ring_attention import dense_attention
 from distributed_tensorflow_tpu.ops.ssd import ssd_chunked
 
 KINDS = ("M", "E", "*")
-WIDE_KINDS = ("K", "L", "D")  # a pattern that uses one has StackLMParams
+# a pattern that uses one of these has StackLMParams
+WIDE_KINDS = ("K", "L", "D", "G", "W")
 GROUP_OF = {"M": "mamba", "E": "moe", "*": "attn",
-            "K": "kda", "L": "mla", "D": "dense"}
+            "K": "kda", "L": "mla", "D": "dense", "G": "full", "W": "window"}
 EXPERT_FORMS = ("relu2", "silu_gated")
+SERVED_KINDS = ("G", "W", "D", "E")  # what the paged serving path runs
 
 
 class MambaParams(NamedTuple):
@@ -167,6 +190,18 @@ class AttnParams(NamedTuple):
     wo: jax.Array  # [n, heads*head_dim, d]
 
 
+class GatedAttnParams(NamedTuple):
+    """``G`` and ``W``: grouped-query attention whose heads are each
+    scaled by a gate of the layer's input before ``wo``."""
+
+    norm: jax.Array  # [n, d]
+    wq: jax.Array  # [n, d, heads*head_dim]
+    wk: jax.Array  # [n, d, kv_heads*head_dim]
+    wv: jax.Array  # [n, d, kv_heads*head_dim]
+    w_gate: jax.Array  # [n, d, heads]
+    wo: jax.Array  # [n, heads*head_dim, d]
+
+
 class HybridLMParams(NamedTuple):
     embed: jax.Array  # [vocab, d]
     mamba: MambaParams
@@ -177,9 +212,9 @@ class HybridLMParams(NamedTuple):
 
 
 class StackLMParams(NamedTuple):
-    """The tree of a pattern that uses ``K``, ``L`` or ``D``: a stack for
-    each of the six kinds (``moe`` in the form the model was built with),
-    None for a kind the pattern lacks."""
+    """The tree of a pattern that uses ``K``, ``L``, ``D``, ``G`` or
+    ``W``: a stack for each kind (``moe`` in the form the model was built
+    with), None for a kind the pattern lacks."""
 
     embed: jax.Array  # [vocab, d]
     mamba: MambaParams | None
@@ -190,6 +225,8 @@ class StackLMParams(NamedTuple):
     dense: DenseParams | None
     norm_f: jax.Array  # [d]
     head: jax.Array  # [d, vocab]
+    full: GatedAttnParams | None = None
+    window: GatedAttnParams | None = None
 
 
 def rmsnorm(x, weight, eps):
@@ -211,12 +248,22 @@ _unstack.defvjp(lambda x: (_unstack(x), None),
 
 def _layers_of(stack):
     """A per-kind stack as a list of per-layer parameter tuples (none for
-    None, or for ``HybridLMParams``'s stack of no layers)."""
-    if stack is None or not stack[0].shape[0]:
+    None, or for ``HybridLMParams``'s stack of no layers). A leaf may also
+    come as a tuple of per-layer arrays, as a server holds weights it must
+    not copy (a compiled loop that slices a stacked leaf copies the slice:
+    an expert layer's matrices are 1.6 GB at 256 experts)."""
+    if stack is None or not len(stack[0]):
         return []
-    n = stack[0].shape[0]
-    leaves = [_unstack(leaf) for leaf in stack]
+    n = len(stack[0])
+    leaves = [leaf if isinstance(leaf, tuple) else _unstack(leaf)
+              for leaf in stack]
     return [type(stack)(*(leaf[i] for leaf in leaves)) for i in range(n)]
+
+
+def _layer_iters(params):
+    """Each kind's layers, in order, as an iterator a pass takes from."""
+    return {kind: iter(_layers_of(getattr(params, group, None)))
+            for kind, group in GROUP_OF.items()}
 
 
 class HybridLM:
@@ -251,6 +298,12 @@ class HybridLM:
         qk_shared_dim: int = 0,
         v_head_dim: int = 0,
         dense_dim: int = 0,
+        full_heads: int = 0,
+        window_heads: int = 0,
+        window: int | None = None,
+        full_rotary: rotary.Rotary | None = None,
+        window_rotary: rotary.Rotary | None = None,
+        max_len: int = 0,
         norm_eps: float = 1e-5,
         init_std: float = 0.02,
         depth_for_init: int | None = None,
@@ -301,6 +354,22 @@ class HybridLM:
                 "v_head_dim")
         if self.counts["D"] and dense_dim < 1:
             raise ValueError("a D layer needs dense_dim")
+        for kind, heads, rot in (("G", full_heads, full_rotary),
+                                 ("W", window_heads, window_rotary)):
+            if self.counts[kind] and (
+                    min(heads, head_dim, num_kv_heads or 0) < 1
+                    or heads % num_kv_heads or rot is None
+                    or not 0 < rot.dims <= head_dim or rot.dims % 2):
+                raise ValueError(
+                    f"a {kind} layer needs its query heads (a multiple of "
+                    "num_kv_heads), head_dim and a Rotary over an even "
+                    "number of a head's dims")
+        if self.counts["W"] and (window is None or window < 1):
+            raise ValueError("a W layer needs window >= 1")
+        self.full_heads, self.window_heads = full_heads, window_heads
+        self.window = window
+        self.full_rotary, self.window_rotary = full_rotary, window_rotary
+        self.max_len = max_len
         self.expert_form = expert_form
         self.kda_heads, self.kda_head_dim = kda_heads, kda_head_dim
         self.kda_gate_rank = kda_gate_rank or kda_head_dim
@@ -423,7 +492,7 @@ class HybridLM:
                 w_gate=normal(moe.w_up.shape), w_up=moe.w_up,
                 w_down=moe.w_down, shared_gate=normal(moe.shared_up.shape),
                 shared_up=moe.shared_up, shared_down=moe.shared_down)
-        nk, nl, nd = (self.counts[kind] for kind in WIDE_KINDS)
+        nk, nl, nd = (self.counts[kind] for kind in ("K", "L", "D"))
         kh, khd, rank = self.kda_heads, self.kda_head_dim, self.kda_gate_rank
         kin = self.kda_inner
         kda = KdaParams(
@@ -454,8 +523,23 @@ class HybridLM:
             w_up=normal((nd, d, self.dense_dim)),
             w_down=normal((nd, self.dense_dim, d), resid),
         )
+        # The gated attention kinds draw from a stream of their own too.
+        keys = iter(jax.random.split(
+            jax.random.fold_in(jax.random.key(seed), 2), 12))
+        kv = self.num_kv_heads * self.head_dim
+
+        def gated(n, heads):
+            return GatedAttnParams(
+                norm=jnp.ones((n, d), f32),
+                wq=normal((n, d, heads * self.head_dim)),
+                wk=normal((n, d, kv)), wv=normal((n, d, kv)),
+                w_gate=normal((n, d, heads)),
+                wo=normal((n, heads * self.head_dim, d), resid))
+
         stacks = dict(mamba=mamba, moe=moe, attn=attn, kda=kda, mla=mla,
-                      dense=dense)
+                      dense=dense,
+                      full=gated(self.counts["G"], self.full_heads),
+                      window=gated(self.counts["W"], self.window_heads))
         return StackLMParams(**ends, **{
             group: stacks[group] if self.counts[kind] else None
             for kind, group in GROUP_OF.items()})
@@ -497,7 +581,9 @@ class HybridLM:
             y = y.reshape(b, l, inner) * p.gate_norm
             return self._dot(y, p.out_proj)
 
-    def _experts(self, p: ExpertParams, h):
+    def _experts(self, p: ExpertParams, h, token_mask=None):
+        """``token_mask`` [B, L] (None: every token) keeps the tokens it
+        is False for out of the routed experts and their load."""
         b, l, d = h.shape
         with jax.named_scope(names.MOE_ROUTE):
             u = rmsnorm(h, p.norm, self.norm_eps).reshape(b * l, d)
@@ -508,7 +594,9 @@ class HybridLM:
             scale=self.routed_scale, w_gate=p.w_gate if gated else None,
             compute_dtype=self.compute_dtype,
             balance=None if self.balance_rounds is None
-            else (l, self.balance_rounds))
+            else (l, self.balance_rounds),
+            token_mask=None if token_mask is None
+            else token_mask.reshape(b * l))
         with jax.named_scope(names.MOE_SHARED):
             if gated:
                 shared = self._gated_ffn(
@@ -585,7 +673,7 @@ class HybridLM:
         with jax.named_scope(names.ATTN_OUT):
             return self._dot(a.reshape(b, l, -1), p.wo)
 
-    def _attend(self, q, k, v):
+    def _attend(self, q, k, v, window=None):
         from distributed_tensorflow_tpu.models.base import resolve_flash_min_len
 
         if self.attention_impl == "flash" and q.shape[1] >= (
@@ -594,9 +682,49 @@ class HybridLM:
                 REMAT_SAVE_NAMES, flash_attention)
 
             return flash_attention(
-                q, k, v, causal=True,
+                q, k, v, causal=True, window=window,
                 save_names=REMAT_SAVE_NAMES if self._policy_remat else None)
-        return dense_attention(q, k, v, causal=True)
+        return dense_attention(q, k, v, causal=True, window=window)
+
+    def _kind(self, kind: str):
+        """(query heads, Rotary, window, core scope) of ``G`` or ``W``."""
+        if kind == "G":
+            return self.full_heads, self.full_rotary, None, names.ATTN_FULL
+        return (self.window_heads, self.window_rotary, self.window,
+                names.ATTN_WINDOW)
+
+    def _gated_qkv(self, p: GatedAttnParams, h, kind: str, positions):
+        """h [B, L, d] at ``positions`` ([L] or [B, L]) -> q [B, L, Hq,
+        Dh], k, v [B, L, Hkv, Dh] (turned, ``compute_dtype``) and the
+        heads' gates [B, L, Hq] float32."""
+        b, l, _ = h.shape
+        heads, rot, _, _ = self._kind(kind)
+        cd, hd = self.compute_dtype, self.head_dim
+        with jax.named_scope(names.ATTN_QKV):
+            u = rmsnorm(h, p.norm, self.norm_eps)
+            q = self._dot(u, p.wq).reshape(b, l, heads, hd)
+            kv = (b, l, self.num_kv_heads, hd)
+            k = self._dot(u, p.wk).reshape(kv)
+            v = self._dot(u, p.wv).reshape(kv)
+            q = rotary.apply(q, positions, rot)
+            k = rotary.apply(k, positions, rot)
+            gate = jax.nn.sigmoid(self._dot(u, p.w_gate))
+        return q.astype(cd), k.astype(cd), v.astype(cd), gate
+
+    def _gated_out(self, p: GatedAttnParams, a, gate):
+        """a [B, L, Hq, Dh] -> the layer's output [B, L, d]."""
+        b, l = a.shape[:2]
+        with jax.named_scope(names.ATTN_OUT):
+            a = a.astype(jnp.float32) * gate[..., None]
+            return self._dot(a.reshape(b, l, -1), p.wo)
+
+    def _gated_attention(self, p: GatedAttnParams, h, kind: str):
+        q, k, v, gate = self._gated_qkv(
+            p, h, kind, jnp.arange(h.shape[1], dtype=jnp.int32))
+        _, _, window, scope = self._kind(kind)
+        with jax.named_scope(scope):
+            a = self._attend(q, k, v, window=window)
+        return self._gated_out(p, a, gate)
 
     def _attention(self, p: AttnParams, h):
         b, l, _ = h.shape
@@ -635,10 +763,11 @@ class HybridLM:
         ``moe_expert_rows`` [E layers, experts held])."""
         with jax.named_scope(names.EMBED):
             h = params.embed[tokens].astype(jnp.float32)
-        layers = {kind: iter(_layers_of(getattr(params, group, None)))
-                  for kind, group in GROUP_OF.items()}
+        layers = _layer_iters(params)
         mixers = {"M": self._mamba, "*": self._attention, "K": self._kda,
-                  "L": self._mla, "D": self._dense}
+                  "L": self._mla, "D": self._dense,
+                  "G": lambda p, h: self._gated_attention(p, h, "G"),
+                  "W": lambda p, h: self._gated_attention(p, h, "W")}
         rows = []
         for kind in self.pattern:
             p = next(layers[kind])
@@ -673,3 +802,200 @@ class HybridLM:
 
     def loss(self, params, tokens, lengths=None):
         return self.loss_and_counters(params, tokens, lengths)[0]
+
+    # -- paged serving (TextServer) ------------------------------------------
+
+    # extend_paged gives each row's last position's logits, not every
+    # position's (at 16k positions every position's would be GBs)
+    extend_returns_last = True
+
+    @property
+    def num_layers(self) -> int:
+        """Layers whose K/V the server's block pool holds: the ``G``
+        layers (a ``W`` layer keeps a ring, the other kinds no cache)."""
+        return self.counts["G"]
+
+    def ring_blocks(self, block_size: int) -> int:
+        """Blocks of a ``W`` layer's ring per slot: ``ceil(window / bs) +
+        1`` (0 where the pattern has no ``W``)."""
+        if not self.counts["W"]:
+            return 0
+        return -(-self.window // block_size) + 1
+
+    def paged_blocks_per_slot(self, block_size: int) -> int:
+        """Block-table width: blocks to address ``max_len`` positions."""
+        if block_size < 1:
+            raise ValueError(f"block_size must be >= 1, got {block_size}")
+        return -(-self.max_len // block_size)
+
+    def _check_served(self) -> None:
+        missing = {"M": "a state-space layer's recurrent state (ROADMAP "
+                        "R-state)",
+                   "K": "a delta-rule layer's recurrent state (ROADMAP "
+                        "R-state)",
+                   "L": "latent attention's compressed cache (ROADMAP "
+                        "R-latent)",
+                   "*": "attention without positions"}
+        for kind in sorted(set(self.pattern) - set(SERVED_KINDS)):
+            raise NotImplementedError(
+                f"pattern {self.pattern!r}: kind {kind!r} has no serving "
+                f"path; the paged cache keeps nothing for {missing[kind]} "
+                f"(served kinds: {SERVED_KINDS})")
+        if self.max_len < 2:
+            raise ValueError("serving needs max_len >= 2 (the positions a "
+                             "slot may address)")
+
+    def empty_paged_cache(self, slots: int, num_blocks: int,
+                          block_size: int = 16,
+                          kv_dtype: str = "bf16") -> PagedKVCache:
+        """A vacant cache: ``num_blocks`` pool blocks for the ``G`` layers,
+        tables of :meth:`paged_blocks_per_slot` entries, and for the ``W``
+        layers a ring of :meth:`ring_blocks` blocks per slot, all zero
+        (unreachable while lengths are 0). K/V are ``compute_dtype``."""
+        self._check_served()
+        if kv_dtype != "bf16":
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r}: a HybridLM cache is held in its "
+                "compute_dtype (quantized rings: ROADMAP R-kinds)")
+        if slots < 1 or num_blocks < 1:
+            raise ValueError("slots and num_blocks must be >= 1")
+        row = (block_size, self.num_kv_heads, self.head_dim)
+        pool = (self.counts["G"], num_blocks) + row
+        ring = (self.counts["W"], slots * self.ring_blocks(block_size)) + row
+        cd = self.compute_dtype
+        return PagedKVCache(
+            k=jnp.zeros(pool, cd), v=jnp.zeros(pool, cd),
+            block_tables=jnp.zeros(
+                (slots, self.paged_blocks_per_slot(block_size)), jnp.int32),
+            lengths=jnp.zeros((slots,), jnp.int32),
+            ring_k=jnp.zeros(ring, cd), ring_v=jnp.zeros(ring, cd))
+
+    def _head_rows(self, params, h):
+        """h [S, d] -> logits [S, vocab] float32."""
+        with jax.named_scope(names.LM_HEAD):
+            return self._dot(rmsnorm(h, params.norm_f, self.norm_eps),
+                             params.head)
+
+    def extend_paged(self, params, cache: PagedKVCache, tokens, suffix_lens,
+                     prefix_lens, admit):
+        """Prefill rows ``tokens`` [B, L] (right-padded, real lengths
+        ``suffix_lens`` [B]) from position 0, through the training-shaped
+        layers (the flash kernels, a ``W`` layer's by its band), and write
+        their K/V where ``admit``: the ``G`` layers' through the rows'
+        block tables, the ``W`` layers' last ``window`` positions into the
+        rings of the rows' slots (``cache.slot_of_row``; None: row ``s`` is
+        slot ``s``). No cached prefix is read: ``prefix_lens`` is the
+        interface's and must be 0 (the server refuses prefix reuse with
+        this cache). Returns (logits [B, vocab] float32 of each row's last
+        real position, the cache; its lengths and tables untouched)."""
+        del prefix_lens
+        b, l = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32), (b, l))
+        valid = (jnp.arange(l)[None, :] < suffix_lens[:, None]) & admit[:, None]
+        with jax.named_scope(names.EMBED):
+            h = params.embed[tokens].astype(jnp.float32)
+        layers = _layer_iters(params)
+        rows = {"G": [], "W": []}
+        for kind in self.pattern:
+            p = next(layers[kind])
+            if kind in rows:
+                q, k, v, gate = self._gated_qkv(p, h, kind, positions[0])
+                _, _, window, scope = self._kind(kind)
+                with jax.named_scope(scope):
+                    a = self._attend(q, k, v, window=window)
+                h = h + self._gated_out(p, a, gate)
+                rows[kind].append((k, v))
+            elif kind == "E":
+                h = h + self._experts(p, h, token_mask=valid)[0]
+            else:
+                h = h + self._dense(p, h)
+        last = jnp.take_along_axis(
+            h, jnp.maximum(suffix_lens - 1, 0)[:, None, None], axis=1)[:, 0]
+        logits = self._head_rows(params, last)
+        new = {}
+        if rows["G"]:
+            for name, pool, got in zip(("k", "v"), (cache.k, cache.v),
+                                       zip(*rows["G"])):
+                new[name] = paged.scatter_token_kv_all_layers(
+                    pool, jnp.stack(got).astype(pool.dtype),
+                    cache.block_tables, positions, valid)
+        if rows["W"]:
+            slot_of_row = (jnp.arange(b, dtype=jnp.int32)
+                           if cache.slot_of_row is None else cache.slot_of_row)
+            ring = self.ring_blocks(cache.ring_k.shape[2])
+            for name, pool, got in zip(("ring_k", "ring_v"),
+                                       (cache.ring_k, cache.ring_v),
+                                       zip(*rows["W"])):
+                new[name] = paged.scatter_ring_rows(
+                    pool, jnp.stack(got).astype(pool.dtype), slot_of_row,
+                    positions, valid, ring=ring, window=self.window)
+        return logits, cache._replace(**new)
+
+    def decode_paged(self, params, token, cache: PagedKVCache, active=None,
+                     *, live=None, counters: bool = False):
+        """One token per slot at position ``lengths``: a ``G`` layer reads
+        the pool through the live-block list ``live``
+        (``paged_attention.live_block_list``; made here for one step where
+        none is given), a ``W`` layer its slot's ring, each meeting the
+        row it writes as one more key; every layer's fresh row is
+        committed after the last layer (pool rows through the tables,
+        ring rows at ``(lengths // bs) % ring``). Inactive slots are
+        untouched and keep out of the experts. Returns (logits [S, vocab],
+        cache with lengths advanced where active), and with ``counters``
+        also the experts the active slots' tokens chose this step, as
+        many distinct ones per ``E`` layer (int32 [n_E])."""
+        s = token.shape[0]
+        act = jnp.ones((s,), bool) if active is None else active
+        lengths = cache.lengths
+        with jax.named_scope(names.EMBED):
+            h = params.embed[token[:, None]].astype(jnp.float32)
+        if live is None and self.counts["G"]:
+            live = paged.live_block_list(
+                cache.block_tables, lengths, act, 1, cache.k.shape[1],
+                cache.k.shape[2])
+        layers = _layer_iters(params)
+        rows = {"G": [], "W": []}
+        touched = []
+        for kind in self.pattern:
+            p = next(layers[kind])
+            if kind in rows:
+                q, k, v, gate = self._gated_qkv(p, h, kind, lengths[:, None])
+                heads, _, _, scope = self._kind(kind)
+                k_row, v_row = k[:, 0], v[:, 0]
+                if kind == "G":
+                    a = paged.paged_decode_attention(
+                        q[:, 0], k_row, v_row, cache.k, cache.v,
+                        len(rows["G"]), live, lengths, scopes=(scope, scope))
+                else:
+                    a = paged.ring_decode_attention(
+                        q[:, 0], k_row, v_row, cache.ring_k, cache.ring_v,
+                        len(rows["W"]), lengths, window=self.window,
+                        scope=scope)
+                rows[kind].append((k_row, v_row))
+                h = h + self._gated_out(
+                    p, a.reshape(s, 1, heads, self.head_dim), gate)
+            elif kind == "E":
+                out, load = self._experts(p, h, token_mask=act[:, None])
+                h = h + out
+                touched.append(jnp.sum(load > 0, dtype=jnp.int32))
+            else:
+                h = h + self._dense(p, h)
+        logits = self._head_rows(params, h[:, 0])
+        new = {"lengths": lengths + act.astype(jnp.int32)}
+        if rows["G"]:
+            for name, pool, got in zip(("k", "v"), (cache.k, cache.v),
+                                       zip(*rows["G"])):
+                new[name] = paged.commit_token_rows(
+                    pool, jnp.stack(got).astype(pool.dtype),
+                    cache.block_tables, lengths, act)
+        if rows["W"]:
+            for name, pool, got in zip(("ring_k", "ring_v"),
+                                       (cache.ring_k, cache.ring_v),
+                                       zip(*rows["W"])):
+                new[name] = paged.commit_ring_rows(
+                    pool, jnp.stack(got).astype(pool.dtype), lengths, act)
+        cache = cache._replace(**new)
+        if not counters:
+            return logits, cache
+        return logits, cache, (jnp.stack(touched) if touched
+                               else jnp.zeros((0,), jnp.int32))

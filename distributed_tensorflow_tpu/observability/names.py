@@ -100,9 +100,15 @@ HYBRID_SCOPES = (
     MOE_SHARED,
 )
 KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN)
+# The hybrid stack's two gated attention kinds: the core of a full layer
+# ("G": the softmax over the block pool, its reads of the pool included)
+# and of a window layer ("W": the softmax over the slot's ring).
+ATTN_FULL = "attn_full"
+ATTN_WINDOW = "attn_window"
+RING_SCOPES = (ATTN_FULL, ATTN_WINDOW)
 SCOPES = MODEL_SCOPES + (
     LOSS, KV_WRITE, KV_GATHER, KV_RESTACK, PICK, OPTIMIZER,
-) + HYBRID_SCOPES + KDA_SCOPES
+) + HYBRID_SCOPES + KDA_SCOPES + RING_SCOPES
 
 # -- Pallas kernels -----------------------------------------------------------
 KERNEL_FLASH_FWD = "flash_fwd"

@@ -437,7 +437,7 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
 def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
                  scale: float, w_gate=None, compute_dtype=jnp.bfloat16,
                  balance: tuple[int, int] | None = None,
-                 block_rows: int | None = None):
+                 block_rows: int | None = None, token_mask=None):
     """The routed part of a sigmoid-scored, top-``k`` expert layer that
     this holder of experts ``first .. first + w_up.shape[0] - 1``
     computes. x [T, D], router [D, E] over all E experts, bias [E], w_up
@@ -460,7 +460,10 @@ def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
     the only path the loop would recompute the usual case's products a
     third time under a layer's remat, or keep every block's residuals).
     Shapes are static either way, and only rows that landed here are
-    multiplied: a step's time follows the load on the held experts."""
+    multiplied: a step's time follows the load on the held experts.
+    ``token_mask`` [T] bool (None: every token) leaves the tokens it is
+    False for out of the experts and of ``load``: they are routed, land
+    nowhere and get 0 (a serving step's vacant slots and pad positions)."""
     t, d = x.shape
     pairs = t * k
     count = w_up.shape[0]
@@ -469,7 +472,10 @@ def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
     with jax.named_scope(names.MOE_ROUTE):
         idx, w = route_sigmoid_topk(x, router, bias, k, scale, balance)
     with jax.named_scope(names.MOE_DISPATCH):
-        order, load = dispatch_held(idx, first, count, router.shape[1])
+        order, load = dispatch_held(
+            idx if token_mask is None
+            else jnp.where(token_mask[:, None], idx, -1),
+            first, count, router.shape[1])
         rows = load[first:first + count]
         order = jnp.pad(order, (0, nb * r - pairs))
         ends = jnp.cumsum(rows)

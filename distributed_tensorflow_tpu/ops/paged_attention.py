@@ -211,6 +211,7 @@ def paged_decode_attention(
     window: int | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    scopes: tuple[str, str] = (names.ATTN_CORE, names.KV_GATHER),
 ):
     """Attention of a DECODE step: one query per slot over the slot's
     resident positions, read out of the layer-stacked pool through the
@@ -239,7 +240,9 @@ def paged_decode_attention(
     (``[.., Hkv·Dh]``) and sums or spreads over a head's ``Dh`` lanes by
     a product with the 0/1 head-membership matrix: the chip tiles an
     array by its two minor axes, and ``[.., Hkv, Dh]`` would be padded
-    there."""
+    there. ``scopes`` names the arithmetic and the reads of the pool
+    (a caller with attention kinds of its own names them by kind)."""
+    core, gather = scopes
     s, hq, dh = q.shape
     hkv = k_row.shape[1]
     g, hd = hq // hkv, hkv * dh
@@ -262,7 +265,7 @@ def paged_decode_attention(
             precision=lax.Precision.HIGHEST, preferred_element_type=x.dtype,
         )
 
-    with jax.named_scope(names.ATTN_CORE):
+    with jax.named_scope(core):
         # [S, G, Hkv·Dh]: group member g of every KV head, rows flat
         qf = jnp.swapaxes(group_query_heads(q, hkv), 1, 2).reshape(s, g, hd)
         qf = qf.astype(f32) * scale
@@ -274,7 +277,7 @@ def paged_decode_attention(
         )
 
     def read(pool, scales, ids):
-        with jax.named_scope(names.KV_GATHER):
+        with jax.named_scope(gather):
             got = pool.at[layer, ids].get(mode="clip")  # [T, bs, ...]
             if scales is not None:
                 got = dequantize_kv(
@@ -292,7 +295,7 @@ def paged_decode_attention(
         start = lax.dynamic_slice(live.start, at, (tile,))
         kt = read(k_pool, k_scale, ids)
         vt = read(v_pool, v_scale, ids)
-        with jax.named_scope(names.ATTN_CORE):
+        with jax.named_scope(core):
             upto = lengths[slot]  # [T]
             pos = start[:, None] + jnp.arange(bs)[None, :]  # [T, bs]
             valid = pos < upto[:, None]
@@ -325,7 +328,7 @@ def paged_decode_attention(
     m, l, acc = lax.fori_loop(
         0, blocks_walked(live, bs) // tile, turn, (m0, l0, acc0)
     )
-    with jax.named_scope(names.ATTN_CORE):
+    with jax.named_scope(core):
         out = (acc / per_lane(l)).reshape(s, g, hkv, dh)
         return jnp.swapaxes(out, 1, 2)  # [S, Hkv, G, Dh]
 
@@ -404,3 +407,112 @@ def paged_extend_attention(
         "shglk,skhd->slhgd", w, vcat, preferred_element_type=jnp.float32
     )
     return out.reshape(s, l, hq, dh).astype(q.dtype)
+
+
+# -- the window layers' rings --------------------------------------------------
+#
+# A window layer (one whose queries see their last ``W`` keys) keeps no
+# blocks of the pool: each slot holds a RING of ``R = ceil(W / bs) + 1``
+# blocks of its own, allocated once, ``[n, S*R, bs, Hkv, Dh]`` for the
+# stack's ``n`` window layers, ring block ``j`` of slot ``s`` at row
+# ``s*R + j``. Logical position ``p`` lives in ring block ``(p // bs) % R``
+# at offset ``p % bs``. A query at ``p`` reads keys ``p-W+1 .. p``: their
+# blocks are at most ``R - 1`` apart, and the block that ``p`` starts
+# overwrites holds positions at least ``R*bs > W`` back, so the ring holds
+# every key a step reads. Which position a ring row holds is arithmetic on
+# the slot's length, never a label stored with it: a row is read only
+# where that arithmetic puts it inside the band, and every such row was
+# written by the slot's present request (its prefill wrote the prompt's
+# last ``W`` positions), so a refilled slot reads nothing of the last one.
+
+
+def ring_positions(lengths: jax.Array, ring: int, block_size: int):
+    """[S, ring*bs]: the logical position each ring row of each slot holds
+    once ``lengths[s]`` positions are written (negative: none yet)."""
+    cur = (lengths - 1) // block_size  # the block of the newest position
+    j = jnp.arange(ring)
+    blk = cur[:, None] - ((cur[:, None] - j[None, :]) % ring)  # [S, R]
+    pos = blk[:, :, None] * block_size + jnp.arange(block_size)
+    return pos.reshape(lengths.shape[0], ring * block_size)
+
+
+def ring_decode_attention(
+    q: jax.Array,
+    k_row: jax.Array,
+    v_row: jax.Array,
+    ring_k: jax.Array,
+    ring_v: jax.Array,
+    layer: int,
+    lengths: jax.Array,
+    *,
+    window: int,
+    scope: str = names.ATTN_CORE,
+):
+    """Attention of a DECODE step of a window layer: one query per slot
+    over its ring's rows inside the band ``(lengths - W, lengths)`` plus
+    the fresh row this step writes at ``lengths``. q [S, Hq, Dh];
+    k_row/v_row [S, Hkv, Dh]; ring_k/ring_v ``[n, S*R, bs, Hkv, Dh]``.
+    Returns ``[S, Hkv, G, Dh]`` float32. Every slot's whole ring is read
+    (``R*bs`` rows: the band and one block), float32 products at the
+    highest precision."""
+    s, hq, dh = q.shape
+    hkv = k_row.shape[1]
+    sr, bs = ring_k.shape[1], ring_k.shape[2]
+    ring = sr // s
+    f32 = jnp.float32
+    hi = lax.Precision.HIGHEST
+    with jax.named_scope(scope):
+        kr = ring_k[layer].reshape(s, ring * bs, hkv, dh).astype(f32)
+        vr = ring_v[layer].reshape(s, ring * bs, hkv, dh).astype(f32)
+        pos = ring_positions(lengths, ring, bs)
+        upto = lengths[:, None]
+        valid = (pos >= 0) & (pos < upto) & (pos > upto - window)
+        qg = group_query_heads(q.astype(f32), hkv) / jnp.sqrt(f32(dh))
+        scores = jnp.einsum("shgd,sphd->shgp", qg, kr, precision=hi)
+        scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
+        fresh = jnp.einsum(
+            "shgd,shd->shg", qg, k_row.astype(f32), precision=hi)
+        w = jax.nn.softmax(
+            jnp.concatenate([scores, fresh[..., None]], axis=-1), axis=-1)
+        out = jnp.einsum("shgp,sphd->shgd", w[..., :-1], vr, precision=hi)
+        return out + w[..., -1:] * v_row.astype(f32)[:, :, None, :]
+
+
+def _ring_index(slot, positions, ring: int, block_size: int, valid, rows: int):
+    """``(ring row, offset)`` of ``positions`` of ``slot`` (broadcast
+    alike), masked writes routed to the sentinel row ``rows``."""
+    row = slot * ring + (positions // block_size) % ring
+    return jnp.where(valid, row, rows), positions % block_size
+
+
+def commit_ring_rows(ring_pool, rows, lengths, active):
+    """The decode step's commit to the rings: ``rows`` [n, S, Hkv, Dh],
+    one fresh row per window layer and slot, written at position
+    ``lengths[s]`` of slot ``s`` where ``active`` (the sentinel drops the
+    rest): :func:`commit_token_rows` for the rings."""
+    n, sr, bs = ring_pool.shape[0], ring_pool.shape[1], ring_pool.shape[2]
+    s = lengths.shape[0]
+    with jax.named_scope(names.KV_WRITE):
+        idx, off = _ring_index(
+            jnp.arange(s), lengths, sr // s, bs, active, sr)
+        return ring_pool.at[jnp.arange(n)[:, None], idx[None, :],
+                            off[None, :]].set(
+            rows.reshape((n, s) + ring_pool.shape[3:]), mode="drop")
+
+
+def scatter_ring_rows(ring_pool, kvs, slot_of_row, positions, valid, *,
+                      ring: int, window: int):
+    """A prefill's write to the rings of ``ring`` blocks a slot: ``kvs``
+    [n, B, L, Hkv, Dh] holds the rows of logical ``positions`` [B, L] of
+    the slots ``slot_of_row`` [B]; of each row's ``valid`` positions only
+    the last ``window`` are written (those a later query can read; older
+    ones would land on the same ring rows)."""
+    n, sr, bs = ring_pool.shape[0], ring_pool.shape[1], ring_pool.shape[2]
+    b, l = positions.shape
+    last = jnp.max(jnp.where(valid, positions, -1), axis=1, keepdims=True)
+    keep = valid & (positions > last - window)
+    with jax.named_scope(names.KV_WRITE):
+        idx, off = _ring_index(
+            slot_of_row[:, None], positions, ring, bs, keep, sr)
+        return ring_pool.at[:, idx.reshape(-1), off.reshape(-1)].set(
+            kvs.reshape((n, b * l) + kvs.shape[3:]), mode="drop")

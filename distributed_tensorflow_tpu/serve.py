@@ -213,6 +213,24 @@ def load_tokenizer(checkpoint_dir: str):
 
 # -- the engine ------------------------------------------------------------
 
+# A prefill dispatch whose whole bank of slots holds at most this many
+# positions (slots x bucket) carries a row for every slot, admitted or
+# not, as it always did; past it a dispatch carries the rows its admissions fill,
+# rounded up to one of PREFILL_ROW_SIZES that keeps rows x bucket within
+# it (one row at the least).
+PREFILL_BANK_POSITIONS = 4096
+PREFILL_ROW_SIZES = (1, 4)
+
+
+def prefill_rows(slots: int, bucket: int, admitted: int) -> int:
+    """Rows of a prefill dispatch of ``bucket`` positions that
+    ``admitted`` requests share (more than that are dispatched again)."""
+    if slots * bucket <= PREFILL_BANK_POSITIONS:
+        return slots
+    fit = [r for r in PREFILL_ROW_SIZES
+           if r < slots and r * bucket <= PREFILL_BANK_POSITIONS] or [1]
+    return next((r for r in fit if r >= admitted), fit[-1])
+
 
 class _DecodeState(NamedTuple):
     """Device-resident per-slot serving state, one pytree so every
@@ -257,6 +275,10 @@ class _PagedState(NamedTuple):
     eos: jax.Array  # [S] i32 — -1: no EOS stop
     k_scale: jax.Array | None = None  # [layers, NB, bs, Hkv] f32
     v_scale: jax.Array | None = None
+    # A model with window layers: their rings, [window layers, S*ring,
+    # bs, Hkv, Dh] (PagedKVCache says how they are addressed).
+    ring_k: jax.Array | None = None
+    ring_v: jax.Array | None = None
 
 
 class _Request:
@@ -387,6 +409,24 @@ class TextServer:
             raise ValueError(
                 f"queue_limit must be >= 1 (or None), got {queue_limit}"
             )
+        # A model with window layers (HybridLM) keeps their K/V in a ring
+        # of blocks per slot, beside the block pool of its full layers
+        # (docs/serving.md §rings). What needs the pool's shareable,
+        # relocatable blocks or every position's logits is refused here.
+        self._ringed = hasattr(model, "ring_blocks")
+        if self._ringed:
+            why = "with window layers' rings in the cache (ROADMAP R-kinds)"
+            for bad, what in (
+                (not paged, "the slab cache (paged=False)"),
+                (prefix_caching, "prefix reuse (prefix_caching=True)"),
+                (spec_draft, "speculative decoding (spec_draft > 0)"),
+                (kv_dtype != "bf16", f"a quantized cache (kv_dtype={kv_dtype!r})"),
+            ):
+                if bad:
+                    raise ValueError(f"{what} is not served {why}")
+        self.ring_blocks = (
+            model.ring_blocks(int(block_size)) if self._ringed else 0
+        )
         self.model = model
         # Bounded admission queue (round 16): submit() raises QueueFull
         # past this depth instead of growing without bound; /healthz
@@ -593,6 +633,12 @@ class TextServer:
             self.metrics.gauge("kv_pool_bytes").set(
                 self.kv_blocks * self.kv_block_bytes
             )
+        if self.ring_blocks:
+            # Ring blocks per window layer: allocated once, a ring a slot.
+            self.metrics.gauge("kv_ring_blocks_total").set(
+                self.slots * self.ring_blocks
+            )
+            self.metrics.gauge("kv_ring_blocks_used").set(0)
         # Live scrape surface (round 12, observability/exporter.py):
         # /metrics = the registry's Prometheus text, /healthz = engine
         # heartbeat (seconds since the last step() tick) + occupancy.
@@ -667,6 +713,8 @@ class TextServer:
                 lengths=cache.lengths,
                 k_scale=cache.k_scale,
                 v_scale=cache.v_scale,
+                ring_k=cache.ring_k,
+                ring_v=cache.ring_v,
                 **common,
             )
         cache = self.model.empty_slot_cache(s, self.kv_dtype)
@@ -778,6 +826,8 @@ class TextServer:
                 lengths=st.lengths,
                 k_scale=st.k_scale,
                 v_scale=st.v_scale,
+                ring_k=st.ring_k,
+                ring_v=st.ring_v,
             )
         return SlotKVCache(
             k=st.k,
@@ -823,49 +873,63 @@ class TextServer:
         )
 
     def _paged_prefill_graph(
-        self, params, st, tokens, suffix_lens, prefix_lens, admit,
-        block_tables, key, budget, greedy, temp, top_p, eos,
+        self, params, st, slot_of_row, tokens, suffix_lens, prefix_lens,
+        admit, block_tables, key, budget, greedy, temp, top_p, eos,
     ):
-        """Paged admission round: ragged batched EXTEND through the
-        block tables (prefix-cache hits arrive as nonzero
-        ``prefix_lens`` — those blocks are read, not recomputed; the
-        host strips the cached prefix, so ``tokens`` is only each
-        request's suffix padded to its bucket) + the first pick from
-        each row's last real suffix position. ``block_tables`` [S, NB]
-        is the host-authoritative table snapshot (non-admitted rows
-        unchanged by construction)."""
-        cache = self._cache(st)._replace(block_tables=block_tables)
+        """Paged admission round over ``R`` rows (:func:`prefill_rows`):
+        row ``i`` is slot ``slot_of_row[i]`` with its table row
+        ``block_tables[i]``. Ragged batched EXTEND through those tables
+        (prefix-cache hits arrive as nonzero ``prefix_lens`` — those
+        blocks are read, not recomputed; the host strips the cached
+        prefix, so ``tokens`` is only each request's suffix padded to its
+        bucket) + the first pick from each row's last real suffix
+        position. Every per-row argument is ``[R]``; the state's rows of
+        the admitted slots are set from them, and a row that admits
+        nothing writes nothing."""
+        cache = self._cache(st)._replace(
+            block_tables=block_tables, slot_of_row=slot_of_row
+        )
         logits, cache = self.model.extend_paged(
             params, cache, tokens, suffix_lens, prefix_lens, admit
         )
-        last_lg = jnp.take_along_axis(
-            logits,
-            jnp.maximum(suffix_lens - 1, 0)[:, None, None],
-            axis=1,
-        )[:, 0]  # [S, vocab]
-        keys = jnp.where(admit[:, None], key, st.key)
-        carried, sub = self._split_keys(keys)
-        first = self._pick(last_lg, sub, greedy, temp, top_p)
-        sel = lambda n, o: jnp.where(admit, n, o)  # noqa: E731
-        eos_eff = sel(eos, st.eos)
-        fin = sel((first == eos_eff) | (budget <= 1), st.finished)
+        carried, sub = self._split_keys(key)
+        first = self._pick(
+            self._last_logits(logits, suffix_lens), sub, greedy, temp, top_p
+        )
+        at = jnp.where(admit, slot_of_row, self.slots)  # the rest drop
+
+        def put(field, value):
+            return field.at[at].set(value, mode="drop")
+
         return st._replace(
             k=cache.k,
             v=cache.v,
             k_scale=cache.k_scale,
             v_scale=cache.v_scale,
-            block_tables=block_tables,
-            lengths=sel(prefix_lens + suffix_lens, st.lengths),
-            last_tok=sel(first, st.last_tok),
-            key=jnp.where(admit[:, None], carried, st.key),
-            emitted=sel(jnp.ones_like(st.emitted), st.emitted),
-            budget=sel(budget, st.budget),
-            finished=fin,
-            greedy=sel(greedy, st.greedy),
-            temp=jnp.where(admit, temp, st.temp),
-            top_p=jnp.where(admit, top_p, st.top_p),
-            eos=eos_eff,
+            ring_k=cache.ring_k,
+            ring_v=cache.ring_v,
+            block_tables=put(st.block_tables, block_tables),
+            lengths=put(st.lengths, prefix_lens + suffix_lens),
+            last_tok=put(st.last_tok, first),
+            key=put(st.key, carried),
+            emitted=put(st.emitted, jnp.ones_like(first)),
+            budget=put(st.budget, budget),
+            finished=put(st.finished, (first == eos) | (budget <= 1)),
+            greedy=put(st.greedy, greedy),
+            temp=put(st.temp, temp),
+            top_p=put(st.top_p, top_p),
+            eos=put(st.eos, eos),
         )
+
+    def _last_logits(self, logits, suffix_lens):
+        """[R, vocab]: each row's last real position's logits, from an
+        extend that gave every position's (or, where the model says so,
+        the last position's alone)."""
+        if getattr(self.model, "extend_returns_last", False):
+            return logits
+        return jnp.take_along_axis(
+            logits, jnp.maximum(suffix_lens - 1, 0)[:, None, None], axis=1
+        )[:, 0]
 
     def _verify_graph(self, params, st, suffix, suffix_lens):
         """One speculative verify round (the paged engine's decode tick
@@ -951,7 +1015,9 @@ class TextServer:
         the token block then carries two more rows, the list's real
         entries and the entries a step walks (that count rounded up to
         whole tiles), each repeated over the slots: they reach the host
-        in the fetch that brings the tokens (:meth:`_account_delivery`)."""
+        in the fetch that brings the tokens (:meth:`_account_delivery`).
+        A model with window layers adds one row per expert layer: the
+        distinct experts its steps' tokens chose, summed over the chunk."""
         max_len = self.model.max_len
         decode = (
             self.model.decode_paged if self.paged else self.model.decode_slots
@@ -965,8 +1031,11 @@ class TextServer:
         pool_shape = st.k.shape
         walked = None
         if self.paged:
-            flat = pool_shape[:3] + (-1,)
-            st = st._replace(k=st.k.reshape(flat), v=st.v.reshape(flat))
+            if not self._ringed:
+                # (A HybridLM's rows are [8, 128] or another whole tile;
+                # flattening them would copy the pool twice a chunk.)
+                flat = pool_shape[:3] + (-1,)
+                st = st._replace(k=st.k.reshape(flat), v=st.v.reshape(flat))
             live = paged_attention.live_block_list(
                 st.block_tables, st.lengths,
                 ~st.finished & (st.lengths < max_len), self.chunk,
@@ -979,9 +1048,15 @@ class TextServer:
 
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
-            logits, cache = decode(
-                params, st.last_tok, self._cache(st), active=act
-            )
+            if self._ringed:  # with the experts each step touched
+                logits, cache, touched = decode(
+                    params, st.last_tok, self._cache(st), active=act,
+                    counters=True,
+                )
+            else:
+                logits, cache = decode(
+                    params, st.last_tok, self._cache(st), active=act
+                )
             carried, sub = self._split_keys(st.key)
             nxt = self._pick(logits, sub, st.greedy, st.temp, st.top_p)
             nxt = jnp.where(act, nxt, st.last_tok)
@@ -1005,18 +1080,23 @@ class TextServer:
                 emitted=emitted,
                 finished=fin,
             )
+            if self._ringed:
+                st = st._replace(ring_k=cache.ring_k, ring_v=cache.ring_v)
+                return st, (nxt, act, touched)
             return st, (nxt, act)
 
-        st, (toks, valid) = jax.lax.scan(
-            body, st, None, length=self.chunk
-        )
+        st, outs = jax.lax.scan(body, st, None, length=self.chunk)
+        toks, valid = outs[:2]
         if self.paged:
             st = st._replace(
                 k=st.k.reshape(pool_shape), v=st.v.reshape(pool_shape)
             )
-            toks = jnp.concatenate(
-                [toks, jnp.broadcast_to(walked[:, None], (2, self.slots))]
-            )
+            if self._ringed:  # per E layer, summed over the chunk's steps
+                walked = jnp.concatenate([walked, outs[2].sum(0)])
+            toks = jnp.concatenate([
+                toks,
+                jnp.broadcast_to(walked[:, None], (len(walked), self.slots)),
+            ])
         return st, toks, valid
 
     # -- the scheduler (host side) -----------------------------------------
@@ -1109,6 +1189,12 @@ class TextServer:
             raise ValueError(
                 "KV migration requires paged=True (block tables are what "
                 "make the cache relocatable across replicas)"
+            )
+        if (prefill_only or resume is not None) and self._ringed:
+            raise ValueError(
+                "KV migration is not served with window layers' rings in "
+                "the cache: a payload carries pool blocks, not rings "
+                "(ROADMAP R-kinds)"
             )
         if prefill_only and resume is not None:
             raise ValueError(
@@ -1466,21 +1552,23 @@ class TextServer:
 
     def _admit_member_row(
         self, slot, req, lb, key, budget, greedy, temp, top_p, eos,
-        journal_extra=None,
+        journal_extra=None, row=None,
     ) -> None:
         """Per-member sampling/budget row + admission telemetry shared by
         BOTH engine modes — a ``GenerationConfig`` field wired here
         reaches the slab and paged admission paths together (they must
-        never drift: the parity contract spans both)."""
+        never drift: the parity contract spans both). The arrays are
+        written at ``row`` (default: the slot's own)."""
         c = req.config
-        key[slot] = np.asarray(
+        i = slot if row is None else row
+        key[i] = np.asarray(
             jax.random.key_data(jax.random.key(c.seed))
         )
-        budget[slot] = c.max_new
-        greedy[slot] = c.greedy
-        temp[slot] = c.temperature
-        top_p[slot] = c.top_p
-        eos[slot] = -1 if c.eos_id is None else c.eos_id
+        budget[i] = c.max_new
+        greedy[i] = c.greedy
+        temp[i] = c.temperature
+        top_p[i] = c.top_p
+        eos[i] = -1 if c.eos_id is None else c.eos_id
         self._slot_req[slot] = req
         req.t_admit = time.perf_counter()
         self.metrics.counter("admissions_total").inc()
@@ -1595,6 +1683,11 @@ class TextServer:
         compile measurement (the ``--warm`` contract)."""
         if not self.paged:
             return
+        if self._ringed:
+            raise ValueError(
+                "KV migration is not served with window layers' rings in "
+                "the cache (ROADMAP R-kinds)"
+            )
         from distributed_tensorflow_tpu.models.gpt import (
             export_kv_blocks,
             import_kv_blocks,
@@ -1715,11 +1808,12 @@ class TextServer:
                 [m for m in batch if m[3] == wave], wave
             )
         self.metrics.gauge("kv_blocks_used").set(self._alloc.used_blocks)
+        self._set_ring_gauge()
 
     def _prefill_wave(self, members_w, wave: int) -> None:
-        """One admission wave's prefill dispatches (one per length
-        bucket among the wave's members)."""
-        s = self.slots
+        """One admission wave's prefill dispatches: per length bucket
+        among the wave's members, as many as :func:`prefill_rows` rows a
+        dispatch holds."""
         by_bucket: dict[int, list] = {}
         for slot, req, plan, _ in members_w:
             prefix_len = plan["matched"] * self.block_size
@@ -1728,68 +1822,74 @@ class TextServer:
                 (slot, req, plan, prefix_len, suffix)
             )
         for lb, members in sorted(by_bucket.items()):
-            tokens = np.zeros((s, lb), np.int32)
-            slens = np.ones((s,), np.int32)  # suffix lens must be >= 1
-            plens = np.zeros((s,), np.int32)  # cached-prefix lens
-            admit = np.zeros((s,), bool)
-            key = np.array(self._state.key)  # writable host copy
-            budget = np.zeros((s,), np.int32)
-            greedy = np.ones((s,), bool)
-            temp = np.ones((s,), np.float32)
-            top_p = np.ones((s,), np.float32)
-            eos = np.full((s,), -1, np.int32)
-            for slot, req, plan, prefix_len, suffix in members:
-                tokens[slot, : suffix.size] = suffix
-                slens[slot] = suffix.size
-                plens[slot] = prefix_len
-                admit[slot] = True
-                miss = 0
-                if self._prefix is not None:
-                    miss = (
-                        self._prefix.matchable_blocks(int(req.tokens.size))
-                        - plan["matched"]
-                    )
-                    self.metrics.counter("prefix_cache_hits").inc(
-                        plan["matched"]
-                    )
-                    self.metrics.counter("prefix_cache_misses").inc(miss)
-                self._admit_member_row(
-                    slot, req, lb, key, budget, greedy, temp, top_p, eos,
-                    journal_extra=dict(
-                        prefix_len=int(prefix_len),
-                        prefix_hit_blocks=int(plan["matched"]),
-                        prefix_miss_blocks=int(miss),
-                        new_blocks=int(plan["new"]),
-                        wave=int(wave),
-                    ),
+            rows = prefill_rows(self.slots, lb, len(members))
+            for at in range(0, len(members), rows):
+                self._prefill_dispatch(lb, members[at:at + rows], rows, wave)
+
+    def _prefill_dispatch(self, lb: int, members, rows: int, wave: int):
+        """One prefill dispatch of bucket ``lb`` over ``rows`` rows: row
+        ``i`` is member ``i``'s slot; vacant rows admit nothing."""
+        n = rows
+        slot_of_row = np.zeros((n,), np.int32)
+        tables = np.zeros((n,) + self._host_tables.shape[1:], np.int32)
+        tokens = np.zeros((n, lb), np.int32)
+        slens = np.ones((n,), np.int32)  # suffix lens must be >= 1
+        plens = np.zeros((n,), np.int32)  # cached-prefix lens
+        admit = np.zeros((n,), bool)
+        key = np.zeros((n,) + self._state.key.shape[1:], self._state.key.dtype)
+        budget = np.zeros((n,), np.int32)
+        greedy = np.ones((n,), bool)
+        temp = np.ones((n,), np.float32)
+        top_p = np.ones((n,), np.float32)
+        eos = np.full((n,), -1, np.int32)
+        for row, (slot, req, plan, prefix_len, suffix) in enumerate(members):
+            slot_of_row[row] = slot
+            tables[row] = self._host_tables[slot]
+            tokens[row, : suffix.size] = suffix
+            slens[row] = suffix.size
+            plens[row] = prefix_len
+            admit[row] = True
+            miss = 0
+            if self._prefix is not None:
+                miss = (
+                    self._prefix.matchable_blocks(int(req.tokens.size))
+                    - plan["matched"]
                 )
-            with self._state_lent(), self.spans.dispatch(
-                names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
-                rids=[int(m[1].rid) for m in members],
-            ) as sp:
-                self._state = self._prefill_jit(
-                    self.params,
-                    self._state,
-                    jnp.asarray(tokens),
-                    jnp.asarray(slens),
-                    jnp.asarray(plens),
-                    jnp.asarray(admit),
-                    jnp.asarray(self._host_tables),
-                    jnp.asarray(key),
-                    jnp.asarray(budget),
-                    jnp.asarray(greedy),
-                    jnp.asarray(temp),
-                    jnp.asarray(top_p),
-                    jnp.asarray(eos),
+                self.metrics.counter("prefix_cache_hits").inc(
+                    plan["matched"]
                 )
-                first = sp.fetch(self._state.last_tok)
-            fin = np.asarray(self._state.finished)
-            t_first = time.perf_counter()
-            for slot, req, plan, prefix_len, suffix in members:
-                # Prompt blocks were registered in the radix at
-                # admission-plan time (wave scheduling above); their K/V
-                # is valid as of this dispatch.
-                self._record_first_token(slot, req, first, fin, t_first)
+                self.metrics.counter("prefix_cache_misses").inc(miss)
+            self._admit_member_row(
+                slot, req, lb, key, budget, greedy, temp, top_p, eos,
+                journal_extra=dict(
+                    prefix_len=int(prefix_len),
+                    prefix_hit_blocks=int(plan["matched"]),
+                    prefix_miss_blocks=int(miss),
+                    new_blocks=int(plan["new"]),
+                    wave=int(wave),
+                ),
+                row=row,
+            )
+        head = (jnp.asarray(tokens), jnp.asarray(slens), jnp.asarray(plens),
+                jnp.asarray(admit))
+        tail = (jnp.asarray(key), jnp.asarray(budget), jnp.asarray(greedy),
+                jnp.asarray(temp), jnp.asarray(top_p), jnp.asarray(eos))
+        with self._state_lent(), self.spans.dispatch(
+            names.SPAN_PREFILL, bucket=int(lb), admitted=len(members),
+            rids=[int(m[1].rid) for m in members], rows=int(rows),
+        ) as sp:
+            self._state = self._prefill_jit(
+                self.params, self._state, jnp.asarray(slot_of_row), *head,
+                jnp.asarray(tables), *tail,
+            )
+            first = sp.fetch(self._state.last_tok)
+        fin = np.asarray(self._state.finished)
+        t_first = time.perf_counter()
+        for slot, req, plan, prefix_len, suffix in members:
+            # Prompt blocks were registered in the radix at
+            # admission-plan time (wave scheduling above); their K/V
+            # is valid as of this dispatch.
+            self._record_first_token(slot, req, first, fin, t_first)
 
     def _admit_slab(self) -> None:
         free = self._free_slots()
@@ -1860,6 +1960,15 @@ class TextServer:
             self._slot_blocks[slot] = None
             self.metrics.gauge("kv_blocks_used").set(
                 self._alloc.used_blocks
+            )
+        self._set_ring_gauge()
+
+    def _set_ring_gauge(self) -> None:
+        """Ring blocks held: a ring per busy slot (per window layer)."""
+        if self.ring_blocks:
+            self.metrics.gauge("kv_ring_blocks_used").set(
+                self.ring_blocks
+                * sum(r is not None for r in self._slot_req)
             )
 
     def _cancel(self, req: _Request, *, slot: int | None = None) -> None:
@@ -1994,10 +2103,14 @@ class TextServer:
         entries, and ``kv_blocks_read``, the entries one step of one
         layer walks (the first rounded up to whole tiles); the second ÷
         (``slots`` × a table's blocks) is the share of a whole-table
-        read that is left."""
+        read that is left. Behind them, for a model with window layers,
+        ``experts_touched``: per expert layer, the distinct experts the
+        chunk's steps chose, summed over its steps."""
         if len(walked):
             sp.args["kv_blocks_live"] = int(walked[0])
             sp.args["kv_blocks_read"] = int(walked[1])
+        if len(walked) > 2:
+            sp.args["experts_touched"] = [int(x) for x in walked[2:]]
         sp.args["emitted"] = [
             int(valid[:, slot].sum())
             for slot, req in enumerate(self._slot_req) if req is not None

@@ -402,8 +402,9 @@ def _prefill_args(srv, lb=8):
     )
     if srv.paged:
         return (
-            srv.params, srv._state, i32(s, lb), jnp.ones((s,), jnp.int32),
-            i32(s), jnp.ones((s,), bool), jnp.asarray(srv._host_tables),
+            srv.params, srv._state, jnp.arange(s, dtype=jnp.int32),
+            i32(s, lb), jnp.ones((s,), jnp.int32), i32(s),
+            jnp.ones((s,), bool), jnp.asarray(srv._host_tables),
         ) + tail
     return (
         srv.params, srv._state, i32(s, lb), jnp.ones((s,), jnp.int32),
@@ -451,12 +452,37 @@ def test_every_scope_and_program_is_held_by_some_case():
     held = MODEL_SCOPES | {names.LOSS, names.OPTIMIZER} | SERVING_SCOPES
     held |= HYBRID_SCOPES  # test_hybrid_train_step_program_carries_every_scope
     held |= KDA_SCOPES  # test_delta_rule_train_step_program_carries_every_scope
+    held |= set(names.RING_SCOPES)  # test_hybrid_serving_programs_carry_...
     for _, _, extra in PROGRAM_CASES.values():
         held |= extra
     assert held == set(names.SCOPES)
     assert {c.split("[")[0] for c in PROGRAM_CASES} | {
         names.PROGRAM_EPOCH, names.PROGRAM_RUN
     } == set(names.PROGRAMS)
+
+
+def test_hybrid_serving_programs_carry_the_attention_kinds_scopes():
+    """A served ``HybridLM``'s prefill and decode chunk name the core of
+    each attention kind apart (``attn_full``, ``attn_window``) beside the
+    expert layer's scopes: the benchmark's ``attn_*_share_pct.laguna`` and
+    ``moe_decode_share_pct.laguna`` read them in the decode program."""
+    from distributed_tensorflow_tpu.models.hybrid import HybridLM
+    from distributed_tensorflow_tpu.ops.rotary import Rotary
+
+    m = HybridLM(
+        64, 32, "GDWE", full_heads=4, window_heads=4, num_kv_heads=2,
+        head_dim=8, window=8, full_rotary=Rotary(1e4, 4),
+        window_rotary=Rotary(1e4, 8), dense_dim=32, num_experts=4,
+        experts_per_token=2, expert_dim=8, shared_dim=8,
+        expert_form="silu_gated", max_len=32)
+    srv = TextServer(m, m.init(1), paged=True, slots=2, chunk=2,
+                     block_size=4, buckets=(8, 16), prefix_caching=False)
+    want = {names.ATTN_FULL, names.ATTN_WINDOW, names.MOE_ROUTE,
+            names.MOE_EXPERTS, names.MOE_SHARED, names.LM_HEAD, names.PICK}
+    for fn, args in ((srv._chunk_jit, (srv.params, srv._state)),
+                     (srv._prefill_jit, _prefill_args(srv))):
+        got = _scopes_by_phase(fn.lower(*args).compile())
+        assert got["forward"] >= want, want - got["forward"]
 
 
 def test_compiled_run_program_name(trainer):
