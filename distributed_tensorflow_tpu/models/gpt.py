@@ -2044,8 +2044,9 @@ class GPTLM:
         """Per-slot single-token block step against the BLOCK POOL. The
         layer-stacked pool is READ-ONLY here: attention walks the
         live-block list ``live`` through layer ``layer`` of the stack
-        (``ops/paged_attention.paged_decode_attention``: a tile of
-        resident blocks a turn, folded into a per-slot running softmax)
+        (``ops/paged_attention.paged_decode_attention``: a tile of one
+        slot's resident blocks a turn, folded into that slot's running
+        softmax)
         and meets the row this step writes as one more key, so no view
         of a slot's whole table is ever built. A pool position is valid
         below ``lengths[s]``; windowed models band by mask

@@ -21,10 +21,13 @@ dense math over it (``gather_block_view`` +
 The DECODE step never builds that rectangle: it walks a compacted list
 of the blocks that are resident (``live_block_list``, made once per
 chunk from the tables, the lengths and the active mask), a tile of
-blocks at a time, and folds each tile into a per-slot running softmax
-(``paged_decode_attention``), so that what a step reads follows what
-the pool holds and not ``slots × max_blocks``. Correctness lives in the
-masks, not the layout, on both paths.
+blocks at a time. Every tile holds the blocks of one slot (each slot's
+entries are padded up to whole tiles), so a turn is two matrix products
+of that slot's queries with the tile and one update of that slot's
+running softmax (``paged_decode_attention``): what a step reads follows
+what the pool holds, plus each slot's padding to a whole tile, and not
+``slots × max_blocks``. Correctness lives in the masks, not the layout,
+on both paths.
 
 Out-of-range discipline: unused table entries and masked (pad /
 non-admitted) writes are routed to a sentinel block index ``num_blocks``
@@ -127,28 +130,34 @@ def commit_token_rows(
 
 
 # Positions one tile of the live-block list holds: a turn of the decode
-# step's loop gathers ``TILE_POSITIONS // block_size`` blocks. Large
-# enough that a turn's fixed cost is small beside its reads, small enough
-# that rounding the list up to whole tiles reads little that is vacant.
+# step's loop gathers ``TILE_POSITIONS // block_size`` blocks of one slot.
+# Large enough that a turn's fixed cost is small beside its reads, small
+# enough that rounding each slot's entries up to whole tiles reads little
+# that is vacant.
 TILE_POSITIONS = 512
 
 
 class LiveBlocks(NamedTuple):
-    """The blocks a decode chunk can read, compacted: entry ``e < live``
-    is pool block ``block[e]``, held by slot ``slot[e]``, whose first
-    row is logical position ``start[e]`` of that slot. Entries past
-    ``live`` name the sentinel block and start past every length, so the
-    position mask alone keeps them out of every softmax. A block two
-    slots share (a cached prefix) has one entry per slot."""
+    """The blocks a decode chunk can read, compacted, one slot a tile:
+    entry ``e`` is pool block ``block[e]``, held by slot ``slot[e]``,
+    whose first row is logical position ``start[e]`` of that slot. Each
+    slot's entries fill whole tiles, the first ``walked`` entries: those
+    past its last block (padding to the tile's end) name the sentinel
+    block and start past every length, so the position mask alone keeps
+    them out of the softmax, as it keeps out the entries past
+    ``walked``. ``slot[t · tile]`` is the slot of tile ``t``. ``live``
+    counts the real entries. A block two slots share (a cached prefix)
+    has one entry per slot."""
 
     block: jax.Array  # [E] i32
     slot: jax.Array  # [E] i32
     start: jax.Array  # [E] i32
     live: jax.Array  # [] i32
+    walked: jax.Array  # [] i32
 
 
-def _tile_blocks(block_size: int, entries: int) -> int:
-    return max(1, min(TILE_POSITIONS // block_size, entries))
+def _tile_blocks(block_size: int, table_blocks: int) -> int:
+    return max(1, min(TILE_POSITIONS // block_size, table_blocks))
 
 
 def live_block_list(
@@ -163,20 +172,24 @@ def live_block_list(
     ``steps`` decode steps: every table entry of an ``active`` slot that
     holds a position a step can read out of the pool — positions below
     ``lengths + steps − 1``; the row a step writes reaches its own
-    softmax as the fresh key — in slot order, then block order. One
-    cumulative sum and three small scatters on the device; ``E`` is the
-    tables' size rounded up to whole tiles. A table entry still at the
-    sentinel (past the blocks reserved at admission: the request ends
-    before it gets there) is left out."""
+    softmax as the fresh key — in slot order, then block order, each
+    slot's entries padded up to whole tiles of ``min(TILE_POSITIONS //
+    block_size, NB)`` blocks. Cumulative sums and three small scatters on
+    the device; ``E`` is every slot's table rounded up to whole tiles. A
+    table entry still at the sentinel (past the blocks reserved at
+    admission: the request ends before it gets there) is left out."""
     s, nb = block_tables.shape
-    tile = _tile_blocks(block_size, s * nb)
-    cap = -(-s * nb // tile) * tile
+    tile = _tile_blocks(block_size, nb)
+    cap = s * -(-nb // tile) * tile
     reach = jnp.minimum(lengths + steps - 1, nb * block_size)
     need = jnp.where(active, -(-reach // block_size), 0)  # [S] blocks
     col = jnp.arange(nb, dtype=jnp.int32)
     held = (col[None, :] < need[:, None]) & (block_tables < num_blocks)
-    held = held.reshape(-1)
-    dest = jnp.where(held, jnp.cumsum(held) - 1, cap)
+    padded = -(-held.sum(axis=1, dtype=jnp.int32) // tile) * tile  # [S]
+    ends = jnp.cumsum(padded)
+    dest = jnp.where(
+        held, (ends - padded)[:, None] + jnp.cumsum(held, axis=1) - 1, cap
+    ).reshape(-1)
 
     def compact(values, fill):
         return jnp.full((cap,), fill, jnp.int32).at[dest].set(
@@ -185,17 +198,13 @@ def live_block_list(
 
     return LiveBlocks(
         block=compact(block_tables, num_blocks),
-        slot=compact(jnp.arange(s, dtype=jnp.int32)[:, None], 0),
+        # the slot whose tiles hold the entry; past the walk, S
+        slot=(jnp.arange(cap)[:, None] >= ends[None, :]).sum(
+            axis=1, dtype=jnp.int32),
         start=compact(col[None, :] * block_size, nb * block_size),
         live=held.sum(dtype=jnp.int32),
+        walked=ends[-1],
     )
-
-
-def blocks_walked(live: LiveBlocks, block_size: int) -> jax.Array:
-    """List entries one step of one layer reads: ``live.live`` rounded up
-    to whole tiles."""
-    tile = _tile_blocks(block_size, live.block.shape[0])
-    return -(-live.live // tile) * tile  # the loop's turns × a tile
 
 
 def paged_decode_attention(
@@ -225,56 +234,63 @@ def paged_decode_attention(
     the quantized pool's ``[n, NB, bs, Hkv]`` side pools, read through
     the same list. Returns ``[S, Hkv, G, Dh]`` float32.
 
-    The loop runs ``ceil(live / tile)`` turns — a number the device
-    computes, so a chunk with two residents and one with eight are one
-    program. A turn gathers one tile of blocks out of layer ``layer``
-    (a block of a flat-rowed pool is one contiguous piece), scores each
-    position against the query of the block's slot, masks by
-    ``position < lengths[slot]`` (and the band ``> lengths[slot] − W``
-    under a window) and folds the tile into the slots' running
-    max / sum / weighted value, float32 throughout; K and V are promoted
-    from the pool's dtype where they are multiplied and the softmax
-    weights narrowed to it, as the dense decode paths do. The running
-    state starts from the fresh row, which every slot sees, so no row of
-    the softmax is ever empty. Everything keeps a row flat
-    (``[.., Hkv·Dh]``) and sums or spreads over a head's ``Dh`` lanes by
-    a product with the 0/1 head-membership matrix: the chip tiles an
-    array by its two minor axes, and ``[.., Hkv, Dh]`` would be padded
-    there. ``scopes`` names the arithmetic and the reads of the pool
-    (a caller with attention kinds of its own names them by kind)."""
+    The loop runs ``walked / tile`` turns — a number the device computes,
+    so a chunk with two residents and one with eight are one program. A
+    turn gathers one tile of blocks out of layer ``layer`` (a block of a
+    flat-rowed pool is one contiguous piece), all of one slot ``s``,
+    masks by ``position < lengths[s]`` (and the band ``> lengths[s] − W``
+    under a window) and folds the tile into slot ``s``'s running max /
+    sum / weighted value, float32; no other slot's state is touched. A
+    turn is two matrix products over the tile's rows kept flat
+    (``[P, Hkv·Dh]``: the chip tiles an array by its two minor axes, and
+    ``[.., Hkv, Dh]`` would be padded there): the scores, of the slot's
+    queries laid block-diagonally (``[Hkv·G, Hkv·Dh]``, each query in its
+    KV head's lanes) against the tile, and ``P · V``, whose diagonal
+    blocks are each query's weighted value. The scores are exact against
+    the float32 query: at the highest precision, or, where the tile's K
+    is bfloat16, as the sum of three bfloat16 products of the query split
+    in three (each product of two bfloat16 numbers is exact in float32).
+    The softmax weights are narrowed to V's dtype before ``P · V``, as
+    the dense decode paths do; products accumulate in float32. The
+    running state starts from the fresh row, which every slot sees, so
+    no row of the softmax is ever empty. ``scopes`` names the arithmetic
+    and the reads of the pool (a caller with attention kinds of its own
+    names them by kind)."""
     core, gather = scopes
     s, hq, dh = q.shape
     hkv = k_row.shape[1]
     g, hd = hq // hkv, hkv * dh
     bs = k_pool.shape[2]
-    tile = _tile_blocks(bs, live.block.shape[0])
-    f32 = jnp.float32
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, f32))
-    # head-membership: lane c of a flat row belongs to KV head c // Dh
-    member = (jnp.arange(hd)[:, None] // dh == jnp.arange(hkv)[None, :])
+    # E is every slot's table in whole tiles
+    tile = _tile_blocks(bs, live.block.shape[0] // s)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = lax.Precision.HIGHEST
+    k_dtype = k_pool.dtype if k_scale is None else k_row.dtype
+    # lane c of a flat row belongs to KV head c // Dh: [Hkv, 1, Hkv·Dh]
+    mine = (jnp.arange(hkv)[:, None] == jnp.arange(hd)[None, :] // dh)[
+        :, None, :]
 
-    def per_head(x):  # [.., Hkv·Dh] -> [.., Hkv], summed over Dh
-        return jnp.einsum(
-            "...c,ch->...h", x, member.astype(f32),
-            precision=lax.Precision.HIGHEST, preferred_element_type=f32,
-        )
-
-    def per_lane(x):  # [.., Hkv] -> [.., Hkv·Dh], each value Dh times
-        return jnp.einsum(
-            "...h,ch->...c", x, member.astype(x.dtype),
-            precision=lax.Precision.HIGHEST, preferred_element_type=x.dtype,
-        )
+    def lanes(x):  # [.., Hkv·G] (head-major) -> [.., G, Hkv·Dh]
+        x = x.reshape(x.shape[:-1] + (hkv, g))
+        return jnp.repeat(jnp.swapaxes(x, -1, -2), dh, axis=-1)
 
     with jax.named_scope(core):
-        # [S, G, Hkv·Dh]: group member g of every KV head, rows flat
-        qf = jnp.swapaxes(group_query_heads(q, hkv), 1, 2).reshape(s, g, hd)
-        qf = qf.astype(f32) * scale
-        kf = k_row.reshape(s, 1, hd).astype(f32)
-        m0 = per_head(qf * kf)  # [S, G, Hkv]: the fresh key's score
+        qg = group_query_heads(q.astype(f32), hkv) / jnp.sqrt(f32(dh))
+        m0 = jnp.einsum(  # [S, Hkv·G]: the fresh key's score
+            "shgd,shd->shg", qg, k_row.astype(f32), precision=hi
+        ).reshape(s, hkv * g)
         l0 = jnp.ones_like(m0)
-        acc0 = jnp.broadcast_to(
+        acc0 = jnp.broadcast_to(  # [S, G·Hkv·Dh]: a slot's G rows, flat
             v_row.reshape(s, 1, hd).astype(f32), (s, g, hd)
-        )
+        ).reshape(s, g * hd)
+        qf = jnp.swapaxes(qg, 1, 2).reshape(s, g, hd)  # [S, G, Hkv·Dh]
+        if k_dtype == bf16:  # q = q1 + q2 + q3 exactly, each bfloat16
+            q1 = qf.astype(bf16)
+            q2 = (qf - q1.astype(f32)).astype(bf16)
+            q3 = (qf - q1.astype(f32) - q2.astype(f32)).astype(bf16)
+            parts = jnp.stack([q1, q2, q3], axis=1)  # [S, 3, G, Hkv·Dh]
+        else:
+            parts = qf[:, None]
 
     def read(pool, scales, ids):
         with jax.named_scope(gather):
@@ -285,51 +301,53 @@ def paged_decode_attention(
                     scales.at[layer, ids].get(mode="clip"),
                     k_row.dtype,
                 )
-            return got.reshape(tile, bs, hd)
+            return got.reshape(tile * bs, hd)
 
     def turn(t, carry):
         m, l, acc = carry
-        at = (t * tile,)
-        ids = lax.dynamic_slice(live.block, at, (tile,))
-        slot = lax.dynamic_slice(live.slot, at, (tile,))
-        start = lax.dynamic_slice(live.start, at, (tile,))
-        kt = read(k_pool, k_scale, ids)
+        at = t * tile
+        ids = lax.dynamic_slice(live.block, (at,), (tile,))
+        start = lax.dynamic_slice(live.start, (at,), (tile,))
+        slot = lax.dynamic_index_in_dim(live.slot, at, keepdims=False)
+        kt = read(k_pool, k_scale, ids)  # [P, Hkv·Dh]
         vt = read(v_pool, v_scale, ids)
-        with jax.named_scope(core):
-            upto = lengths[slot]  # [T]
-            pos = start[:, None] + jnp.arange(bs)[None, :]  # [T, bs]
-            valid = pos < upto[:, None]
-            if window is not None:
-                valid &= pos > upto[:, None] - window
-            valid = valid[:, :, None, None]
-            scores = per_head(
-                kt.astype(f32)[:, :, None, :] * qf[slot][:, None]
-            )  # [T, bs, G, Hkv]
-            scores = jnp.where(valid, scores, _NEG_INF)
-            mine = slot[None, :] == jnp.arange(s)[:, None]  # [S, T]
-            mine = mine[:, :, None, None]
-            m_new = jnp.maximum(m, jnp.max(
-                jnp.where(mine, scores.max(axis=1)[None], _NEG_INF), axis=1
-            ))
-            shrink = jnp.exp(m - m_new)
-            w = jnp.where(
-                valid, jnp.exp(scores - m_new[slot][:, None]), 0.0
-            )  # [T, bs, G, Hkv]
-            wv = (
-                per_lane(w.astype(vt.dtype)).astype(f32)
-                * vt.astype(f32)[:, :, None, :]
-            ).sum(axis=1)  # [T, G, Hkv·Dh]: each block's weighted value
-            l = l * shrink + jnp.where(mine, w.sum(axis=1)[None], 0.0).sum(1)
-            acc = acc * per_lane(shrink) + jnp.where(
-                mine, wv[None], 0.0
-            ).sum(axis=1)
-        return m_new, l, acc
 
-    m, l, acc = lax.fori_loop(
-        0, blocks_walked(live, bs) // tile, turn, (m0, l0, acc0)
-    )
+        def row(x):  # slot's row of a per-slot array
+            return lax.dynamic_index_in_dim(x, slot, keepdims=False)
+
+        def put(x, new):
+            return lax.dynamic_update_index_in_dim(x, new, slot, 0)
+
+        with jax.named_scope(core):
+            upto = row(lengths)
+            pos = (start[:, None] + jnp.arange(bs)[None, :]).reshape(-1)
+            valid = pos < upto
+            if window is not None:
+                valid &= pos > upto - window
+            qs = row(parts)  # [parts, G, Hkv·Dh]
+            bd = jnp.where(mine, qs[:, None], 0).reshape(-1, hd)
+            scores = jnp.einsum(
+                "qc,pc->qp", bd, kt.astype(bd.dtype), precision=hi,
+                preferred_element_type=f32,
+            ).reshape(qs.shape[0], hkv * g, -1).sum(axis=0)  # [Hkv·G, P]
+            scores = jnp.where(valid[None, :], scores, _NEG_INF)
+            m_old = row(m)
+            m_new = jnp.maximum(m_old, scores.max(axis=1))
+            shrink = jnp.exp(m_old - m_new)
+            w = jnp.exp(scores - m_new[:, None])  # 0 where masked
+            pv = jnp.einsum(
+                "qp,pc->qc", w.astype(vt.dtype), vt, precision=hi,
+                preferred_element_type=f32,
+            ).reshape(hkv, g, hd)
+            pv = jnp.where(mine, pv, 0.0).sum(axis=0)  # [G, Hkv·Dh]
+            l = put(l, row(l) * shrink + w.sum(axis=1))
+            acc = put(acc, (
+                row(acc).reshape(g, hd) * lanes(shrink) + pv).reshape(-1))
+        return put(m, m_new), l, acc
+
+    m, l, acc = lax.fori_loop(0, live.walked // tile, turn, (m0, l0, acc0))
     with jax.named_scope(core):
-        out = (acc / per_lane(l)).reshape(s, g, hkv, dh)
+        out = (acc.reshape(s, g, hd) / lanes(l)).reshape(s, g, hkv, dh)
         return jnp.swapaxes(out, 1, 2)  # [S, Hkv, G, Dh]
 
 

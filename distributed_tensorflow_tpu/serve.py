@@ -1013,8 +1013,8 @@ class TextServer:
         are resident, made here once for the whole chunk
         (every block a slot active now can reach by the chunk's end);
         the token block then carries two more rows, the list's real
-        entries and the entries a step walks (that count rounded up to
-        whole tiles), each repeated over the slots: they reach the host
+        entries and the entries a step walks (each slot's count rounded
+        up to whole tiles), each repeated over the slots: they reach the host
         in the fetch that brings the tokens (:meth:`_account_delivery`).
         A model with window layers adds one row per expert layer: the
         distinct experts its steps' tokens chose, summed over the chunk."""
@@ -1042,9 +1042,7 @@ class TextServer:
                 pool_shape[1], pool_shape[2],
             )
             decode = functools.partial(decode, live=live)
-            walked = jnp.stack(
-                [live.live, paged_attention.blocks_walked(live, pool_shape[2])]
-            )
+            walked = jnp.stack([live.live, live.walked])
 
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
@@ -2101,7 +2099,8 @@ class TextServer:
         pool through a live-block list, is the two counts the program
         sent along with its tokens: ``kv_blocks_live``, the list's real
         entries, and ``kv_blocks_read``, the entries one step of one
-        layer walks (the first rounded up to whole tiles); the second ÷
+        layer walks (each slot's share of the first rounded up to whole
+        tiles: one slot a tile); the second ÷
         (``slots`` × a table's blocks) is the share of a whole-table
         read that is left. Behind them, for a model with window layers,
         ``experts_touched``: per expert layer, the distinct experts the

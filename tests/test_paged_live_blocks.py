@@ -8,7 +8,9 @@ put the fresh row at ``lengths``, mask ``idx <= lengths`` (and the window's
 band) and run one softmax over the view. Ragged residency is the point:
 vacant slots, a slot that stops inside the chunk, lengths on a block's
 edge and at the table's end, lists longer than a tile, a block two slots
-share, a window, a quantized pool.
+share, a window, a quantized pool, a bfloat16 pool, six queries a KV head.
+Every tile of the list holds one slot's blocks: each slot's entries are
+padded up to whole tiles.
 """
 
 import numpy as np
@@ -29,7 +31,10 @@ LAYERS, LAYER = 3, 1
 def dense_reference(q, k_row, v_row, k_pool, v_pool, tables, lengths, act,
                     window=None):
     """The parent's step, one layer: the whole-table view with the fresh
-    row selected into it (active rows only), one masked softmax."""
+    row selected into it (active rows only), one masked softmax. Over a
+    bfloat16 pool the weights meet V narrowed to it, as the walk narrows
+    them: ``exp(score − max)`` rounded to bfloat16, over the float32 sum
+    (the walk's arithmetic where every slot fits one tile)."""
     def view(pool, row):
         got = pool[LAYER][tables]  # [S, NB, bs, Hkv, Dh]
         got = got.reshape(got.shape[0], -1, *got.shape[3:])
@@ -41,11 +46,17 @@ def dense_reference(q, k_row, v_row, k_pool, v_pool, tables, lengths, act,
     valid = idx <= lengths[:, None]
     if window is not None:
         valid &= idx > lengths[:, None] - window
-    qg = q.reshape(q.shape[0], HKV, HQ // HKV, DH)
+    qg = q.reshape(q.shape[0], HKV, q.shape[1] // HKV, DH)
     scores = jnp.einsum("shgd,skhd->shgk", qg, ck.astype(jnp.float32))
     scores = jnp.where(valid[:, None, None], scores / np.sqrt(DH), -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("shgk,skhd->shgd", w, cv.astype(jnp.float32))
+    if cv.dtype != jnp.bfloat16:
+        w = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("shgk,skhd->shgd", w, cv.astype(jnp.float32))
+    w = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    fresh = (jnp.arange(ck.shape[1])[None] == lengths[:, None])[:, None, None]
+    narrowed = jnp.where(fresh, w, w.astype(jnp.bfloat16).astype(jnp.float32))
+    out = jnp.einsum("shgk,skhd->shgd", narrowed, cv.astype(jnp.float32))
+    return out / w.sum(axis=-1)[..., None]
 
 
 def make_pools(rng, kv_dtype=None):
@@ -54,6 +65,8 @@ def make_pools(rng, kv_dtype=None):
     v = jnp.asarray(rng.normal(size=shape), jnp.float32)
     if kv_dtype is None:
         return k, v, None, None
+    if kv_dtype == "bfloat16":
+        return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), None, None
     (k, ks), (v, vs) = quantize_kv(k, kv_dtype), quantize_kv(v, kv_dtype)
     return k, v, ks, vs
 
@@ -77,32 +90,42 @@ def attend(q, k_row, v_row, pools, tables, lengths, act, *, steps=1,
 
 
 CASES = {
-    # name: (lengths, active, window, kv_dtype, tile positions, flat rows)
-    "ragged": ([5, 17, 0, 9], [1, 1, 1, 1], None, None, 512, False),
-    "one_resident": ([0, 13, 0, 0], [0, 1, 0, 0], None, None, 512, True),
-    "block_edge": ([4, 8, 16, 20], [1, 1, 1, 1], None, None, 512, False),
-    "table_end": ([23, 1, 23, 12], [1, 1, 1, 1], None, None, 512, True),
-    "many_tiles": ([21, 17, 23, 19], [1, 1, 1, 1], None, None, 8, True),
-    "tile_of_one_block": ([21, 3, 11, 6], [1, 0, 1, 1], None, None, 4, False),
-    "window": ([5, 17, 23, 9], [1, 1, 1, 1], 6, None, 8, True),
-    "int8": ([5, 17, 2, 9], [1, 1, 0, 1], None, "int8", 8, True),
-    "int8_window": ([20, 17, 2, 23], [1, 1, 1, 1], 5, "int8", 512, False),
+    # name: (lengths, active, window, kv_dtype, tile positions, flat rows,
+    #        query heads)
+    "ragged": ([5, 17, 0, 9], [1, 1, 1, 1], None, None, 512, False, HQ),
+    "one_resident": ([0, 13, 0, 0], [0, 1, 0, 0], None, None, 512, True, HQ),
+    "block_edge": ([4, 8, 16, 20], [1, 1, 1, 1], None, None, 512, False, HQ),
+    "table_end": ([23, 1, 23, 12], [1, 1, 1, 1], None, None, 512, True, HQ),
+    "many_tiles": ([21, 17, 23, 19], [1, 1, 1, 1], None, None, 8, True, HQ),
+    "tile_of_one_block": (
+        [21, 3, 11, 6], [1, 0, 1, 1], None, None, 4, False, HQ),
+    "window": ([5, 17, 23, 9], [1, 1, 1, 1], 6, None, 8, True, HQ),
+    "int8": ([5, 17, 2, 9], [1, 1, 0, 1], None, "int8", 8, True, HQ),
+    "int8_window": ([20, 17, 2, 23], [1, 1, 1, 1], 5, "int8", 512, False, HQ),
+    # six queries a KV head; slot 0 spans three tiles, slot 1 one block
+    "group_of_six": ([21, 2, 0, 14], [1, 1, 0, 1], None, None, 8, True, 12),
+    # float32 queries against a bfloat16 pool: the scores' split product
+    "bf16_pool": ([5, 17, 0, 23], [1, 1, 0, 1], None, "bfloat16", 512, False,
+                  12),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_list_walk_equals_the_whole_table_softmax(case, monkeypatch):
-    lengths, act, window, kv_dtype, tile_positions, flat = CASES[case]
+    lengths, act, window, kv_dtype, tile_positions, flat, hq = CASES[case]
     monkeypatch.setattr(paged, "TILE_POSITIONS", tile_positions)
     rng = np.random.default_rng(sorted(CASES).index(case))
     lengths = jnp.asarray(lengths, jnp.int32)
     act = jnp.asarray(act, bool)
     pools = make_pools(rng, kv_dtype)
     tables = disjoint_tables(rng)
-    q = jnp.asarray(rng.normal(size=(S, HQ, DH)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(S, hq, DH)), jnp.float32)
     k_row = jnp.asarray(rng.normal(size=(S, HKV, DH)), jnp.float32)
     v_row = jnp.asarray(rng.normal(size=(S, HKV, DH)), jnp.float32)
-    if kv_dtype is not None:  # a quantized pool attends what it will hold
+    if kv_dtype == "bfloat16":  # the rows as the pool will hold them
+        k_row, v_row = k_row.astype(jnp.bfloat16), v_row.astype(jnp.bfloat16)
+        dense_pools = pools[:2]
+    elif kv_dtype is not None:  # a quantized pool attends what it will hold
         k_row = dequantize_kv(*quantize_kv(k_row, kv_dtype))
         v_row = dequantize_kv(*quantize_kv(v_row, kv_dtype))
         dense_pools = (dequantize_kv(pools[0], pools[2]),
@@ -131,7 +154,7 @@ def test_a_block_two_slots_share_is_read_for_each(monkeypatch):
     act = jnp.asarray([1, 1, 1, 0], bool)
     live = paged.live_block_list(tables, lengths, act, 1, NUM_BLOCKS, BS)
     assert int(live.live) == 4 + 2 + 3
-    blocks = np.asarray(live.block)[: int(live.live)]
+    blocks = np.asarray(live.block)
     assert sorted(blocks).count(int(tables[0, 0])) == 2
     q, k_row, v_row = (
         jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -158,7 +181,7 @@ def test_one_list_serves_a_chunk_in_which_a_slot_stops(monkeypatch):
     live = paged.live_block_list(tables, lengths, start, chunk, NUM_BLOCKS, BS)
     # positions below lengths + chunk - 1 are all a step can read
     assert int(live.live) == 3 + 4 + 0 + 2
-    assert int(paged.blocks_walked(live, BS)) == 10
+    assert int(live.walked) == 4 + 4 + 0 + 2  # each slot's, whole tiles
     for step in range(chunk):
         act = start & jnp.asarray([True, step < 2, False, True])
         q, k_row, v_row = (
@@ -185,16 +208,55 @@ def test_the_list_is_compact_ordered_and_skips_what_was_never_reserved():
     live = paged.live_block_list(
         jnp.asarray(tables), jnp.asarray([5, 2, 9], jnp.int32),
         jnp.asarray([True, True, False]), 4, 40, 4)
-    n = int(live.live)
-    assert n == 2 + 1  # slot 0 reads below 8; slot 1's second entry is none
-    assert np.asarray(live.block)[:n].tolist() == [7, 2, 4]
-    assert np.asarray(live.slot)[:n].tolist() == [0, 0, 1]
-    assert np.asarray(live.start)[:n].tolist() == [0, 4, 0]
-    # past the live count: the sentinel block, starting past every length
-    assert set(np.asarray(live.block)[n:].tolist()) == {40}
-    assert set(np.asarray(live.start)[n:].tolist()) == {20}
-    assert live.block.shape == (15,)  # the tables' size: one tile holds it
-    assert int(paged.blocks_walked(live, 4)) == 15
+    assert int(live.live) == 2 + 1  # slot 0 reads below 8; slot 1's
+    # second entry is none. A tile is a table's 5 blocks: each slot's
+    # entries come first in its own tile, the sentinel after them
+    block, slot, start = (
+        np.asarray(a).tolist() for a in (live.block, live.slot, live.start))
+    assert block[:10] == [7, 2, 40, 40, 40, 4, 40, 40, 40, 40]
+    assert slot[:10] == [0] * 5 + [1] * 5
+    assert start[:2] == [0, 4] and start[5] == 0
+    # padding and the entries past the walk: the sentinel block,
+    # starting past every length
+    assert set(start[2:5] + start[6:]) == {20}
+    assert set(block[10:]) == {40}
+    assert live.block.shape == (15,)  # three tables' size in whole tiles
+    assert int(live.walked) == 10  # two slots × one tile of 5
+
+
+@pytest.mark.parametrize("tile_positions", [8, 12, 512])
+def test_every_tile_holds_one_slot_and_pads_past_its_length(
+        tile_positions, monkeypatch):
+    """However the residents' blocks fall against the tiles, a tile's
+    entries all belong to one slot, a slot's real entries come first in
+    block order, and its padding names the sentinel and starts past the
+    slot's length; the tiles walked are the slots' own, rounded up."""
+    monkeypatch.setattr(paged, "TILE_POSITIONS", tile_positions)
+    rng = np.random.default_rng(7)
+    tables = disjoint_tables(rng)
+    lengths = jnp.asarray([9, 0, 23, 4], jnp.int32)
+    act = jnp.asarray([1, 0, 1, 1], bool)
+    live = paged.live_block_list(tables, lengths, act, 2, NUM_BLOCKS, BS)
+    tile = min(tile_positions // BS, NB_SLOT)
+    block, slot, start = (
+        np.asarray(a) for a in (live.block, live.slot, live.start))
+    walked = int(live.walked)
+    need = {0: 3, 2: 6, 3: 2}  # blocks below lengths + 1 (two steps)
+    assert walked == sum(-(-n // tile) * tile for n in need.values())
+    assert int(live.live) == sum(need.values())
+    for t in range(walked // tile):
+        owner = set(slot[t * tile:(t + 1) * tile].tolist())
+        assert len(owner) == 1 and owner <= set(need)
+    for owner, n in need.items():
+        mine = slot[:walked] == owner
+        assert block[:walked][mine][:n].tolist() == (
+            np.asarray(tables)[owner, :n].tolist())
+        assert start[:walked][mine][:n].tolist() == [
+            i * BS for i in range(n)]
+        # the padding: the sentinel, past what two steps can reach
+        assert (block[:walked][mine][n:] == NUM_BLOCKS).all()
+        assert (start[:walked][mine][n:] >= int(lengths[owner]) + 2).all()
+    assert (block[walked:] == NUM_BLOCKS).all()
 
 
 def _model(window=None):
@@ -290,10 +352,12 @@ def test_decode_chunk_spans_carry_the_list_s_counts(monkeypatch):
         assert span["args"]["active"] == len(resident)
         # the pool holds prompt + done - 1 rows; the chunk reads below
         # that + chunk - 1
-        live = sum(
-            -(-(prompts[i] + done[i] - 1 + chunk - 1) // bs) for i in resident)
-        assert span["args"]["kv_blocks_live"] == live
-        assert span["args"]["kv_blocks_read"] == -(-live // 4) * 4
+        blocks = [
+            -(-(prompts[i] + done[i] - 1 + chunk - 1) // bs) for i in resident]
+        assert span["args"]["kv_blocks_live"] == sum(blocks)
+        # one slot a tile: each slot's blocks rounded up to whole tiles
+        assert span["args"]["kv_blocks_read"] == sum(
+            -(-n // 4) * 4 for n in blocks)
         for i in resident:
             done[i] = min(budgets[i], done[i] + chunk)
     assert len(spans) == 3 and done == budgets
