@@ -165,11 +165,13 @@ class PagedKVCache(NamedTuple):
     the payload). ``kv_dtype="bf16"`` keeps scales ``None``: the
     round-11 bitwise path.
 
-    A model with window layers (:class:`~.hybrid.HybridLM`) keeps their
-    K/V out of the pool, in a ring of blocks per slot (``ring_k`` /
-    ``ring_v``, ``ops/paged_attention`` says how it is addressed); the
-    pool and its tables then hold the full-attention layers alone. An
-    extend over fewer rows than there are slots names each row's slot in
+    A model whose layers keep state of their own per slot
+    (:class:`~.hybrid.HybridLM`: a window layer's ring of K/V blocks, a
+    delta-rule layer's recurrent and convolution states) holds it in
+    ``slot_state``, a dict of arrays (or tuples of them) that the model
+    declares in its ``empty_paged_cache`` and alone reads; the pool and
+    its tables then hold the full-attention layers alone. An extend over
+    fewer rows than there are slots names each row's slot in
     ``slot_of_row`` (None: row ``s`` is slot ``s``)."""
 
     k: jax.Array  # [num_layers, num_blocks, block_size, Hkv, Dh]
@@ -178,8 +180,7 @@ class PagedKVCache(NamedTuple):
     lengths: jax.Array  # [S] int32 — tokens written for each slot
     k_scale: jax.Array | None = None  # [num_layers, num_blocks, bs, Hkv] f32
     v_scale: jax.Array | None = None
-    ring_k: jax.Array | None = None  # [window layers, S*ring, bs, Hkv, Dh]
-    ring_v: jax.Array | None = None
+    slot_state: dict | None = None  # the model's per-slot state, by name
     slot_of_row: jax.Array | None = None  # [rows] int32
 
 

@@ -6,7 +6,7 @@ The residual stream ``h`` runs through ``len(pattern)`` layers, each ONE
 mixer behind one pre-norm — ``h <- h + mixer(RMSNorm(h))``; a block that
 pairs a token mixer with a feed-forward is two letters, there is no pair
 in the code — then a final RMSNorm and an untied head.
-The pattern is a string over eight letters:
+The pattern is a string over nine letters:
 
 - ``M`` — a Mamba-2 mixer: ``[z | xBC | dt] = in_proj(u)``; ``xBC`` through
   a causal depthwise convolution (with bias) and silu; split into ``x``
@@ -54,14 +54,38 @@ The pattern is a string over eight letters:
   (``full_rotary``, ``window_rotary``: a base, the leading dims that turn,
   YaRN where given), scores scaled by ``head_dim^-1/2``, causal; each
   head's output times ``sigmoid(u W_gate)`` of that head (``u`` the
-  layer's normed input) before ``wo``. No bias, no norm on q or k.
+  layer's normed input) before ``wo``. No bias, no norm on q or k. A
+  ``G`` layer may instead (``full_gate="element"``) gate each element of
+  each head's output by its own column of ``W_gate`` (``heads x head_dim``
+  wide), and (``full_qk_norm``) RMS-normalise each head of q and of k over
+  ``head_dim`` by a learned weight before the rotation.
+- ``N`` — a gated delta-rule mixer with ONE decay per head ("Gated
+  DeltaNet"): ``[q | k | v | z] = u W_qkvz`` and ``[b | a] = u W_ba``;
+  ``q, k, v`` through a causal depthwise convolution (no bias) and silu;
+  ``gdn_key_heads`` heads of q and k (``gdn_key_dim`` wide), L2-normalised,
+  ``q`` times ``gdn_key_dim^-1/2``; ``gdn_value_heads`` heads of v
+  (``gdn_value_dim``), value head ``j`` reading key head ``j // (value
+  heads / key heads)``; ``beta = sigmoid(b)`` and the log-decay ``g =
+  -exp(A_log) * softplus(a + dt_bias)``, one each per value head and
+  token; the float32 recurrence of :mod:`~distributed_tensorflow_tpu.ops.kda`
+  with ``g`` the same for every key channel; an RMSNorm over each head
+  with one learned weight, times ``silu(z)``; ``out_proj``.
+
+With ``moe_router="softmax"`` an ``E`` layer routes by float32 softmax
+probabilities over all experts, the ``experts_per_token`` most probable
+weighed by their probabilities renormalised over the chosen (no bias, no
+scale), and its shared expert's output is multiplied by ``sigmoid(u
+w_shared)`` (``shared_router``, one column). With ``norm_offset`` 1 every
+RMSNorm of the stack but a mixer's inner ones (the kinds' output norms,
+latent attention's) is zero-centred: ``x / rms(x) * (1 + w)``.
 
 Parameters are stacked per kind (``mamba[n_M]``, ``moe[n_E]``,
 ``attn[n_*]``, ``kda[n_K]``, ``mla[n_L]``, ``dense[n_D]``, ``full[n_G]``,
-``window[n_W]``) and the layers run unrolled in pattern order, each taking
-its kind's next slice. A pattern over ``M``, ``E``, ``*`` alone keeps the
-tree it always had (:class:`HybridLMParams`); one that uses ``K``, ``L``,
-``D``, ``G`` or ``W`` has :class:`StackLMParams`. Matmul operands are
+``window[n_W]``, ``gdn[n_N]``) and the layers run unrolled in pattern
+order, each taking its kind's next slice. A pattern over ``M``, ``E``,
+``*`` alone keeps the tree it always had (:class:`HybridLMParams`); one
+that uses ``K``, ``L``, ``D``, ``G``, ``W`` or ``N`` has
+:class:`StackLMParams`. Matmul operands are
 ``compute_dtype`` with float32 accumulation; norms, the router, the convolutions, both recurrences (their
 decays, states and solves) and the loss are float32. ``remat`` checkpoints
 each layer through the same policy surface as
@@ -80,15 +104,19 @@ that many rounds of an auction
 a training recipe holds the load even with. Without it (None, the
 default) the buffer is used as it stands.
 
-A pattern over ``G``, ``W``, ``D`` and ``E`` is also served by
+A pattern over ``G``, ``W``, ``N``, ``D`` and ``E`` is also served by
 :class:`~distributed_tensorflow_tpu.serve.TextServer` through the paged
 interface :class:`~..gpt.GPTLM` gives it (``empty_paged_cache``,
 ``extend_paged``, ``decode_paged``, ``paged_blocks_per_slot``,
 ``max_len``, ``num_layers``, ``num_kv_heads``, ``head_dim``): the ``G``
-layers' K/V in the server's block pool, the ``W`` layers' in a ring of
-``ring_blocks`` blocks per slot (``ops/paged_attention``). The prefill
-reads no cached prefix and returns the logits of each row's last
-position only; the experts hold every expert and drop nothing.
+layers' K/V in the server's block pool; per slot, in the cache's
+``slot_state``, the ``W`` layers' K/V in a ring of ``ring_blocks`` blocks
+(``ops/paged_attention``) and an ``N`` layer's recurrent state ``[value
+heads, key dim, value dim]`` float32 and its last ``conv_kernel - 1``
+rows before the convolution, written whole by the prefill at each row's
+real length and advanced a token a decode step. The prefill reads no
+cached prefix and returns the logits of each row's last position only;
+the experts hold their share and drop nothing.
 """
 
 from __future__ import annotations
@@ -104,18 +132,23 @@ from distributed_tensorflow_tpu.models.gpt import (
 from distributed_tensorflow_tpu.observability import names
 from distributed_tensorflow_tpu.ops import paged_attention as paged
 from distributed_tensorflow_tpu.ops import rotary
-from distributed_tensorflow_tpu.ops.kda import kda_chunked
-from distributed_tensorflow_tpu.ops.moe import moe_ffn_held
+from distributed_tensorflow_tpu.ops.kda import kda_chunked, kda_step
+from distributed_tensorflow_tpu.ops.moe import (
+    moe_ffn_held, route_sigmoid_topk, route_softmax_topk,
+)
 from distributed_tensorflow_tpu.ops.ring_attention import dense_attention
 from distributed_tensorflow_tpu.ops.ssd import ssd_chunked
 
 KINDS = ("M", "E", "*")
 # a pattern that uses one of these has StackLMParams
-WIDE_KINDS = ("K", "L", "D", "G", "W")
+WIDE_KINDS = ("K", "L", "D", "G", "W", "N")
 GROUP_OF = {"M": "mamba", "E": "moe", "*": "attn",
-            "K": "kda", "L": "mla", "D": "dense", "G": "full", "W": "window"}
+            "K": "kda", "L": "mla", "D": "dense", "G": "full", "W": "window",
+            "N": "gdn"}
 EXPERT_FORMS = ("relu2", "silu_gated")
-SERVED_KINDS = ("G", "W", "D", "E")  # what the paged serving path runs
+GATE_FORMS = ("head", "element")  # a G layer's output gate
+ROUTERS = ("sigmoid", "softmax")  # an E layer's routing
+SERVED_KINDS = ("G", "W", "N", "D", "E")  # what the paged serving path runs
 
 
 class MambaParams(NamedTuple):
@@ -152,6 +185,35 @@ class GatedExpertParams(NamedTuple):
     shared_gate: jax.Array  # [n, d, shared_dim]
     shared_up: jax.Array  # [n, d, shared_dim]
     shared_down: jax.Array  # [n, shared_dim, d]
+
+
+class SoftmaxExpertParams(NamedTuple):
+    """``E`` with ``moe_router="softmax"`` (silu-gated experts): no
+    correction bias, and the shared expert's gate."""
+
+    norm: jax.Array  # [n, d]
+    router: jax.Array  # [n, d, num_experts] over ALL experts
+    w_gate: jax.Array  # [n, held, d, expert_dim]
+    w_up: jax.Array  # [n, held, d, expert_dim]
+    w_down: jax.Array  # [n, held, expert_dim, d]
+    shared_gate: jax.Array  # [n, d, shared_dim]
+    shared_up: jax.Array  # [n, d, shared_dim]
+    shared_down: jax.Array  # [n, shared_dim, d]
+    shared_router: jax.Array  # [n, d, 1]: the shared expert's sigmoid gate
+
+
+class GdnParams(NamedTuple):
+    """``N``: widths ``kd = key heads x key dim``, ``vd = value heads x
+    value dim``, ``hv`` value heads."""
+
+    norm: jax.Array  # [n, d]
+    in_qkvz: jax.Array  # [n, d, 2*kd + 2*vd]: q, k, v, z
+    in_ba: jax.Array  # [n, d, 2*hv]: b (write strength), a (decay)
+    conv_w: jax.Array  # [n, kernel, 2*kd + vd]; last row = now; no bias
+    dt_bias: jax.Array  # [n, hv]
+    a_log: jax.Array  # [n, hv]
+    out_norm: jax.Array  # [n, value dim]: one weight for every head
+    out_proj: jax.Array  # [n, vd, d]
 
 
 class KdaParams(NamedTuple):
@@ -198,8 +260,22 @@ class GatedAttnParams(NamedTuple):
     wq: jax.Array  # [n, d, heads*head_dim]
     wk: jax.Array  # [n, d, kv_heads*head_dim]
     wv: jax.Array  # [n, d, kv_heads*head_dim]
-    w_gate: jax.Array  # [n, d, heads]
+    w_gate: jax.Array  # [n, d, heads] (a gate per element: heads*head_dim)
     wo: jax.Array  # [n, heads*head_dim, d]
+
+
+class NormedGatedAttnParams(NamedTuple):
+    """``G`` with ``full_qk_norm``: a norm weight over a head of q and
+    one of k."""
+
+    norm: jax.Array  # [n, d]
+    wq: jax.Array  # [n, d, heads*head_dim]
+    wk: jax.Array  # [n, d, kv_heads*head_dim]
+    wv: jax.Array  # [n, d, kv_heads*head_dim]
+    w_gate: jax.Array  # [n, d, heads] (a gate per element: heads*head_dim)
+    wo: jax.Array  # [n, heads*head_dim, d]
+    q_norm: jax.Array  # [n, head_dim]
+    k_norm: jax.Array  # [n, head_dim]
 
 
 class HybridLMParams(NamedTuple):
@@ -212,21 +288,22 @@ class HybridLMParams(NamedTuple):
 
 
 class StackLMParams(NamedTuple):
-    """The tree of a pattern that uses ``K``, ``L``, ``D``, ``G`` or
-    ``W``: a stack for each kind (``moe`` in the form the model was built
-    with), None for a kind the pattern lacks."""
+    """The tree of a pattern that uses ``K``, ``L``, ``D``, ``G``, ``W``
+    or ``N``: a stack for each kind (``moe`` in the form the model was
+    built with), None for a kind the pattern lacks."""
 
     embed: jax.Array  # [vocab, d]
     mamba: MambaParams | None
-    moe: ExpertParams | GatedExpertParams | None
+    moe: ExpertParams | GatedExpertParams | SoftmaxExpertParams | None
     attn: AttnParams | None
     kda: KdaParams | None
     mla: MlaParams | None
     dense: DenseParams | None
     norm_f: jax.Array  # [d]
     head: jax.Array  # [d, vocab]
-    full: GatedAttnParams | None = None
+    full: GatedAttnParams | NormedGatedAttnParams | None = None
     window: GatedAttnParams | None = None
+    gdn: GdnParams | None = None
 
 
 def rmsnorm(x, weight, eps):
@@ -303,6 +380,14 @@ class HybridLM:
         window: int | None = None,
         full_rotary: rotary.Rotary | None = None,
         window_rotary: rotary.Rotary | None = None,
+        full_gate: str = "head",
+        full_qk_norm: bool = False,
+        gdn_key_heads: int = 0,
+        gdn_key_dim: int = 0,
+        gdn_value_heads: int = 0,
+        gdn_value_dim: int = 0,
+        moe_router: str = "sigmoid",
+        norm_offset: float = 0.0,
         max_len: int = 0,
         norm_eps: float = 1e-5,
         init_std: float = 0.02,
@@ -366,6 +451,26 @@ class HybridLM:
                     "number of a head's dims")
         if self.counts["W"] and (window is None or window < 1):
             raise ValueError("a W layer needs window >= 1")
+        if full_gate not in GATE_FORMS:
+            raise ValueError(
+                f"unknown full_gate {full_gate!r}; one of {GATE_FORMS}")
+        if self.counts["N"] and (
+                min(gdn_key_heads, gdn_key_dim, gdn_value_heads,
+                    gdn_value_dim) < 1 or gdn_value_heads % gdn_key_heads):
+            raise ValueError(
+                "an N layer needs gdn_key_heads, gdn_key_dim, "
+                "gdn_value_heads (a multiple of the key heads) and "
+                "gdn_value_dim")
+        if moe_router not in ROUTERS or (
+                moe_router == "softmax" and expert_form != "silu_gated"):
+            raise ValueError(
+                f"moe_router={moe_router!r}: one of {ROUTERS}; softmax "
+                "routing is built with silu_gated experts")
+        self.full_gate, self.full_qk_norm = full_gate, full_qk_norm
+        self.gdn_key_heads, self.gdn_key_dim = gdn_key_heads, gdn_key_dim
+        self.gdn_value_heads, self.gdn_value_dim = gdn_value_heads, gdn_value_dim
+        self.moe_router = moe_router
+        self.norm_offset = float(norm_offset)
         self.full_heads, self.window_heads = full_heads, window_heads
         self.window = window
         self.full_rotary, self.window_rotary = full_rotary, window_rotary
@@ -422,6 +527,12 @@ class HybridLM:
     @property
     def kda_inner(self) -> int:
         return self.kda_heads * self.kda_head_dim
+
+    @property
+    def gdn_widths(self) -> tuple[int, int]:
+        """(q and k's width each, v and z's width each) of an ``N`` layer."""
+        return (self.gdn_key_heads * self.gdn_key_dim,
+                self.gdn_value_heads * self.gdn_value_dim)
 
     # -- init ----------------------------------------------------------------
 
@@ -528,23 +639,62 @@ class HybridLM:
             jax.random.fold_in(jax.random.key(seed), 2), 12))
         kv = self.num_kv_heads * self.head_dim
 
-        def gated(n, heads):
+        def gated(n, heads, gate=1):
             return GatedAttnParams(
                 norm=jnp.ones((n, d), f32),
                 wq=normal((n, d, heads * self.head_dim)),
                 wk=normal((n, d, kv)), wv=normal((n, d, kv)),
-                w_gate=normal((n, d, heads)),
+                w_gate=normal((n, d, heads * gate)),
                 wo=normal((n, heads * self.head_dim, d), resid))
 
+        full = gated(self.counts["G"], self.full_heads,
+                     self.head_dim if self.full_gate == "element" else 1)
+        window = gated(self.counts["W"], self.window_heads)
+        # The kinds and options of the delta rule with a decay per head
+        # draw from a stream of their own; a zero-centred norm starts at 0.
+        keys = iter(jax.random.split(
+            jax.random.fold_in(jax.random.key(seed), 3), 8))
+        norm_w = 1.0 - self.norm_offset
+        if self.full_qk_norm:
+            qk = jnp.full((self.counts["G"], self.head_dim), norm_w, f32)
+            full = NormedGatedAttnParams(*full, q_norm=qk, k_norm=qk)
+        if self.moe_router == "softmax":
+            moe = SoftmaxExpertParams(
+                **{k: v for k, v in moe._asdict().items()
+                   if k != "router_bias"},
+                shared_router=normal((ne, d, 1)))
+        nn = self.counts["N"]
+        kd, vd = self.gdn_widths
+        hv = self.gdn_value_heads
+        gdn = GdnParams(
+            norm=jnp.ones((nn, d), f32),
+            in_qkvz=normal((nn, d, 2 * kd + 2 * vd)),
+            in_ba=normal((nn, d, 2 * hv)),
+            conv_w=uniform((nn, self.conv_kernel, 2 * kd + vd), -1.0, 1.0)
+            / math.sqrt(self.conv_kernel),
+            dt_bias=jnp.ones((nn, hv), f32),
+            a_log=jnp.log(uniform((nn, hv), 0.0, 16.0)),
+            out_norm=jnp.ones((nn, self.gdn_value_dim), f32),
+            out_proj=normal((nn, vd, d), resid),
+        )
         stacks = dict(mamba=mamba, moe=moe, attn=attn, kda=kda, mla=mla,
-                      dense=dense,
-                      full=gated(self.counts["G"], self.full_heads),
-                      window=gated(self.counts["W"], self.window_heads))
+                      dense=dense, full=full, window=window, gdn=gdn)
+        if self.norm_offset:  # every layer's norm and the final one
+            stacks = {g: t._replace(norm=jnp.full_like(t.norm, norm_w))
+                      for g, t in stacks.items()}
+            ends["norm_f"] = jnp.full_like(ends["norm_f"], norm_w)
         return StackLMParams(**ends, **{
             group: stacks[group] if self.counts[kind] else None
             for kind, group in GROUP_OF.items()})
 
     # -- pieces --------------------------------------------------------------
+
+    def _norm(self, x, weight):
+        """The stack's RMSNorm: ``weight`` as it stands, or ``1 + weight``
+        where the norms are zero-centred (``norm_offset``)."""
+        if self.norm_offset:
+            weight = weight.astype(jnp.float32) + self.norm_offset
+        return rmsnorm(x, weight, self.norm_eps)
 
     def _dot(self, x, w):
         if self.matmul_dtype is not None:
@@ -561,7 +711,7 @@ class HybridLM:
                         self.ssm_state)
         inner, cdim = self.ssm_inner, self.conv_dim
         with jax.named_scope(names.SSM_PROJ):
-            proj = self._dot(rmsnorm(h, p.norm, self.norm_eps), p.in_proj)
+            proj = self._dot(self._norm(h, p.norm), p.in_proj)
             z, xbc, dt = jnp.split(proj, [inner, inner + cdim], axis=-1)
         with jax.named_scope(names.SSM_CONV):
             xbc = self._causal_conv_silu(xbc, p.conv_w, p.conv_b)
@@ -585,18 +735,21 @@ class HybridLM:
         """``token_mask`` [B, L] (None: every token) keeps the tokens it
         is False for out of the routed experts and their load."""
         b, l, d = h.shape
-        with jax.named_scope(names.MOE_ROUTE):
-            u = rmsnorm(h, p.norm, self.norm_eps).reshape(b * l, d)
         gated = self.expert_form == "silu_gated"
+        softmax = self.moe_router == "softmax"
+        k = self.experts_per_token
+        with jax.named_scope(names.MOE_ROUTE):
+            u = self._norm(h, p.norm).reshape(b * l, d)
+            mask = None if token_mask is None else token_mask.reshape(b * l)
+            idx, w = (route_softmax_topk(u, p.router, k) if softmax
+                      else route_sigmoid_topk(
+                          u, p.router, p.router_bias, k, self.routed_scale,
+                          None if self.balance_rounds is None
+                          else (l, self.balance_rounds)))
         routed, load = moe_ffn_held(
-            u, p.router, p.router_bias, p.w_up, p.w_down,
-            first=self.experts_held[0], k=self.experts_per_token,
-            scale=self.routed_scale, w_gate=p.w_gate if gated else None,
-            compute_dtype=self.compute_dtype,
-            balance=None if self.balance_rounds is None
-            else (l, self.balance_rounds),
-            token_mask=None if token_mask is None
-            else token_mask.reshape(b * l))
+            u, idx, w, p.w_up, p.w_down, experts=p.router.shape[1],
+            first=self.experts_held[0], w_gate=p.w_gate if gated else None,
+            compute_dtype=self.compute_dtype, token_mask=mask)
         with jax.named_scope(names.MOE_SHARED):
             if gated:
                 shared = self._gated_ffn(
@@ -604,6 +757,8 @@ class HybridLM:
             else:
                 act = jnp.square(jax.nn.relu(self._dot(u, p.shared_up)))
                 shared = self._dot(act, p.shared_down)
+            if softmax:
+                shared = shared * jax.nn.sigmoid(self._dot(u, p.shared_router))
         return (routed + shared).reshape(b, l, d), load
 
     def _gated_ffn(self, u, w_gate, w_up, w_down):
@@ -612,7 +767,7 @@ class HybridLM:
 
     def _dense(self, p: DenseParams, h):
         with jax.named_scope(names.MLP):
-            return self._gated_ffn(rmsnorm(h, p.norm, self.norm_eps),
+            return self._gated_ffn(self._norm(h, p.norm),
                                    p.w_gate, p.w_up, p.w_down)
 
     def _causal_conv_silu(self, x, conv_w, bias=0.0):
@@ -629,7 +784,7 @@ class HybridLM:
                                self.kda_gate_rank, self.kda_inner)
         heads = (b, l, nh, hd)
         with jax.named_scope(names.KDA_PROJ):
-            proj = self._dot(rmsnorm(h, p.norm, self.norm_eps), p.in_proj)
+            proj = self._dot(self._norm(h, p.norm), p.in_proj)
             qkv, f, z, beta = jnp.split(
                 proj, [3 * inner, 3 * inner + rank, 3 * inner + 2 * rank],
                 axis=-1)
@@ -655,12 +810,86 @@ class HybridLM:
         with jax.named_scope(names.KDA_PROJ):
             return self._dot(o.reshape(b, l, inner), p.out_proj)
 
+    def _gdn_in(self, p: GdnParams, h):
+        """h [B, L, d] -> (q|k|v before the convolution [B, L, 2kd + vd],
+        z [B, L, vd], b and a [B, L, hv]), float32."""
+        kd, vd = self.gdn_widths
+        with jax.named_scope(names.GDN_PROJ):
+            u = self._norm(h, p.norm)
+            qkv, z = jnp.split(self._dot(u, p.in_qkvz), [2 * kd + vd], axis=-1)
+            b, a = jnp.split(self._dot(u, p.in_ba), 2, axis=-1)
+        return qkv, z, b, a
+
+    def _gdn_heads(self, p: GdnParams, qkv, b, a):
+        """The recurrence's inputs from the convolved ``qkv`` [..., 2kd +
+        vd] and ``b``, ``a`` [..., hv]: q and k L2-normalised and repeated
+        to the value heads [..., hv, dk], v [..., hv, dv], the log-decay
+        and the write strength [..., hv] (one per value head)."""
+        kd, _ = self.gdn_widths
+        hk, dk = self.gdn_key_heads, self.gdn_key_dim
+        hv, dv = self.gdn_value_heads, self.gdn_value_dim
+        lead = qkv.shape[:-1]
+        q, k, v = jnp.split(qkv, [kd, 2 * kd], axis=-1)
+        unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        q = unit(q.reshape(*lead, hk, dk)) * dk ** -0.5
+        k = unit(k.reshape(*lead, hk, dk))
+        q, k = (jnp.repeat(t, hv // hk, axis=-2) for t in (q, k))
+        g = -jnp.exp(p.a_log) * jax.nn.softplus(a + p.dt_bias)
+        return q, k, v.reshape(*lead, hv, dv), g, jax.nn.sigmoid(b)
+
+    def _gdn_out(self, p: GdnParams, o, z):
+        """o [..., hv, dv], z [..., vd] -> the layer's output [..., d]."""
+        with jax.named_scope(names.GDN_PROJ):
+            o = rmsnorm(o, p.out_norm, self.norm_eps) * jax.nn.silu(
+                z.reshape(o.shape))
+            return self._dot(o.reshape(*o.shape[:-2], -1), p.out_proj)
+
+    def _gdn_scan(self, p: GdnParams, h, valid=None):
+        """An ``N`` layer over whole sequences from a zero state: (its
+        output [B, L, d], the state after the last position [B, hv, dk,
+        dv] float32, q|k|v before the convolution). ``valid`` [B, L]
+        (None: every position) leaves the state as it stands at the
+        positions it is False for (a right-padded row's tail)."""
+        qkv, z, b, a = self._gdn_in(p, h)
+        with jax.named_scope(names.GDN_CONV):
+            conv = self._causal_conv_silu(qkv, p.conv_w)
+        with jax.named_scope(names.GDN_SCAN):
+            q, k, v, g, beta = self._gdn_heads(p, conv, b, a)
+            if valid is not None:
+                g = jnp.where(valid[..., None], g, 0.0)
+                beta = jnp.where(valid[..., None], beta, 0.0)
+            o, state = kda_chunked(
+                q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta,
+                precision=jax.lax.Precision.HIGHEST, final_state=True)
+        return self._gdn_out(p, o, z), state, qkv
+
+    def _gdn(self, p: GdnParams, h):
+        return self._gdn_scan(p, h)[0]
+
+    def _gdn_decode(self, p: GdnParams, h, state, conv, act):
+        """One token per slot: h [S, 1, d], the slots' state [S, hv, dk,
+        dv] and last ``conv_kernel - 1`` rows before the convolution
+        [S, kernel - 1, 2kd + vd] -> (output [S, 1, d], the state and
+        rows advanced where ``act``, as they were elsewhere)."""
+        qkv, z, b, a = self._gdn_in(p, h)
+        with jax.named_scope(names.GDN_CONV):
+            rows = jnp.concatenate([conv, qkv], axis=1)  # [S, kernel, C]
+            x = jax.nn.silu(sum(rows[:, j] * p.conv_w[j]
+                                for j in range(self.conv_kernel)))
+            conv = jnp.where(act[:, None, None], rows[:, 1:], conv)
+        with jax.named_scope(names.GDN_STATE):
+            q, k, v, g, beta = self._gdn_heads(p, x, b[:, 0], a[:, 0])
+            o, new = kda_step(state, q, k, v, g, beta)
+            state = jnp.where(act[:, None, None, None], new, state)
+        return self._gdn_out(p, o[:, None], z), state, conv
+
     def _mla(self, p: MlaParams, h):
         b, l, _ = h.shape
         cd, nh = self.compute_dtype, self.num_heads
         nope, shared, rank = self.qk_nope_dim, self.qk_shared_dim, self.kv_lora_rank
         with jax.named_scope(names.ATTN_QKV):
-            u = rmsnorm(h, p.norm, self.norm_eps)
+            u = self._norm(h, p.norm)
             q = self._dot(u, p.wq).reshape(b, l, nh, nope + shared)
             c, k_s = jnp.split(self._dot(u, p.w_dkv), [rank], axis=-1)
             kv = self._dot(rmsnorm(c, p.kv_norm, self.norm_eps), p.w_ukv)
@@ -696,26 +925,33 @@ class HybridLM:
     def _gated_qkv(self, p: GatedAttnParams, h, kind: str, positions):
         """h [B, L, d] at ``positions`` ([L] or [B, L]) -> q [B, L, Hq,
         Dh], k, v [B, L, Hkv, Dh] (turned, ``compute_dtype``) and the
-        heads' gates [B, L, Hq] float32."""
+        gates float32: the heads' [B, L, Hq], or each element's [B, L,
+        Hq, Dh] (``full_gate="element"``)."""
         b, l, _ = h.shape
         heads, rot, _, _ = self._kind(kind)
         cd, hd = self.compute_dtype, self.head_dim
         with jax.named_scope(names.ATTN_QKV):
-            u = rmsnorm(h, p.norm, self.norm_eps)
+            u = self._norm(h, p.norm)
             q = self._dot(u, p.wq).reshape(b, l, heads, hd)
             kv = (b, l, self.num_kv_heads, hd)
             k = self._dot(u, p.wk).reshape(kv)
             v = self._dot(u, p.wv).reshape(kv)
+            if kind == "G" and self.full_qk_norm:
+                q, k = self._norm(q, p.q_norm), self._norm(k, p.k_norm)
             q = rotary.apply(q, positions, rot)
             k = rotary.apply(k, positions, rot)
             gate = jax.nn.sigmoid(self._dot(u, p.w_gate))
+            if kind == "G" and self.full_gate == "element":
+                gate = gate.reshape(b, l, heads, hd)
         return q.astype(cd), k.astype(cd), v.astype(cd), gate
 
     def _gated_out(self, p: GatedAttnParams, a, gate):
         """a [B, L, Hq, Dh] -> the layer's output [B, L, d]."""
         b, l = a.shape[:2]
         with jax.named_scope(names.ATTN_OUT):
-            a = a.astype(jnp.float32) * gate[..., None]
+            # a gate per head, or one per element
+            a = a.astype(jnp.float32) * (
+                gate[..., None] if gate.ndim < a.ndim else gate)
             return self._dot(a.reshape(b, l, -1), p.wo)
 
     def _gated_attention(self, p: GatedAttnParams, h, kind: str):
@@ -730,7 +966,7 @@ class HybridLM:
         b, l, _ = h.shape
         cd = self.compute_dtype
         with jax.named_scope(names.ATTN_QKV):
-            u = rmsnorm(h, p.norm, self.norm_eps)
+            u = self._norm(h, p.norm)
             q = self._dot(u, p.wq).reshape(b, l, self.num_heads, self.head_dim)
             kv = (b, l, self.num_kv_heads, self.head_dim)
             k = self._dot(u, p.wk).reshape(kv)
@@ -765,7 +1001,7 @@ class HybridLM:
             h = params.embed[tokens].astype(jnp.float32)
         layers = _layer_iters(params)
         mixers = {"M": self._mamba, "*": self._attention, "K": self._kda,
-                  "L": self._mla, "D": self._dense,
+                  "L": self._mla, "D": self._dense, "N": self._gdn,
                   "G": lambda p, h: self._gated_attention(p, h, "G"),
                   "W": lambda p, h: self._gated_attention(p, h, "W")}
         rows = []
@@ -778,7 +1014,7 @@ class HybridLM:
             else:
                 h = h + self._remat_wrap(mixers[kind])(p, h)
         with jax.named_scope(names.LM_HEAD):
-            logits = self._dot(rmsnorm(h, params.norm_f, self.norm_eps),
+            logits = self._dot(self._norm(h, params.norm_f),
                                params.head)
         first, held = self.experts_held
         load = (jnp.stack(rows) if rows
@@ -808,6 +1044,10 @@ class HybridLM:
     # extend_paged gives each row's last position's logits, not every
     # position's (at 16k positions every position's would be GBs)
     extend_returns_last = True
+    # The cache carries per-slot state (``PagedKVCache.slot_state``) that a
+    # block pool's sharing, relocation and quantization do not cover, and
+    # the prefill reads no cached prefix: the server refuses what needs them.
+    per_slot_state = True
 
     @property
     def num_layers(self) -> int:
@@ -831,8 +1071,8 @@ class HybridLM:
     def _check_served(self) -> None:
         missing = {"M": "a state-space layer's recurrent state (ROADMAP "
                         "R-state)",
-                   "K": "a delta-rule layer's recurrent state (ROADMAP "
-                        "R-state)",
+                   "K": "a delta-rule layer's recurrent state with a decay "
+                        "per key channel (ROADMAP R-state)",
                    "L": "latent attention's compressed cache (ROADMAP "
                         "R-latent)",
                    "*": "attention without positions"}
@@ -849,9 +1089,15 @@ class HybridLM:
                           block_size: int = 16,
                           kv_dtype: str = "bf16") -> PagedKVCache:
         """A vacant cache: ``num_blocks`` pool blocks for the ``G`` layers,
-        tables of :meth:`paged_blocks_per_slot` entries, and for the ``W``
-        layers a ring of :meth:`ring_blocks` blocks per slot, all zero
-        (unreachable while lengths are 0). K/V are ``compute_dtype``."""
+        tables of :meth:`paged_blocks_per_slot` entries, and per slot, in
+        ``slot_state``: for the ``W`` layers a ring of :meth:`ring_blocks`
+        blocks (``ring_k``, ``ring_v``: [W layers, slots * ring, bs, Hkv,
+        Dh]); for each ``N`` layer its recurrent state (``gdn_state``, a
+        tuple of [slots, hv, dk, dv] float32) and its last ``conv_kernel -
+        1`` rows before the convolution (``gdn_conv``, a tuple of [slots,
+        kernel - 1, 2kd + vd] float32). All zero (unreachable while
+        lengths are 0; a prefill writes a slot's state whole). K/V are
+        ``compute_dtype``."""
         self._check_served()
         if kv_dtype != "bf16":
             raise ValueError(
@@ -861,30 +1107,45 @@ class HybridLM:
             raise ValueError("slots and num_blocks must be >= 1")
         row = (block_size, self.num_kv_heads, self.head_dim)
         pool = (self.counts["G"], num_blocks) + row
-        ring = (self.counts["W"], slots * self.ring_blocks(block_size)) + row
-        cd = self.compute_dtype
+        cd, f32 = self.compute_dtype, jnp.float32
+        state = {}
+        if self.counts["W"]:
+            ring = (self.counts["W"], slots * self.ring_blocks(block_size)) + row
+            state.update(ring_k=jnp.zeros(ring, cd), ring_v=jnp.zeros(ring, cd))
+        if self.counts["N"]:
+            kd, vd = self.gdn_widths
+            n = self.counts["N"]
+            state.update(
+                gdn_state=tuple(jnp.zeros(
+                    (slots, self.gdn_value_heads, self.gdn_key_dim,
+                     self.gdn_value_dim), f32) for _ in range(n)),
+                gdn_conv=tuple(jnp.zeros(
+                    (slots, self.conv_kernel - 1, 2 * kd + vd), f32)
+                    for _ in range(n)))
         return PagedKVCache(
             k=jnp.zeros(pool, cd), v=jnp.zeros(pool, cd),
             block_tables=jnp.zeros(
                 (slots, self.paged_blocks_per_slot(block_size)), jnp.int32),
             lengths=jnp.zeros((slots,), jnp.int32),
-            ring_k=jnp.zeros(ring, cd), ring_v=jnp.zeros(ring, cd))
+            slot_state=state or None)
 
     def _head_rows(self, params, h):
         """h [S, d] -> logits [S, vocab] float32."""
         with jax.named_scope(names.LM_HEAD):
-            return self._dot(rmsnorm(h, params.norm_f, self.norm_eps),
-                             params.head)
+            return self._dot(self._norm(h, params.norm_f), params.head)
 
     def extend_paged(self, params, cache: PagedKVCache, tokens, suffix_lens,
                      prefix_lens, admit):
         """Prefill rows ``tokens`` [B, L] (right-padded, real lengths
         ``suffix_lens`` [B]) from position 0, through the training-shaped
-        layers (the flash kernels, a ``W`` layer's by its band), and write
-        their K/V where ``admit``: the ``G`` layers' through the rows'
-        block tables, the ``W`` layers' last ``window`` positions into the
-        rings of the rows' slots (``cache.slot_of_row``; None: row ``s`` is
-        slot ``s``). No cached prefix is read: ``prefix_lens`` is the
+        layers (the flash kernels, a ``W`` layer's by its band, an ``N``
+        layer's chunked scan), and write each row's state where ``admit``,
+        into the rows' slots (``cache.slot_of_row``; None: row ``s`` is
+        slot ``s``): the ``G`` layers' K/V through the rows' block tables,
+        the ``W`` layers' last ``window`` positions into the slots' rings,
+        an ``N`` layer's state at the row's real length (its pads neither
+        decay nor write) and its last rows before the convolution, zero
+        before position 0. No cached prefix is read: ``prefix_lens`` is the
         interface's and must be 0 (the server refuses prefix reuse with
         this cache). Returns (logits [B, vocab] float32 of each row's last
         real position, the cache; its lengths and tables untouched)."""
@@ -895,16 +1156,26 @@ class HybridLM:
         with jax.named_scope(names.EMBED):
             h = params.embed[tokens].astype(jnp.float32)
         layers = _layer_iters(params)
-        rows = {"G": [], "W": []}
+        rows = {"G": [], "W": [], "N": []}
         for kind in self.pattern:
             p = next(layers[kind])
-            if kind in rows:
+            if kind in ("G", "W"):
                 q, k, v, gate = self._gated_qkv(p, h, kind, positions[0])
                 _, _, window, scope = self._kind(kind)
                 with jax.named_scope(scope):
                     a = self._attend(q, k, v, window=window)
                 h = h + self._gated_out(p, a, gate)
                 rows[kind].append((k, v))
+            elif kind == "N":
+                out, state, qkv = self._gdn_scan(p, h, valid)
+                h = h + out
+                with jax.named_scope(names.GDN_CONV):
+                    back = suffix_lens[:, None] + jnp.arange(
+                        1 - self.conv_kernel, 0)  # [B, kernel - 1]
+                    conv = jnp.take_along_axis(
+                        qkv, jnp.maximum(back, 0)[..., None], axis=1)
+                    conv = jnp.where(back[..., None] >= 0, conv, 0.0)
+                rows["N"].append((state, conv))
             elif kind == "E":
                 h = h + self._experts(p, h, token_mask=valid)[0]
             else:
@@ -919,16 +1190,25 @@ class HybridLM:
                 new[name] = paged.scatter_token_kv_all_layers(
                     pool, jnp.stack(got).astype(pool.dtype),
                     cache.block_tables, positions, valid)
+        slot_of_row = (jnp.arange(b, dtype=jnp.int32)
+                       if cache.slot_of_row is None else cache.slot_of_row)
+        state = dict(cache.slot_state or {})
         if rows["W"]:
-            slot_of_row = (jnp.arange(b, dtype=jnp.int32)
-                           if cache.slot_of_row is None else cache.slot_of_row)
-            ring = self.ring_blocks(cache.ring_k.shape[2])
-            for name, pool, got in zip(("ring_k", "ring_v"),
-                                       (cache.ring_k, cache.ring_v),
-                                       zip(*rows["W"])):
-                new[name] = paged.scatter_ring_rows(
+            ring = self.ring_blocks(state["ring_k"].shape[2])
+            for name, got in zip(("ring_k", "ring_v"), zip(*rows["W"])):
+                pool = state[name]
+                state[name] = paged.scatter_ring_rows(
                     pool, jnp.stack(got).astype(pool.dtype), slot_of_row,
                     positions, valid, ring=ring, window=self.window)
+        if rows["N"]:
+            # a row that admits nothing writes nothing
+            at = jnp.where(admit, slot_of_row, cache.lengths.shape[0])
+            for name, got in zip(("gdn_state", "gdn_conv"), zip(*rows["N"])):
+                state[name] = tuple(
+                    old.at[at].set(row, mode="drop")
+                    for old, row in zip(state[name], got))
+        if state:
+            new["slot_state"] = state
         return logits, cache._replace(**new)
 
     def decode_paged(self, params, token, cache: PagedKVCache, active=None,
@@ -939,14 +1219,17 @@ class HybridLM:
         none is given), a ``W`` layer its slot's ring, each meeting the
         row it writes as one more key; every layer's fresh row is
         committed after the last layer (pool rows through the tables,
-        ring rows at ``(lengths // bs) % ring``). Inactive slots are
-        untouched and keep out of the experts. Returns (logits [S, vocab],
-        cache with lengths advanced where active), and with ``counters``
-        also the experts the active slots' tokens chose this step, as
-        many distinct ones per ``E`` layer (int32 [n_E])."""
+        ring rows at ``(lengths // bs) % ring``); an ``N`` layer advances
+        its slot's state and convolution rows by the token. Inactive slots
+        are untouched and keep out of the experts. Returns (logits [S,
+        vocab], cache with lengths advanced where active), and with
+        ``counters`` also the experts held here that the active slots'
+        tokens chose this step, as many distinct ones per ``E`` layer
+        (int32 [n_E])."""
         s = token.shape[0]
         act = jnp.ones((s,), bool) if active is None else active
         lengths = cache.lengths
+        state = dict(cache.slot_state or {})
         with jax.named_scope(names.EMBED):
             h = params.embed[token[:, None]].astype(jnp.float32)
         if live is None and self.counts["G"]:
@@ -954,11 +1237,11 @@ class HybridLM:
                 cache.block_tables, lengths, act, 1, cache.k.shape[1],
                 cache.k.shape[2])
         layers = _layer_iters(params)
-        rows = {"G": [], "W": []}
+        rows = {"G": [], "W": [], "N": []}
         touched = []
         for kind in self.pattern:
             p = next(layers[kind])
-            if kind in rows:
+            if kind in ("G", "W"):
                 q, k, v, gate = self._gated_qkv(p, h, kind, lengths[:, None])
                 heads, _, _, scope = self._kind(kind)
                 k_row, v_row = k[:, 0], v[:, 0]
@@ -968,15 +1251,24 @@ class HybridLM:
                         len(rows["G"]), live, lengths, scopes=(scope, scope))
                 else:
                     a = paged.ring_decode_attention(
-                        q[:, 0], k_row, v_row, cache.ring_k, cache.ring_v,
-                        len(rows["W"]), lengths, window=self.window,
-                        scope=scope)
+                        q[:, 0], k_row, v_row, state["ring_k"],
+                        state["ring_v"], len(rows["W"]), lengths,
+                        window=self.window, scope=scope)
                 rows[kind].append((k_row, v_row))
                 h = h + self._gated_out(
                     p, a.reshape(s, 1, heads, self.head_dim), gate)
+            elif kind == "N":
+                i = len(rows["N"])
+                out, st, conv = self._gdn_decode(
+                    p, h, state["gdn_state"][i], state["gdn_conv"][i], act)
+                h = h + out
+                rows["N"].append((st, conv))
             elif kind == "E":
                 out, load = self._experts(p, h, token_mask=act[:, None])
                 h = h + out
+                first, held = self.experts_held
+                if held < self.num_experts:  # the experts held here
+                    load = load[first:first + held]
                 touched.append(jnp.sum(load > 0, dtype=jnp.int32))
             else:
                 h = h + self._dense(p, h)
@@ -989,11 +1281,15 @@ class HybridLM:
                     pool, jnp.stack(got).astype(pool.dtype),
                     cache.block_tables, lengths, act)
         if rows["W"]:
-            for name, pool, got in zip(("ring_k", "ring_v"),
-                                       (cache.ring_k, cache.ring_v),
-                                       zip(*rows["W"])):
-                new[name] = paged.commit_ring_rows(
+            for name, got in zip(("ring_k", "ring_v"), zip(*rows["W"])):
+                pool = state[name]
+                state[name] = paged.commit_ring_rows(
                     pool, jnp.stack(got).astype(pool.dtype), lengths, act)
+        if rows["N"]:
+            state["gdn_state"], state["gdn_conv"] = (
+                tuple(t) for t in zip(*rows["N"]))
+        if state:
+            new["slot_state"] = state
         cache = cache._replace(**new)
         if not counters:
             return logits, cache

@@ -95,6 +95,16 @@ KDA_PROJ = "kda_proj"
 KDA_CONV = "kda_conv"
 KDA_GATE = "kda_gate"
 KDA_SCAN = "kda_scan"
+# A gated delta-rule layer with one decay per head ("N", Gated DeltaNet):
+# its projections in and out with the gated output norm, the causal
+# depthwise convolution (served: with the shift of the slot's last rows),
+# the chunked scan over a sequence (prefill, training) and the one-token
+# update of a slot's state (the served decode step); the L2 norms, the
+# decay and the write strength run under the scan's or the update's scope.
+GDN_PROJ = "gdn_proj"
+GDN_CONV = "gdn_conv"
+GDN_SCAN = "gdn_scan"
+GDN_STATE = "gdn_state"
 HYBRID_SCOPES = (
     SSM_PROJ, SSM_CONV, SSM_SCAN, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
     MOE_SHARED,
@@ -106,9 +116,10 @@ KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN)
 ATTN_FULL = "attn_full"
 ATTN_WINDOW = "attn_window"
 RING_SCOPES = (ATTN_FULL, ATTN_WINDOW)
+GDN_SCOPES = (GDN_PROJ, GDN_CONV, GDN_SCAN, GDN_STATE)
 SCOPES = MODEL_SCOPES + (
     LOSS, KV_WRITE, KV_GATHER, KV_RESTACK, PICK, OPTIMIZER,
-) + HYBRID_SCOPES + KDA_SCOPES + RING_SCOPES
+) + HYBRID_SCOPES + KDA_SCOPES + RING_SCOPES + GDN_SCOPES
 
 # -- Pallas kernels -----------------------------------------------------------
 KERNEL_FLASH_FWD = "flash_fwd"

@@ -62,7 +62,10 @@ the matrix unit. Any other shape (the tests' narrow heads) takes
 argument chooses between the two. Everything else here is plain
 ``jax.numpy`` that ``jax.grad`` differentiates.
 
-:func:`kda_sequential` is the three lines above token by token, for tests.
+:func:`kda_step` is the three lines above for one token from a given state
+(a served decode step), with the decay per key channel or one per head
+broadcast over the channels (a "Gated DeltaNet" layer's form);
+:func:`kda_sequential` is them token by token, for tests.
 """
 
 from __future__ import annotations
@@ -371,13 +374,17 @@ def _within_chunks(q, k, v, g, beta, sub, precision):
             k * jnp.exp(end - cum), jnp.exp(end[..., 0, :]))
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, precision=None):
+def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, precision=None,
+                final_state: bool = False):
     """q, k, g [B, L, H, K], v [B, L, H, V], beta [B, L, H] -> o [B, L, H,
     V] (float32), from a zero state. ``L`` need not be a multiple of
     ``chunk``: the tail is padded with tokens that neither decay nor write
-    (``g = 0``, ``beta = 0``). A chunk longer than :data:`SUB` is a
-    multiple of it. ``precision`` is the products' (``lax.Precision`` or
-    None)."""
+    (``g = 0``, ``beta = 0``), so a caller's own padding passes the state
+    through unchanged where it gives them the same. A chunk longer than
+    :data:`SUB` is a multiple of it. ``precision`` is the products'
+    (``lax.Precision`` or None). With ``final_state`` the result is
+    ``(o, S)``: ``S`` [B, H, K, V] float32, the state after the last
+    token (the carry of the scan over chunks)."""
     bsz, l, h, _ = q.shape
     sub = min(SUB, chunk)
     if chunk % sub:
@@ -413,11 +420,30 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, precision=None):
             one_chunk, s, _within_chunks(qs, ks, vs, gs, bs, sub, precision))
 
     s0 = jnp.zeros((bsz, h, q.shape[-1], v.shape[-1]), f32)
-    _, o = lax.scan(one_step, s0, (by_step(q), by_step(k), by_step(v),
+    s, o = lax.scan(one_step, s0, (by_step(q), by_step(k), by_step(v),
                                    by_step(g), beta_s))
     # [steps, per, B, H, chunk, V] -> [B, L, H, V]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 0), 3, 4)
-    return o.reshape(bsz, l + pad, h, v.shape[-1])[:, :l]
+    o = o.reshape(bsz, l + pad, h, v.shape[-1])[:, :l]
+    return (o, s) if final_state else o
+
+
+def kda_step(s, q, k, v, g, beta):
+    """One token of the recurrence for every row: s [B, H, K, V], q, k
+    [B, H, K], v [B, H, V], beta [B, H] and the log-decay ``g`` either per
+    key channel [B, H, K] or one per head [B, H] -> (o [B, H, V], the new
+    state), all float32. The sums over ``K`` are elementwise products
+    summed (exact float32, as ``precision=HIGHEST`` asks of a product):
+    one token's update reads and writes the state once, a product's
+    passes would buy nothing."""
+    f32 = jnp.float32
+    s, q, k, v, g, beta = (t.astype(f32) for t in (s, q, k, v, g, beta))
+    decay = jnp.exp(g)[..., None] if g.ndim == k.ndim else jnp.exp(g)[
+        ..., None, None]
+    s = decay * s
+    answered = jnp.sum(s * k[..., None], axis=-2)  # S'^T k  [B, H, V]
+    s = s + (beta[..., None] * k)[..., None] * (v - answered)[..., None, :]
+    return jnp.sum(s * q[..., None], axis=-2), s
 
 
 def kda_sequential(q, k, v, g, beta, *, precision=None):

@@ -381,6 +381,19 @@ def route_sigmoid_topk(x, router, bias, k: int, scale: float,
     return idx.astype(jnp.int32), w
 
 
+def route_softmax_topk(x, router, k: int):
+    """Softmax probabilities over all experts (float32); the ``k`` most
+    probable chosen and weighed by their probabilities renormalised to
+    sum 1 over the chosen (no bias, no scale). x [T, D], router [D, E] ->
+    (expert index [T, k] int32, weight [T, k] float32)."""
+    f32 = jnp.float32
+    logits = jnp.dot(
+        x.astype(f32), router.astype(f32), precision=lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    top, idx = lax.top_k(p, k)
+    return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+
+
 def dispatch_held(idx, first: int, count: int, experts: int):
     """Which (token, choice) pairs landed on the ``count`` experts from
     ``first``, in expert order. idx [T, k] -> (``order`` [T*k]: the pair
@@ -434,19 +447,18 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
     return min(pairs, -(-even // 512) * 512)
 
 
-def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
-                 scale: float, w_gate=None, compute_dtype=jnp.bfloat16,
-                 balance: tuple[int, int] | None = None,
+def moe_ffn_held(x, idx, w, w_up, w_down, *, experts: int, first: int,
+                 w_gate=None, compute_dtype=jnp.bfloat16,
                  block_rows: int | None = None, token_mask=None):
-    """The routed part of a sigmoid-scored, top-``k`` expert layer that
-    this holder of experts ``first .. first + w_up.shape[0] - 1``
-    computes. x [T, D], router [D, E] over all E experts, bias [E], w_up
-    [E_held, D, F], w_down [E_held, F, D] -> (out [T, D] float32: the sum,
-    over the choices that landed here, of weight * expert(x); load [E]
-    int32: the (token, choice) pairs that chose each expert, held or not —
-    ``load[first:first + E_held]`` landed here). ``balance`` = (tokens a
-    sequence, rounds): the choice is by each sequence's own
-    :func:`balancing_bias`. The expert's form: ``w_down relu(w_up x)^2``
+    """The routed part of a top-``k`` expert layer that this holder of
+    experts ``first .. first + w_up.shape[0] - 1`` computes, given the
+    routing (:func:`route_sigmoid_topk` or :func:`route_softmax_topk`).
+    x [T, D], idx [T, k] int32 the chosen of all ``experts`` experts, w
+    [T, k] their weights, w_up [E_held, D, F], w_down [E_held, F, D] ->
+    (out [T, D] float32: the sum, over the choices that landed here, of
+    weight * expert(x); load [experts] int32: the (token, choice) pairs
+    that chose each expert, held or not — ``load[first:first + E_held]``
+    landed here). The expert's form: ``w_down relu(w_up x)^2``
     (:func:`relu2_experts`), or with ``w_gate`` [E_held, D, F] the gated
     ``w_down (silu(w_gate x) * w_up x)`` (:func:`silu_gated_experts`).
 
@@ -465,17 +477,16 @@ def moe_ffn_held(x, router, bias, w_up, w_down, *, first: int, k: int,
     False for out of the experts and of ``load``: they are routed, land
     nowhere and get 0 (a serving step's vacant slots and pad positions)."""
     t, d = x.shape
+    k = idx.shape[1]
     pairs = t * k
     count = w_up.shape[0]
-    r = block_rows or held_block_rows(pairs, count, router.shape[1])
+    r = block_rows or held_block_rows(pairs, count, experts)
     nb = -(-pairs // r)
-    with jax.named_scope(names.MOE_ROUTE):
-        idx, w = route_sigmoid_topk(x, router, bias, k, scale, balance)
     with jax.named_scope(names.MOE_DISPATCH):
         order, load = dispatch_held(
             idx if token_mask is None
             else jnp.where(token_mask[:, None], idx, -1),
-            first, count, router.shape[1])
+            first, count, experts)
         rows = load[first:first + count]
         order = jnp.pad(order, (0, nb * r - pairs))
         ends = jnp.cumsum(rows)

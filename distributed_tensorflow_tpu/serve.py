@@ -275,10 +275,10 @@ class _PagedState(NamedTuple):
     eos: jax.Array  # [S] i32 — -1: no EOS stop
     k_scale: jax.Array | None = None  # [layers, NB, bs, Hkv] f32
     v_scale: jax.Array | None = None
-    # A model with window layers: their rings, [window layers, S*ring,
-    # bs, Hkv, Dh] (PagedKVCache says how they are addressed).
-    ring_k: jax.Array | None = None
-    ring_v: jax.Array | None = None
+    # The model's per-slot state beside the pool (window layers' rings,
+    # delta-rule layers' recurrent states): PagedKVCache.slot_state, carried
+    # whole and read by the model alone.
+    slot_state: dict | None = None
 
 
 class _Request:
@@ -409,13 +409,15 @@ class TextServer:
             raise ValueError(
                 f"queue_limit must be >= 1 (or None), got {queue_limit}"
             )
-        # A model with window layers (HybridLM) keeps their K/V in a ring
-        # of blocks per slot, beside the block pool of its full layers
-        # (docs/serving.md §rings). What needs the pool's shareable,
-        # relocatable blocks or every position's logits is refused here.
-        self._ringed = hasattr(model, "ring_blocks")
-        if self._ringed:
-            why = "with window layers' rings in the cache (ROADMAP R-kinds)"
+        # A model whose layers keep state per slot beside the block pool of
+        # its full layers (HybridLM: window layers' rings, delta-rule
+        # layers' recurrent states; docs/serving.md §per-slot state). What
+        # needs the pool's shareable, relocatable blocks or every
+        # position's logits is refused here.
+        self._per_slot = getattr(model, "per_slot_state", False)
+        if self._per_slot:
+            why = ("with window layers' rings or recurrent states in the "
+                   "cache (ROADMAP R-kinds, R-state)")
             for bad, what in (
                 (not paged, "the slab cache (paged=False)"),
                 (prefix_caching, "prefix reuse (prefix_caching=True)"),
@@ -425,7 +427,7 @@ class TextServer:
                 if bad:
                     raise ValueError(f"{what} is not served {why}")
         self.ring_blocks = (
-            model.ring_blocks(int(block_size)) if self._ringed else 0
+            model.ring_blocks(int(block_size)) if self._per_slot else 0
         )
         self.model = model
         # Bounded admission queue (round 16): submit() raises QueueFull
@@ -639,6 +641,16 @@ class TextServer:
                 self.slots * self.ring_blocks
             )
             self.metrics.gauge("kv_ring_blocks_used").set(0)
+        # Bytes of the per-slot state (rings and recurrent states),
+        # allocated once; a busy slot's share of them is in use.
+        self.slot_state_bytes = sum(
+            int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(
+                getattr(self._state, "slot_state", None)))
+        if self.slot_state_bytes:
+            self.metrics.gauge("slot_state_bytes_total").set(
+                self.slot_state_bytes
+            )
+            self.metrics.gauge("slot_state_bytes_used").set(0)
         # Live scrape surface (round 12, observability/exporter.py):
         # /metrics = the registry's Prometheus text, /healthz = engine
         # heartbeat (seconds since the last step() tick) + occupancy.
@@ -713,8 +725,7 @@ class TextServer:
                 lengths=cache.lengths,
                 k_scale=cache.k_scale,
                 v_scale=cache.v_scale,
-                ring_k=cache.ring_k,
-                ring_v=cache.ring_v,
+                slot_state=cache.slot_state,
                 **common,
             )
         cache = self.model.empty_slot_cache(s, self.kv_dtype)
@@ -826,8 +837,7 @@ class TextServer:
                 lengths=st.lengths,
                 k_scale=st.k_scale,
                 v_scale=st.v_scale,
-                ring_k=st.ring_k,
-                ring_v=st.ring_v,
+                slot_state=st.slot_state,
             )
         return SlotKVCache(
             k=st.k,
@@ -906,8 +916,7 @@ class TextServer:
             v=cache.v,
             k_scale=cache.k_scale,
             v_scale=cache.v_scale,
-            ring_k=cache.ring_k,
-            ring_v=cache.ring_v,
+            slot_state=cache.slot_state,
             block_tables=put(st.block_tables, block_tables),
             lengths=put(st.lengths, prefix_lens + suffix_lens),
             last_tok=put(st.last_tok, first),
@@ -1016,8 +1025,9 @@ class TextServer:
         entries and the entries a step walks (each slot's count rounded
         up to whole tiles), each repeated over the slots: they reach the host
         in the fetch that brings the tokens (:meth:`_account_delivery`).
-        A model with window layers adds one row per expert layer: the
-        distinct experts its steps' tokens chose, summed over the chunk."""
+        A model with per-slot state (a ``HybridLM``) adds one row per
+        expert layer: the distinct experts its steps' tokens chose, summed
+        over the chunk."""
         max_len = self.model.max_len
         decode = (
             self.model.decode_paged if self.paged else self.model.decode_slots
@@ -1031,7 +1041,7 @@ class TextServer:
         pool_shape = st.k.shape
         walked = None
         if self.paged:
-            if not self._ringed:
+            if not self._per_slot:
                 # (A HybridLM's rows are [8, 128] or another whole tile;
                 # flattening them would copy the pool twice a chunk.)
                 flat = pool_shape[:3] + (-1,)
@@ -1046,7 +1056,7 @@ class TextServer:
 
         def body(st, _):
             act = ~st.finished & (st.lengths < max_len)
-            if self._ringed:  # with the experts each step touched
+            if self._per_slot:  # with the experts each step touched
                 logits, cache, touched = decode(
                     params, st.last_tok, self._cache(st), active=act,
                     counters=True,
@@ -1078,8 +1088,8 @@ class TextServer:
                 emitted=emitted,
                 finished=fin,
             )
-            if self._ringed:
-                st = st._replace(ring_k=cache.ring_k, ring_v=cache.ring_v)
+            if self._per_slot:
+                st = st._replace(slot_state=cache.slot_state)
                 return st, (nxt, act, touched)
             return st, (nxt, act)
 
@@ -1089,7 +1099,7 @@ class TextServer:
             st = st._replace(
                 k=st.k.reshape(pool_shape), v=st.v.reshape(pool_shape)
             )
-            if self._ringed:  # per E layer, summed over the chunk's steps
+            if self._per_slot:  # per E layer, summed over the chunk's steps
                 walked = jnp.concatenate([walked, outs[2].sum(0)])
             toks = jnp.concatenate([
                 toks,
@@ -1188,11 +1198,11 @@ class TextServer:
                 "KV migration requires paged=True (block tables are what "
                 "make the cache relocatable across replicas)"
             )
-        if (prefill_only or resume is not None) and self._ringed:
+        if (prefill_only or resume is not None) and self._per_slot:
             raise ValueError(
-                "KV migration is not served with window layers' rings in "
-                "the cache: a payload carries pool blocks, not rings "
-                "(ROADMAP R-kinds)"
+                "KV migration is not served with per-slot state in the "
+                "cache: a payload carries pool blocks, not window layers' "
+                "rings or recurrent states (ROADMAP R-kinds, R-state)"
             )
         if prefill_only and resume is not None:
             raise ValueError(
@@ -1681,10 +1691,10 @@ class TextServer:
         compile measurement (the ``--warm`` contract)."""
         if not self.paged:
             return
-        if self._ringed:
+        if self._per_slot:
             raise ValueError(
-                "KV migration is not served with window layers' rings in "
-                "the cache (ROADMAP R-kinds)"
+                "KV migration is not served with per-slot state in the "
+                "cache (ROADMAP R-kinds, R-state)"
             )
         from distributed_tensorflow_tpu.models.gpt import (
             export_kv_blocks,
@@ -1806,7 +1816,7 @@ class TextServer:
                 [m for m in batch if m[3] == wave], wave
             )
         self.metrics.gauge("kv_blocks_used").set(self._alloc.used_blocks)
-        self._set_ring_gauge()
+        self._set_slot_gauges()
 
     def _prefill_wave(self, members_w, wave: int) -> None:
         """One admission wave's prefill dispatches: per length bucket
@@ -1959,14 +1969,19 @@ class TextServer:
             self.metrics.gauge("kv_blocks_used").set(
                 self._alloc.used_blocks
             )
-        self._set_ring_gauge()
+        self._set_slot_gauges()
 
-    def _set_ring_gauge(self) -> None:
-        """Ring blocks held: a ring per busy slot (per window layer)."""
+    def _set_slot_gauges(self) -> None:
+        """Per-slot state held by busy slots: ring blocks (a ring per
+        slot, per window layer) and the bytes of all of it."""
+        busy = sum(r is not None for r in self._slot_req)
         if self.ring_blocks:
             self.metrics.gauge("kv_ring_blocks_used").set(
-                self.ring_blocks
-                * sum(r is not None for r in self._slot_req)
+                self.ring_blocks * busy
+            )
+        if self.slot_state_bytes:
+            self.metrics.gauge("slot_state_bytes_used").set(
+                self.slot_state_bytes * busy // self.slots
             )
 
     def _cancel(self, req: _Request, *, slot: int | None = None) -> None:

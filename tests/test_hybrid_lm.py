@@ -270,12 +270,13 @@ def test_the_shares_add_up_to_the_uncut_layer(block_rows):
     x, router, w_up, w_down = _layer()
     bias = 0.1 * jax.random.normal(jax.random.key(4), (32,))
     whole, _ = _dense_layer(x, router, bias, w_up, w_down, 3, 2.5)
+    idx, w = moe.route_sigmoid_topk(x, router, bias, 3, 2.5)
     total, landed = 0.0, 0
     for share in range(16):
         first = 2 * share
         out, load = moe.moe_ffn_held(
-            x, router, bias, w_up[first:first + 2], w_down[first:first + 2],
-            first=first, k=3, scale=2.5, compute_dtype=jnp.float32,
+            x, idx, w, w_up[first:first + 2], w_down[first:first + 2],
+            experts=32, first=first, compute_dtype=jnp.float32,
             block_rows=block_rows)
         assert int(load.sum()) == 40 * 3  # the load is over all experts
         total, landed = total + out, landed + int(load[first:first + 2].sum())
@@ -289,8 +290,9 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert(block_rows):
     bias = jnp.zeros((32,)).at[9].set(50.0)  # every token's first choice
     whole, idx = _dense_layer(x, router, bias, w_up, w_down, 3, 2.5)
     held = slice(8, 12)
+    chosen, w = moe.route_sigmoid_topk(x, router, bias, 3, 2.5)
     out, load = moe.moe_ffn_held(
-        x, router, bias, w_up[held], w_down[held], first=8, k=3, scale=2.5,
+        x, chosen, w, w_up[held], w_down[held], experts=32, first=8,
         compute_dtype=jnp.float32, block_rows=block_rows)
     rows = load[held]
     assert int(rows[1]) == 40  # all 40 tokens landed on expert 9
@@ -301,8 +303,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert(block_rows):
         x, router, bias,
         w_up.at[:8].set(0).at[12:].set(0), w_down, 3, 2.5)
     close(out, only, 2e-5)
-    grads = jax.grad(lambda w: moe.moe_ffn_held(
-        x, router, bias, w, w_down[held], first=8, k=3, scale=2.5,
+    grads = jax.grad(lambda up: moe.moe_ffn_held(
+        x, chosen, w, up, w_down[held], experts=32, first=8,
         compute_dtype=jnp.float32, block_rows=block_rows)[0].sum())(w_up[held])
     want = jax.grad(lambda w: _dense_layer(
         x, router, bias, jnp.zeros_like(w_up).at[held].set(w), w_down, 3,

@@ -234,11 +234,12 @@ def test_the_gated_shares_add_up_to_the_uncut_layer(block_rows):
     x = _unit_rows(x)
     bias = 0.1 * jax.random.normal(jax.random.key(4), (32,))
     whole, load_ref = _reference_layer(x, router, bias, w_gate, w_up, w_down)
+    idx, w = moe.route_sigmoid_topk(x, router, bias, 3, 2.446)
     total, landed = 0.0, 0
     for share in range(32):
         out, load = moe.moe_ffn_held(
-            x, router, bias, w_up[share:share + 1], w_down[share:share + 1],
-            w_gate=w_gate[share:share + 1], first=share, k=3, scale=2.446,
+            x, idx, w, w_up[share:share + 1], w_down[share:share + 1],
+            w_gate=w_gate[share:share + 1], experts=32, first=share,
             compute_dtype=jnp.float32, block_rows=block_rows)
         assert int(load.sum()) == 40 * 3  # the load is over all experts
         np.testing.assert_array_equal(np.asarray(load), np.asarray(load_ref))
@@ -281,17 +282,18 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert(block_rows):
     x = _unit_rows(x)
     bias = jnp.zeros((32,)).at[9].set(50.0)  # every token's first choice
     held = slice(8, 12)
+    idx, w = moe.route_sigmoid_topk(x, router, bias, 3, 2.446)
     out, load = moe.moe_ffn_held(
-        x, router, bias, w_up[held], w_down[held], w_gate=w_gate[held],
-        first=8, k=3, scale=2.446, compute_dtype=jnp.float32,
+        x, idx, w, w_up[held], w_down[held], w_gate=w_gate[held],
+        experts=32, first=8, compute_dtype=jnp.float32,
         block_rows=block_rows)
     assert int(load[9]) == 40  # all 40 tokens landed on expert 9
     only, _ = _reference_layer(x, router, bias, w_gate, w_up, w_down, (8, 4))
     close(out, only, 2e-5)
     grad = lambda f: jax.grad(lambda w: f(w).sum())(w_gate[held])  # noqa: E731
-    got = grad(lambda w: moe.moe_ffn_held(
-        x, router, bias, w_up[held], w_down[held], w_gate=w, first=8, k=3,
-        scale=2.446, compute_dtype=jnp.float32, block_rows=block_rows)[0])
+    got = grad(lambda g: moe.moe_ffn_held(
+        x, idx, w, w_up[held], w_down[held], w_gate=g, experts=32, first=8,
+        compute_dtype=jnp.float32, block_rows=block_rows)[0])
     want = grad(lambda w: _reference_layer(
         x, router, bias, w_gate.at[held].set(w), w_up, w_down, (8, 4))[0])
     close(got, want, 1e-4)

@@ -453,6 +453,7 @@ def test_every_scope_and_program_is_held_by_some_case():
     held |= HYBRID_SCOPES  # test_hybrid_train_step_program_carries_every_scope
     held |= KDA_SCOPES  # test_delta_rule_train_step_program_carries_every_scope
     held |= set(names.RING_SCOPES)  # test_hybrid_serving_programs_carry_...
+    held |= set(names.GDN_SCOPES)  # test_gdn_serving_programs_carry_...
     for _, _, extra in PROGRAM_CASES.values():
         held |= extra
     assert held == set(names.SCOPES)
@@ -483,6 +484,32 @@ def test_hybrid_serving_programs_carry_the_attention_kinds_scopes():
                      (srv._prefill_jit, _prefill_args(srv))):
         got = _scopes_by_phase(fn.lower(*args).compile())
         assert got["forward"] >= want, want - got["forward"]
+
+
+def test_gdn_serving_programs_carry_the_delta_rules_scopes():
+    """A served ``HybridLM`` with ``N`` layers names their projections and
+    convolution in both programs, the chunked scan in the prefill and the
+    one-token state update in the decode chunk: the benchmark's
+    ``gdn_*.qnext`` readers read them by program."""
+    from distributed_tensorflow_tpu.models.hybrid import HybridLM
+    from distributed_tensorflow_tpu.ops.rotary import Rotary
+
+    m = HybridLM(
+        64, 32, "NEGE", full_heads=4, num_kv_heads=2, head_dim=8,
+        full_rotary=Rotary(1e7, 4), full_gate="element", full_qk_norm=True,
+        gdn_key_heads=2, gdn_key_dim=8, gdn_value_heads=4, gdn_value_dim=8,
+        num_experts=8, experts_per_token=2, expert_dim=8, shared_dim=8,
+        expert_form="silu_gated", moe_router="softmax", norm_offset=1.0,
+        max_len=32)
+    srv = TextServer(m, m.init(1), paged=True, slots=2, chunk=2,
+                     block_size=4, buckets=(8, 16), prefix_caching=False)
+    both = {names.GDN_PROJ, names.GDN_CONV, names.ATTN_FULL, names.MOE_ROUTE,
+            names.MOE_SHARED}
+    for fn, args, own in (
+            (srv._chunk_jit, (srv.params, srv._state), names.GDN_STATE),
+            (srv._prefill_jit, _prefill_args(srv), names.GDN_SCAN)):
+        got = _scopes_by_phase(fn.lower(*args).compile())
+        assert got["forward"] >= both | {own}, (both | {own}) - got["forward"]
 
 
 def test_compiled_run_program_name(trainer):
